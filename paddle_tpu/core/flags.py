@@ -14,6 +14,7 @@ between C++ and Python (core.globals()).
 """
 from __future__ import annotations
 
+import contextlib
 import os
 import threading
 from typing import Any, Dict
@@ -185,6 +186,44 @@ define_flag("use_pallas_rms_norm", True,
 define_flag("pallas_force_interpret", False,
             "run Pallas kernels in interpret mode on non-TPU backends "
             "(testing only — the interpreter is orders slower than XLA)")
+
+_pallas_mode_override = None
+
+
+def pallas_mode() -> str:
+    """How Pallas kernels run in this process — the ONE predicate every
+    kernel gate and every ``pallas_call`` reads, so a caller can assert
+    which path ran:
+
+    - ``"compiled"``: Mosaic-compiled (the default backend is ``tpu``);
+    - ``"interpret"``: the Pallas interpreter, off-TPU with the
+      ``pallas_force_interpret`` test flag set. Interpret mode checks
+      the kernel's arithmetic, NOT that Mosaic accepts it;
+    - ``"off"``: the gates route to the XLA compositions (kernels called
+      directly through ``ops.pallas.*`` still interpret).
+    """
+    if _pallas_mode_override is not None:
+        return _pallas_mode_override
+    import jax
+
+    if jax.default_backend() == "tpu":
+        return "compiled"
+    return "interpret" if get_flag("pallas_force_interpret") else "off"
+
+
+@contextlib.contextmanager
+def pallas_mode_override(mode: str):
+    """Pin :func:`pallas_mode` — for compile-only tooling that lowers
+    for a TPU topology from a host whose default backend is the CPU
+    (tests/test_tpu_aot_compile.py)."""
+    global _pallas_mode_override
+    if mode not in ("compiled", "interpret", "off"):
+        raise ValueError(f"unknown pallas mode {mode!r}")
+    prev, _pallas_mode_override = _pallas_mode_override, mode
+    try:
+        yield
+    finally:
+        _pallas_mode_override = prev
 define_flag("observability_ts_points", 512,
             "ring-buffer capacity per metric time-series (points kept by "
             "observability/timeseries.SeriesRecorder; oldest samples drop "

@@ -30,21 +30,13 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
-try:
-    from jax import shard_map as _jax_shard_map
-except ImportError:  # older JAX
-    from jax.experimental.shard_map import shard_map as _jax_shard_map
 
 
 def shard_map(fn, mesh, in_specs, out_specs):
     # replication checking is disabled: ppermute/all_to_all bodies are not
-    # representable under it (kwarg renamed check_rep→check_vma in jax 0.8)
-    try:
-        return _jax_shard_map(fn, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_vma=False)
-    except TypeError:
-        return _jax_shard_map(fn, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=False)
+    # representable under it
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 from ...core.tensor import Tensor, apply
 from ...ops._helpers import defprim, ensure_tensor
@@ -211,12 +203,9 @@ def _ring_flash_local_factory(axis, n, causal, scale):
 
 
 def _ring_use_flash(chunk: int, head_dim: int, nq: int, nkv: int) -> bool:
-    from ...core.flags import get_flag
+    from ...core.flags import get_flag, pallas_mode
 
-    if not get_flag("use_pallas_flash_attention"):
-        return False
-    if (jax.default_backend() != "tpu"
-            and not get_flag("pallas_force_interpret")):
+    if not get_flag("use_pallas_flash_attention") or pallas_mode() == "off":
         return False
     # non-divisible GQA head counts would silently floor-divide in the
     # kernel's kv-head map; let them fall back to the einsum path, which

@@ -11,6 +11,7 @@ host-bound on decode, not on Python loops at this scale.
 from __future__ import annotations
 
 import itertools
+import os
 import queue
 import threading
 from typing import Any, Callable, Iterable, List, Optional, Sequence
@@ -452,14 +453,27 @@ class _MultiprocessIter:
         from ._mp_worker import worker_loop
 
         self.procs = []
-        for wid in range(n):
-            p = ctx.Process(
-                target=worker_loop,
-                args=(loader.dataset, loader.worker_init_fn, wid, n,
-                      self.index_q, self.result_q),
-                daemon=True)
-            p.start()
-            self.procs.append(p)
+        # A worker must never open the accelerator: the chip belongs to
+        # this process, and a second one that touches it fails or hangs.
+        # Unpickling a dataset that holds Tensors initialises a JAX
+        # backend before worker_loop runs, so the platform is pinned in
+        # the environment the workers are spawned with.
+        prev = os.environ.get("JAX_PLATFORMS")
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        try:
+            for wid in range(n):
+                p = ctx.Process(
+                    target=worker_loop,
+                    args=(loader.dataset, loader.worker_init_fn, wid, n,
+                          self.index_q, self.result_q),
+                    daemon=True)
+                p.start()
+                self.procs.append(p)
+        finally:
+            if prev is None:
+                del os.environ["JAX_PLATFORMS"]
+            else:
+                os.environ["JAX_PLATFORMS"] = prev
         self._next_seq = 0      # next batch to hand out
         self._sent = 0          # jobs dispatched
         self._exhausted = False

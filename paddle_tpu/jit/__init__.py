@@ -250,7 +250,7 @@ def _aval_key(arrays):
 
 class _CompiledEntry:
     __slots__ = ("jitted", "slots", "out_template_box", "optimizers",
-                 "step_deltas", "fallback", "ran_ok")
+                 "step_deltas", "fallback", "ran_ok", "call_avals")
 
     def __init__(self):
         self.jitted = None
@@ -260,6 +260,7 @@ class _CompiledEntry:
         self.step_deltas = []
         self.fallback = False
         self.ran_ok = False
+        self.call_avals = None
 
 
 class StaticFunction:
@@ -324,35 +325,47 @@ class StaticFunction:
         rng = generator.next_key("local_seed")
         first_run = not entry.ran_ok  # first run pays jax trace + XLA compile
         t0 = time.perf_counter()
-        try:
-            out_arrays, new_state = entry.jitted(state, arrays, rng, lr_vals,
-                                                 steps)
-        except Exception as e:  # noqa: BLE001 — SOT-style graph break
-            # Reference contract (jit/sot program_translator.py:711): an
-            # untraceable construct (data-dependent Python control flow,
-            # reverse-mode through a while_loop, ...) must not crash the
-            # user's function — fall back to eager for this signature.
-            # Only TRACE-time failures fall back: if tracing succeeded and
-            # XLA execution itself failed, the input state buffers may
-            # already be donated/deleted, and the real error (OOM, nan
-            # check) must surface, not be masked by an eager rerun. Note
-            # the failed trace already ran the function's Python body, so
-            # Python-level side effects execute twice on a fallback call.
-            if self._full_graph or entry.ran_ok:
+        call_args = (state, arrays, rng, lr_vals, steps)
+        if first_run:
+            # shapes + placements of this signature, for lowered() (taken
+            # before the call donates the state buffers); an uncommitted
+            # array follows the others, as it does in the call
+            entry.call_avals = jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(
+                    a.shape, a.dtype,
+                    sharding=a.sharding if a.committed else None),
+                call_args)
+        if first_run and not self._full_graph:
+            try:
+                # trace only (the call below reuses the cached trace)
+                entry.jitted.trace(*call_args)
+            except jax.errors.JaxRuntimeError:
                 raise
-            if "XlaRuntimeError" in type(e).__name__:
-                raise
-            import warnings
+            except Exception as e:  # noqa: BLE001 — SOT-style graph break
+                # Reference contract (jit/sot program_translator.py:711): an
+                # untraceable construct (data-dependent Python control flow,
+                # reverse-mode through a while_loop, ...) must not crash the
+                # user's function — fall back to eager for this signature.
+                # Only a failure to TRACE falls back. Whatever the device or
+                # its compilers refuse — a Mosaic kernel, an out-of-memory
+                # program, a fault while running — raises from the call
+                # below: an eager rerun would hide it behind a slow step
+                # that still prints a number (and the donated state may be
+                # gone). Note the failed trace already ran the function's
+                # Python body, so Python-level side effects execute twice on
+                # a fallback call.
+                import warnings
 
-            warnings.warn(
-                f"to_static: tracing '{getattr(self._fn, '__name__', '?')}' "
-                f"failed ({type(e).__name__}: {e}); falling back to eager "
-                "execution for this input signature. Pass full_graph=True "
-                "to make this an error.")
-            entry.fallback = True
-            if _obs.state.on:
-                _M_JIT_FALLBACKS.inc(fn=fn_label)
-            return self._fn(*args, **kwargs)
+                warnings.warn(
+                    f"to_static: tracing '{fn_label}' "
+                    f"failed ({type(e).__name__}: {e}); falling back to eager "
+                    "execution for this input signature. Pass full_graph=True "
+                    "to make this an error.")
+                entry.fallback = True
+                if _obs.state.on:
+                    _M_JIT_FALLBACKS.inc(fn=fn_label)
+                return self._fn(*args, **kwargs)
+        out_arrays, new_state = entry.jitted(*call_args)
         entry.ran_ok = True
         if first_run and _obs.state.on:
             dt = time.perf_counter() - t0
@@ -457,6 +470,16 @@ class StaticFunction:
         donate = (0,) if self._donate else ()
         entry.jitted = jax.jit(pure_fn, donate_argnums=donate)
         return entry
+
+    def lowered(self):
+        """The jax ``Lowered`` program of every signature run so far, for
+        inspection: ``.as_text()`` is the StableHLO (Pallas kernels show
+        as ``tpu_custom_call`` with their kernel name),
+        ``.compile().as_text()`` the partitioned HLO the devices run.
+        Lowering re-traces the function but runs nothing and leaves the
+        state untouched."""
+        return [e.jitted.lower(*e.call_avals)
+                for e in self._cache.values() if e.ran_ok]
 
     @property
     def code(self):

@@ -99,9 +99,11 @@ class LlamaRMSNorm(nn.Layer):
             default_initializer=nn.initializer.Constant(1.0),
         )
         self.eps = config.rms_norm_eps
+        self.kernel_partition = None    # set by llama_shard_plan
 
     def forward(self, x):
-        return F.rms_norm(x, self.weight, self.eps)
+        return F.rms_norm(x, self.weight, self.eps,
+                          partition=self.kernel_partition)
 
 
 def fused_qkv_linear(x, projs):
@@ -147,6 +149,7 @@ class LlamaAttention(nn.Layer):
         self.k_proj = nn.Linear(h, kv, bias_attr=False)
         self.v_proj = nn.Linear(h, kv, bias_attr=False)
         self.o_proj = nn.Linear(h, h, bias_attr=False)
+        self.kernel_partition = None    # set by llama_shard_plan
 
     def forward(self, hidden_states, position_ids=None, attention_mask=None):
         b, s, h = hidden_states.shape
@@ -177,6 +180,7 @@ class LlamaAttention(nn.Layer):
             out = F.scaled_dot_product_attention(
                 q, k, v, attn_mask=attention_mask,
                 is_causal=attention_mask is None,
+                partition=self.kernel_partition,
             )
         return self.o_proj(out.reshape([b, s, h]))
 
@@ -335,10 +339,21 @@ def llama_shard_plan(model: LlamaForCausalLM, mesh, dp_axis="dp", mp_axis="mp"):
     - o_proj/down_proj:       Shard(0) on mp (row parallel)
     - lm_head.weight:         Shard(1) on mp
     - norms:                  replicated
+
+    and records on every attention and norm layer where the activations
+    live (batch over ``dp_axis``, heads over ``mp_axis``), so the Pallas
+    kernels run per shard instead of asking XLA to partition them.
     """
     import paddle_tpu.distributed as dist
+    from ..ops.kernel_partition import KernelPartition
 
     mp = mesh.dim_names.index(mp_axis)
+    partition = KernelPartition(
+        mesh, batch=dp_axis if dp_axis in mesh.dim_names else None,
+        heads=mp_axis)
+    for layer in model.sublayers():
+        if isinstance(layer, (LlamaAttention, LlamaRMSNorm)):
+            layer.kernel_partition = partition
 
     def place(p, tensor_dim=None):
         placements = [dist.Replicate() for _ in range(mesh.ndim)]

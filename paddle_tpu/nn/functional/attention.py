@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...core.flags import get_flag
+from ...core.flags import get_flag, pallas_mode
 from ...core.tensor import Tensor, apply
 from ...ops._helpers import defprim, ensure_tensor
 
@@ -88,8 +88,7 @@ _MASK_FLASH_MIN_SK = 1024
 def _use_pallas(q, k):
     if not get_flag("use_pallas_flash_attention"):
         return False
-    if (jax.default_backend() != "tpu"
-            and not get_flag("pallas_force_interpret")):
+    if pallas_mode() == "off":
         return False
     # lane-aligned seqlens, MXU-friendly head dim, divisible GQA groups
     return (q.shape[-1] % 64 == 0 and q.shape[1] % 128 == 0
@@ -98,12 +97,15 @@ def _use_pallas(q, k):
 
 def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                  dropout_p=0.0, is_causal=False, training=True,
-                                 name=None):
+                                 name=None, *, partition=None):
     """paddle.nn.functional.scaled_dot_product_attention parity
     (flash_attention.py:991). Input layout [B, S, H, D]. Dropout applies to
     the attention weights, matching the reference; the Pallas kernel
     regenerates the dropout mask in-kernel from a counter RNG, so a nonzero
-    rate stays on the flash path (the masked path is still XLA)."""
+    rate stays on the flash path (the masked path is still XLA).
+    ``partition`` is the ``KernelPartition`` a sharded model's shard plan
+    recorded: the flash kernel then runs per shard (XLA partitions the
+    compositions itself)."""
     from ...core import generator
 
     q, k, v = ensure_tensor(query), ensure_tensor(key), ensure_tensor(value)
@@ -131,7 +133,7 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
             # precedence over is_causal — both paths must agree
             return flash_attention_fused(
                 q, k, v, causal=False, scale=scale,
-                dropout_p=p, rng=rng, key_bias=bias)
+                dropout_p=p, rng=rng, key_bias=bias, partition=partition)
         out = apply("sdpa_mask_p", q, k, v, m, rng,
                     scale=scale, dropout_p=p)
     elif _use_pallas(q, k) and p < 1.0:
@@ -140,7 +142,8 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
         from ...ops.pallas.flash_attention import flash_attention_fused
 
         out = flash_attention_fused(q, k, v, causal=bool(is_causal),
-                                    scale=scale, dropout_p=p, rng=rng)
+                                    scale=scale, dropout_p=p, rng=rng,
+                                    partition=partition)
     else:
         out = apply("sdpa_p", q, k, v, rng, causal=bool(is_causal),
                     scale=scale, dropout_p=p)

@@ -60,13 +60,10 @@ defprim("rms_norm_p", _rms_norm_fwd)
 
 
 def _use_pallas_rms(x) -> bool:
-    # mirror of ops/pallas/rms_norm.use_pallas_rms_norm, duplicated so the
-    # XLA fallback path never imports the pallas stack
-    from ...core.flags import get_flag
+    # the gate lives here so the XLA path never imports the pallas stack
+    from ...core.flags import get_flag, pallas_mode
 
-    if not get_flag("use_pallas_rms_norm"):
-        return False
-    if jax.default_backend() != "tpu" and not get_flag("pallas_force_interpret"):
+    if not get_flag("use_pallas_rms_norm") or pallas_mode() == "off":
         return False
     hidden = x.shape[-1]
     rows = 1
@@ -75,16 +72,20 @@ def _use_pallas_rms(x) -> bool:
     return hidden % 128 == 0 and rows % 8 == 0
 
 
-def rms_norm(x, weight, epsilon=1e-6, name=None):
+def rms_norm(x, weight, epsilon=1e-6, name=None, *, partition=None):
     """RMSNorm (reference: paddle.incubate.nn.functional.fused_rms_norm,
     phi/kernels/gpu/rms_norm_kernel.cu). Pallas fused kernel on TPU when the
-    hidden dim is lane-aligned; XLA composition otherwise."""
+    hidden dim is lane-aligned; XLA composition otherwise. ``partition`` is
+    the ``KernelPartition`` a sharded model's shard plan recorded (the
+    kernel then runs per shard; XLA partitions the composition itself)."""
     x = ensure_tensor(x)
     w = ensure_tensor(weight)
     if _use_pallas_rms(x):
         from ...ops.pallas import rms_norm as _  # registers the primitive
 
-        return apply("rms_norm_pallas_p", x, w, eps=float(epsilon))
+        statics = {} if partition is None else {"partition": partition}
+        return apply("rms_norm_pallas_p", x, w, eps=float(epsilon),
+                     **statics)
     return apply("rms_norm_p", x, w, eps=float(epsilon))
 
 

@@ -106,24 +106,19 @@ def _clear_watermarks():
 
 def default_peak_flops() -> float:
     """Peak chip FLOPs/s for MFU: ``PADDLE_TPU_PEAK_FLOPS`` env override,
-    else the v5e bf16 peak on TPU and a 1 TF/s nominal figure on CPU
-    (same convention as bench.py)."""
+    else the bf16 peak of the TPU this process runs on from the one
+    table (``device/chip.py``; an unknown chip raises), else — off-TPU,
+    where nothing is a device metric — a nominal 1 TF/s."""
     env = os.environ.get(PEAK_FLOPS_ENV)
     if env:
         try:
             return float(env)
         except ValueError:
             pass
-    try:
-        import jax
+    from ..device import chip
 
-        platforms = {d.platform for d in jax.devices()}
-        if "tpu" in platforms:
-            return 197e12
-        if platforms & {"gpu", "cuda", "rocm"}:
-            return 312e12  # A100 bf16 — the ROADMAP's comparison chip
-    except Exception:
-        pass
+    if chip.on_tpu():
+        return chip.chip_peaks()["bf16_flops_per_sec"]
     return 1e12
 
 

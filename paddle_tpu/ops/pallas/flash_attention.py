@@ -14,7 +14,14 @@ Layouts: public API uses paddle's [B, S, H, D]; kernels run [B, H, S, D].
 Compute is fp32 on the MXU (`preferred_element_type`), outputs cast back.
 
 On non-TPU backends the same kernels run under `interpret=True`, which is
-how the OpTest suite checks them against the XLA composition oracle.
+how the OpTest suite checks their arithmetic against the XLA composition
+oracle. The interpreter does not check that Mosaic accepts a kernel:
+tests/test_tpu_aot_compile.py compiles each one for the chip, and
+chip_smoke.py runs them there against the same oracle.
+
+In a sharded program each array-level function runs under shard_map over
+batch and head (`ops/kernel_partition.py`); sequence and head_dim stay
+whole per shard.
 """
 from __future__ import annotations
 
@@ -27,6 +34,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ...core import dispatch
+from ...core.flags import pallas_mode
+from ..kernel_partition import shard_kernel
 
 NEG_INF = float("-inf")
 Z = __import__("numpy").int32(0)  # index-map literal: stays i32 under jax_enable_x64
@@ -69,7 +78,7 @@ def _dropout_keep(seed, bh, i, j, block_q, block_k, rate):
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return pallas_mode() != "compiled"
 
 
 def _pick_block(n: int, target: int = 512) -> int:
@@ -185,14 +194,55 @@ def _fwd_kernel(*refs, scale, causal, block_q, block_k, nk, offset,
         lse_ref[0, 0] = jnp.broadcast_to(lse, lse_ref[0, 0].shape)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("causal", "scale", "dropout_rate"))
-def _flash_fwd_bhsd(q, k, v, seed=None, key_bias=None, *, causal, scale,
-                    dropout_rate=0.0):
-    """q: [B,H,Sq,D]; k,v: [B,Hkv,Sk,D] -> (out [B,H,Sq,D], lse [B,H,Sq]).
-    seed: int32 [1] dropout seed, required when dropout_rate > 0.
-    key_bias: [B, Sk] additive logit bias broadcast over heads/rows (the
-    padding-mask pattern), added BEFORE the causal mask/softmax."""
+# ---------------------------------------------------------------------------
+# sharded programs: batch and head are independent, sequence and head_dim
+# are not (see ops/kernel_partition.py)
+# ---------------------------------------------------------------------------
+def _run_flash(local, operands, results, arrays, *, partition, **statics):
+    """Call one shard-level flash function: directly, or under shard_map
+    when the program is sharded. ``operands``/``results`` name each
+    array's layout (x = q-shaped, k = kv-shaped, l = lse-shaped); the
+    optional [B|1, Sk] bias and [1] seed follow the operands."""
+    local = functools.partial(local, **statics)
+    if partition is None:
+        return local(*arrays)
+    q, k = arrays[0], arrays[1]
+    b_ax = partition.axis_if_divides(partition.batch, q.shape[0])
+    # heads split on kv-head boundaries: a shard holds whole GQA groups
+    h_ax = partition.axis_if_divides(partition.heads, k.shape[1])
+    specs = {"x": (b_ax, h_ax, None, None), "l": (b_ax, h_ax, None)}
+    specs["k"] = specs["x"]
+    in_specs = [specs[c] for c in operands]
+    if statics["has_bias"]:
+        bias = arrays[len(operands)]
+        in_specs.append((None if bias.shape[0] == 1 else b_ax, None))
+    if statics["rate"] > 0.0:
+        in_specs.append((None,))
+    local = functools.partial(
+        local, shard_axes=tuple(a for a in (b_ax, h_ax) if a is not None))
+    return shard_kernel(local, partition, in_specs,
+                        [specs[c] for c in results])(*arrays)
+
+
+def _split_extras(extras, has_bias, rate, shard_axes):
+    """(key_bias, seed) from the optional trailing operands. Inside a
+    partitioned program the dropout seed is offset by the shard's index,
+    so shards draw different masks (the kernels key the bits on LOCAL
+    batch/head indices); forward and backward apply the same offset."""
+    extras = list(extras)
+    key_bias = extras.pop(0) if has_bias else None
+    seed = extras.pop(0) if rate > 0.0 else None
+    if seed is not None and shard_axes:
+        seed = seed + (jax.lax.axis_index(shard_axes).astype(jnp.int32)
+                       * _c32(0x9E3779B1))
+    return key_bias, seed
+
+
+def _fwd_local(q, k, v, *extras, causal, scale, rate, has_bias, interpret,
+               shard_axes=()):
+    """One shard's forward: q [B,H,Sq,D]; k,v [B,Hkv,Sk,D] ->
+    (out [B,H,Sq,D], lse [B,H,Sq])."""
+    key_bias, seed = _split_extras(extras, has_bias, rate, shard_axes)
     B, H, Sq, D = q.shape
     Hkv, Sk = k.shape[1], k.shape[2]
     g = H // Hkv
@@ -204,7 +254,7 @@ def _flash_fwd_bhsd(q, k, v, seed=None, key_bias=None, *, causal, scale,
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal,
         block_q=block_q, block_k=block_k, nk=nk, offset=Sk - Sq,
-        rate=dropout_rate, n_heads=H, has_bias=key_bias is not None)
+        rate=rate, n_heads=H, has_bias=has_bias)
     in_specs = [
             pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, Z)),
             pl.BlockSpec((1, 1, block_k, D),
@@ -223,7 +273,7 @@ def _flash_fwd_bhsd(q, k, v, seed=None, key_bias=None, *, causal, scale,
         in_specs.append(pl.BlockSpec((1, 1, block_k), bmap))
         inputs.append(key_bias.reshape(key_bias.shape[0], 1,
                                        key_bias.shape[1]))
-    if dropout_rate > 0.0:
+    if rate > 0.0:
         in_specs.append(pl.BlockSpec((1,), lambda b, h, i, j: (Z,),
                                   memory_space=pltpu.SMEM))
         inputs.append(seed)
@@ -254,9 +304,35 @@ def _flash_fwd_bhsd(q, k, v, seed=None, key_bias=None, *, causal, scale,
             bytes_accessed=(q.size + k.size + v.size) * q.dtype.itemsize,
             transcendentals=B * H * Sq * Sk,
         ),
-        interpret=_interpret(),
+        name="flash_fwd",
+        interpret=interpret,
     )(*inputs)
     return out, lse[:, :, :, 0]
+
+
+_JIT_STATICS = ("causal", "scale", "dropout_rate", "partition", "interpret")
+
+
+@functools.partial(jax.jit, static_argnames=_JIT_STATICS)
+def _flash_fwd_jit(q, k, v, seed, key_bias, *, causal, scale, dropout_rate,
+                   partition, interpret):
+    extras = [x for x in (key_bias, seed) if x is not None]
+    return _run_flash(
+        _fwd_local, "xkk", "xl", (q, k, v, *extras), partition=partition,
+        causal=causal, scale=scale, rate=dropout_rate,
+        has_bias=key_bias is not None, interpret=interpret)
+
+
+def _flash_fwd_bhsd(q, k, v, seed=None, key_bias=None, *, causal, scale,
+                    dropout_rate=0.0, partition=None):
+    """q: [B,H,Sq,D]; k,v: [B,Hkv,Sk,D] -> (out [B,H,Sq,D], lse [B,H,Sq]).
+    seed: int32 [1] dropout seed, required when dropout_rate > 0.
+    key_bias: [B, Sk] additive logit bias broadcast over heads/rows (the
+    padding-mask pattern), added BEFORE the causal mask/softmax.
+    partition: the :class:`KernelPartition` of a sharded program."""
+    return _flash_fwd_jit(q, k, v, seed, key_bias, causal=causal,
+                          scale=scale, dropout_rate=dropout_rate,
+                          partition=partition, interpret=_interpret())
 
 
 # ---------------------------------------------------------------------------
@@ -399,10 +475,10 @@ def _bwd_dkv_kernel(*refs, scale, causal, block_q, block_k, nq, offset,
         dv_ref[0, 0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("causal", "scale", "dropout_rate"))
-def _flash_bwd_bhsd(q, k, v, out, lse, do, seed=None, key_bias=None, *,
-                    causal, scale, dropout_rate=0.0):
+def _bwd_local(q, k, v, out, lse, do, *extras, causal, scale, rate,
+               has_bias, interpret, shard_axes=()):
+    """One shard's backward -> (dq, dk, dv)."""
+    key_bias, seed = _split_extras(extras, has_bias, rate, shard_axes)
     B, H, Sq, D = q.shape
     Hkv, Sk = k.shape[1], k.shape[2]
     g = H // Hkv
@@ -418,7 +494,7 @@ def _flash_bwd_bhsd(q, k, v, out, lse, do, seed=None, key_bias=None, *,
     dq_kernel = functools.partial(
         _bwd_dq_kernel, scale=scale, causal=causal,
         block_q=block_q, block_k=block_k, nk=nk, offset=Sk - Sq,
-        rate=dropout_rate, n_heads=H, has_bias=key_bias is not None)
+        rate=rate, n_heads=H, has_bias=has_bias)
     dq_in_specs = [
             pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, Z)),
             pl.BlockSpec((1, 1, block_k, D),
@@ -438,7 +514,7 @@ def _flash_bwd_bhsd(q, k, v, out, lse, do, seed=None, key_bias=None, *,
         dq_in_specs.append(pl.BlockSpec((1, 1, block_k), bmap))
         dq_inputs.append(key_bias.reshape(key_bias.shape[0], 1,
                                           key_bias.shape[1]))
-    if dropout_rate > 0.0:
+    if rate > 0.0:
         dq_in_specs.append(pl.BlockSpec((1,), lambda b, h, i, j: (Z,),
                                   memory_space=pltpu.SMEM))
         dq_inputs.append(seed)
@@ -454,13 +530,14 @@ def _flash_bwd_bhsd(q, k, v, out, lse, do, seed=None, key_bias=None, *,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
-        interpret=_interpret(),
+        name="flash_bwd_dq",
+        interpret=interpret,
     )(*dq_inputs)
 
     dkv_kernel = functools.partial(
         _bwd_dkv_kernel, scale=scale, causal=causal,
         block_q=block_q, block_k=block_k, nq=nq, offset=Sk - Sq,
-        rate=dropout_rate, n_heads=H, has_bias=key_bias is not None)
+        rate=rate, n_heads=H, has_bias=has_bias)
     dkv_in_specs = [
             pl.BlockSpec((1, 1, block_q, D), lambda b, h, j, i: (b, h, i, Z)),
             pl.BlockSpec((1, 1, block_k, D),
@@ -481,7 +558,7 @@ def _flash_bwd_bhsd(q, k, v, out, lse, do, seed=None, key_bias=None, *,
         dkv_in_specs.append(pl.BlockSpec((1, 1, block_k), bmap))
         dkv_inputs.append(key_bias.reshape(key_bias.shape[0], 1,
                                            key_bias.shape[1]))
-    if dropout_rate > 0.0:
+    if rate > 0.0:
         dkv_in_specs.append(pl.BlockSpec((1,), lambda b, h, i, j: (Z,),
                                   memory_space=pltpu.SMEM))
         dkv_inputs.append(seed)
@@ -506,7 +583,8 @@ def _flash_bwd_bhsd(q, k, v, out, lse, do, seed=None, key_bias=None, *,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
-        interpret=_interpret(),
+        name="flash_bwd_dkv",
+        interpret=interpret,
     )(*dkv_inputs)
     if g > 1:
         dk = dk_h.reshape(B, Hkv, g, Sk, D).sum(axis=2).astype(k.dtype)
@@ -516,11 +594,29 @@ def _flash_bwd_bhsd(q, k, v, out, lse, do, seed=None, key_bias=None, *,
     return dq, dk, dv
 
 
+@functools.partial(jax.jit, static_argnames=_JIT_STATICS)
+def _flash_bwd_jit(q, k, v, out, lse, do, seed, key_bias, *, causal, scale,
+                   dropout_rate, partition, interpret):
+    extras = [x for x in (key_bias, seed) if x is not None]
+    return _run_flash(
+        _bwd_local, "xkkxlx", "xkk", (q, k, v, out, lse, do, *extras),
+        partition=partition, causal=causal, scale=scale, rate=dropout_rate,
+        has_bias=key_bias is not None, interpret=interpret)
+
+
+def _flash_bwd_bhsd(q, k, v, out, lse, do, seed=None, key_bias=None, *,
+                    causal, scale, dropout_rate=0.0, partition=None):
+    return _flash_bwd_jit(q, k, v, out, lse, do, seed, key_bias,
+                          causal=causal, scale=scale,
+                          dropout_rate=dropout_rate, partition=partition,
+                          interpret=_interpret())
+
+
 # ---------------------------------------------------------------------------
 # array-level API (paddle [B, S, H, D] layout) + primitive registration
 # ---------------------------------------------------------------------------
 def flash_attention_bshd(q, k, v, *extras, causal=False, scale=None,
-                         dropout_rate=0.0, has_bias=False):
+                         dropout_rate=0.0, has_bias=False, partition=None):
     """Array-level flash attention in paddle layout. Returns (out, lse).
 
     ``extras`` holds the optional inputs IN ORDER: ``key_bias`` ([B, Sk]
@@ -537,12 +633,13 @@ def flash_attention_bshd(q, k, v, *extras, causal=False, scale=None,
     vt = jnp.swapaxes(v, 1, 2)
     out, lse = _flash_fwd_bhsd(qt, kt, vt, seed, key_bias, causal=causal,
                                scale=float(scale),
-                               dropout_rate=float(dropout_rate))
+                               dropout_rate=float(dropout_rate),
+                               partition=partition)
     return jnp.swapaxes(out, 1, 2), lse
 
 
 def _flash_vjp(grads_out, saved, *, causal, scale, dropout_rate=0.0,
-               has_bias=False):
+               has_bias=False, partition=None):
     *ins, out, lse = saved
     q, k, v = ins[:3]
     rest = list(ins[3:])
@@ -553,7 +650,8 @@ def _flash_vjp(grads_out, saved, *, causal, scale, dropout_rate=0.0,
     ot, dot = jnp.swapaxes(out, 1, 2), jnp.swapaxes(do, 1, 2)
     dq, dk, dv = _flash_bwd_bhsd(qt, kt, vt, ot, lse, dot, seed, key_bias,
                                  causal=causal, scale=float(scale),
-                                 dropout_rate=float(dropout_rate))
+                                 dropout_rate=float(dropout_rate),
+                                 partition=partition)
     grads = (jnp.swapaxes(dq, 1, 2), jnp.swapaxes(dk, 1, 2),
              jnp.swapaxes(dv, 1, 2))
     # optional inputs (bias, seed) take no grads: the bias is a mask
@@ -572,19 +670,24 @@ dispatch.register_primitive(
 
 
 def flash_attention_fused(q, k, v, *, causal=False, scale=None,
-                          dropout_p=0.0, rng=None, key_bias=None):
+                          dropout_p=0.0, rng=None, key_bias=None,
+                          partition=None):
     """Tensor-level entry used by nn.functional.scaled_dot_product_attention.
     Returns the attention output Tensor (lse is kept for backward only).
     ``dropout_p`` > 0 requires ``rng`` (a Tensor wrapping a jax PRNG key);
     the key is folded to an int32 seed for the in-kernel counter RNG.
     ``key_bias`` is a [B, Sk] additive logit bias Tensor (the padding-mask
-    pattern), broadcast over heads and query rows inside the kernel."""
+    pattern), broadcast over heads and query rows inside the kernel.
+    ``partition`` is the :class:`KernelPartition` of a sharded model; it
+    reaches the backward kernels as a primitive static."""
     from ...core.tensor import Tensor, apply
 
     scale = (float(scale) if scale is not None
              else 1.0 / math.sqrt(q.shape[-1]))
     extras = []
     statics = dict(causal=bool(causal), scale=scale)
+    if partition is not None:
+        statics["partition"] = partition
     if key_bias is not None:
         if not getattr(key_bias, "stop_gradient", True):
             raise ValueError(

@@ -65,13 +65,40 @@ def _seg_vectors(cu_q, cu_k, t_q, t_k, pad_q, pad_k, n_seqs):
     return seg_q, seg_k, bound
 
 
+def _seg_operands(cu_q, cu_k, Tq, Tk, n_seqs):
+    """The segment vectors as the 2-D operands the kernels block:
+    per-q-row values lane-broadcast to [Tq, LANES], per-k-column values
+    sublane-broadcast to [8, Tk]. A 1-D int32 operand cannot be blocked
+    at (block,): XLA tiles it T(1024) and Mosaic wants the block's own
+    tiling, so the chip refuses the call at T=8192. The 2-D forms also
+    hand the kernel a column and a row without an in-kernel relayout."""
+    seg_q, seg_k, bound = _seg_vectors(
+        cu_q, cu_k, cu_q[-1], cu_k[-1], Tq, Tk, n_seqs)
+    return (jnp.broadcast_to(seg_q[:, None], (Tq, LANES)),
+            jnp.broadcast_to(seg_k[None, :], (8, Tk)),
+            jnp.broadcast_to(bound[:, None], (Tq, LANES)))
+
+
+def _seg_specs(block_q, block_k, q_map, k_map):
+    """BlockSpecs of (seg_q, seg_k, bound); ``q_map``/``k_map`` pick the
+    q / k block index out of the grid indices."""
+    rows = pl.BlockSpec((block_q, LANES), lambda *g: (q_map(*g), Z))
+    cols = pl.BlockSpec((8, block_k), lambda *g: (Z, k_map(*g)))
+    return [rows, cols, rows]
+
+
+def _load_segs(segq_ref, segk_ref, bound_ref):
+    """(sq [bq, 1], sk [1, bk], bound [bq, 1]) from the broadcast blocks."""
+    return segq_ref[:, :1], segk_ref[:1, :], bound_ref[:, :1]
+
+
 def _mask_for(sq, sk, bound, j, block_k, causal):
-    """[bq, bk] validity mask from per-row segment vectors."""
-    same = sq[:, None] == sk[None, :]
+    """[bq, bk] validity mask from the segment column/row."""
+    same = sq == sk
     if causal:
         cols = j * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (sq.shape[0], block_k), 1)
-        same = same & (cols <= bound[:, None])
+        same = same & (cols <= bound)
     return same
 
 
@@ -106,9 +133,7 @@ def _vfwd_kernel(*refs, scale, causal, block_q, block_k, nk, rate):
         l_scr[:] = jnp.zeros(l_scr.shape, jnp.float32)
         acc_scr[:] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-    sq = segq_ref[:]
-    sk = segk_ref[:]
-    bound = bound_ref[:]
+    sq, sk, bound = _load_segs(segq_ref, segk_ref, bound_ref)
 
     @pl.when(~_skip_block(sq, sk, bound, j, block_k, causal))
     def _compute():
@@ -154,9 +179,9 @@ def _vfwd_kernel(*refs, scale, causal, block_q, block_k, nk, rate):
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "scale", "n_seqs",
-                                              "dropout_rate"))
+                                              "dropout_rate", "interpret"))
 def _vflash_fwd(q, k, v, cu_q, cu_k, seed=None, *, causal, scale, n_seqs,
-                dropout_rate=0.0):
+                dropout_rate=0.0, interpret):
     """q: [H, Tq, D]; k, v: [Hkv, Tk, D] (already padded to block
     multiples); returns (out [H, Tq, D], lse [H, Tq])."""
     H, Tq, D = q.shape
@@ -166,8 +191,7 @@ def _vflash_fwd(q, k, v, cu_q, cu_k, seed=None, *, causal, scale, n_seqs,
     block_k = _pick_block(Tk)
     nq, nk = Tq // block_q, Tk // block_k
     kv_head = _kv_head_map(g)
-    seg_q, seg_k, bound = _seg_vectors(
-        cu_q, cu_k, cu_q[-1], cu_k[-1], Tq, Tk, n_seqs)
+    seg_q, seg_k, bound = _seg_operands(cu_q, cu_k, Tq, Tk, n_seqs)
     kernel = functools.partial(
         _vfwd_kernel, scale=scale, causal=causal,
         block_q=block_q, block_k=block_k, nk=nk, rate=dropout_rate)
@@ -175,9 +199,8 @@ def _vflash_fwd(q, k, v, cu_q, cu_k, seed=None, *, causal, scale, n_seqs,
             pl.BlockSpec((1, block_q, D), lambda h, i, j: (h, i, Z)),
             pl.BlockSpec((1, block_k, D), lambda h, i, j: (kv_head(h), j, Z)),
             pl.BlockSpec((1, block_k, D), lambda h, i, j: (kv_head(h), j, Z)),
-            pl.BlockSpec((block_q,), lambda h, i, j: (i,)),
-            pl.BlockSpec((block_k,), lambda h, i, j: (j,)),
-            pl.BlockSpec((block_q,), lambda h, i, j: (i,)),
+            *_seg_specs(block_q, block_k,
+                        lambda h, i, j: i, lambda h, i, j: j),
     ]
     inputs = [q, k, v, seg_q, seg_k, bound]
     if dropout_rate > 0.0:
@@ -204,7 +227,8 @@ def _vflash_fwd(q, k, v, cu_q, cu_k, seed=None, *, causal, scale, n_seqs,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        interpret=_interpret(),
+        name="flash_varlen_fwd",
+        interpret=interpret,
     )(*inputs)
     return out, lse[:, :, 0]
 
@@ -228,9 +252,7 @@ def _vbwd_dq_kernel(*refs, scale, causal, block_q, block_k, nk, rate):
     def _init():
         dq_scr[:] = jnp.zeros(dq_scr.shape, jnp.float32)
 
-    sq = segq_ref[:]
-    sk = segk_ref[:]
-    bound = bound_ref[:]
+    sq, sk, bound = _load_segs(segq_ref, segk_ref, bound_ref)
 
     @pl.when(~_skip_block(sq, sk, bound, j, block_k, causal))
     def _compute():
@@ -281,9 +303,7 @@ def _vbwd_dkv_kernel(*refs, scale, causal, block_q, block_k, nq, rate):
         dk_scr[:] = jnp.zeros(dk_scr.shape, jnp.float32)
         dv_scr[:] = jnp.zeros(dv_scr.shape, jnp.float32)
 
-    sq = segq_ref[:]
-    sk = segk_ref[:]
-    bound = bound_ref[:]
+    sq, sk, bound = _load_segs(segq_ref, segk_ref, bound_ref)
 
     @pl.when(~_skip_block(sq, sk, bound, j, block_k, causal))
     def _compute():
@@ -324,9 +344,9 @@ def _vbwd_dkv_kernel(*refs, scale, causal, block_q, block_k, nq, rate):
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "scale", "n_seqs",
-                                              "dropout_rate"))
+                                              "dropout_rate", "interpret"))
 def _vflash_bwd(q, k, v, cu_q, cu_k, out, lse, do, seed=None, *, causal,
-                scale, n_seqs, dropout_rate=0.0):
+                scale, n_seqs, dropout_rate=0.0, interpret):
     H, Tq, D = q.shape
     Hkv, Tk = k.shape[0], k.shape[1]
     g = H // Hkv
@@ -334,8 +354,7 @@ def _vflash_bwd(q, k, v, cu_q, cu_k, out, lse, do, seed=None, *, causal,
     block_k = _pick_block(Tk)
     nq, nk = Tq // block_q, Tk // block_k
     kv_head = _kv_head_map(g)
-    seg_q, seg_k, bound = _seg_vectors(
-        cu_q, cu_k, cu_q[-1], cu_k[-1], Tq, Tk, n_seqs)
+    seg_q, seg_k, bound = _seg_operands(cu_q, cu_k, Tq, Tk, n_seqs)
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
     lse_p = jnp.broadcast_to(lse[..., None], (H, Tq, LANES))
     delta_p = jnp.broadcast_to(delta[..., None], (H, Tq, LANES))
@@ -347,9 +366,8 @@ def _vflash_bwd(q, k, v, cu_q, cu_k, out, lse, do, seed=None, *, causal,
             pl.BlockSpec((1, block_q, D), lambda h, i, j: (h, i, Z)),
             pl.BlockSpec((1, block_q, LANES), lambda h, i, j: (h, i, Z)),
             pl.BlockSpec((1, block_q, LANES), lambda h, i, j: (h, i, Z)),
-            pl.BlockSpec((block_q,), lambda h, i, j: (i,)),
-            pl.BlockSpec((block_k,), lambda h, i, j: (j,)),
-            pl.BlockSpec((block_q,), lambda h, i, j: (i,)),
+            *_seg_specs(block_q, block_k,
+                        lambda h, i, j: i, lambda h, i, j: j),
     ]
     dq_inputs = [q, k, v, do, lse_p, delta_p, seg_q, seg_k, bound]
     if dropout_rate > 0.0:
@@ -368,7 +386,8 @@ def _vflash_bwd(q, k, v, cu_q, cu_k, out, lse, do, seed=None, *, causal,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        interpret=_interpret(),
+        name="flash_varlen_bwd_dq",
+        interpret=interpret,
     )(*dq_inputs)
 
     dkv_in_specs = [
@@ -378,9 +397,8 @@ def _vflash_bwd(q, k, v, cu_q, cu_k, out, lse, do, seed=None, *, causal,
             pl.BlockSpec((1, block_q, D), lambda h, j, i: (h, i, Z)),
             pl.BlockSpec((1, block_q, LANES), lambda h, j, i: (h, i, Z)),
             pl.BlockSpec((1, block_q, LANES), lambda h, j, i: (h, i, Z)),
-            pl.BlockSpec((block_q,), lambda h, j, i: (i,)),
-            pl.BlockSpec((block_k,), lambda h, j, i: (j,)),
-            pl.BlockSpec((block_q,), lambda h, j, i: (i,)),
+            *_seg_specs(block_q, block_k,
+                        lambda h, j, i: i, lambda h, j, i: j),
     ]
     dkv_inputs = [q, k, v, do, lse_p, delta_p, seg_q, seg_k, bound]
     if dropout_rate > 0.0:
@@ -408,7 +426,8 @@ def _vflash_bwd(q, k, v, cu_q, cu_k, out, lse, do, seed=None, *, causal,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        interpret=_interpret(),
+        name="flash_varlen_bwd_dkv",
+        interpret=interpret,
     )(*dkv_inputs)
     if g > 1:
         dk = dk_h.reshape(Hkv, g, Tk, D).sum(axis=1).astype(k.dtype)
@@ -450,7 +469,8 @@ def flash_attn_varlen_thd(q, k, v, cu_q, cu_k, seed=None, *, causal=False,
     vh = _to_htd(v, pad_k)
     out, lse = _vflash_fwd(qh, kh, vh, cu_q, cu_k, seed, causal=bool(causal),
                            scale=float(scale), n_seqs=int(n_seqs),
-                           dropout_rate=float(dropout_rate))
+                           dropout_rate=float(dropout_rate),
+                           interpret=_interpret())
     return jnp.swapaxes(out[:, :Tq], 0, 1), lse
 
 
@@ -476,7 +496,7 @@ def _varlen_vjp(grads_out, saved, *, causal, scale, n_seqs,
         _to_htd(q, pad_q), _to_htd(k, pad_k), _to_htd(v, pad_k),
         cu_q, cu_k, _to_htd(out, pad_q), lse, _to_htd(do, pad_q), seed,
         causal=causal, scale=float(scale), n_seqs=int(n_seqs),
-        dropout_rate=float(dropout_rate))
+        dropout_rate=float(dropout_rate), interpret=_interpret())
     grads = (jnp.swapaxes(dq[:, :Tq], 0, 1), jnp.swapaxes(dk[:, :Tk], 0, 1),
              jnp.swapaxes(dv[:, :Tk], 0, 1), None, None)
     if seed is not None:

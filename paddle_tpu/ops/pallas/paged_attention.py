@@ -39,7 +39,10 @@ this way; ``_bmha_fwd``'s ``[nb, kvh, bs, dh]`` transposes into it).
 CPU CI runs :func:`paged_attention_decode_reference` — the same masked
 softmax as a plain jnp gather program — or the kernel itself under
 ``interpret=True`` (tests/test_paged_attention_kernel.py pins kernel ==
-reference == the block_mha gather path).
+reference == the block_mha gather path). The interpreter does not check
+that Mosaic accepts the kernel (it let int64 index-map literals through):
+tests/test_tpu_aot_compile.py compiles it for the chip and chip_smoke.py
+runs it there against the reference.
 """
 from __future__ import annotations
 
@@ -50,10 +53,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...core.flags import pallas_mode
+from .flash_attention import Z
+
 __all__ = [
     "paged_attention_decode",
     "paged_attention_decode_reference",
     "paged_attention_decode_kernel",
+    "resolve_backend",
 ]
 
 
@@ -180,17 +187,17 @@ def paged_attention_decode_kernel(q, k_pages, v_pages, lengths,
         valid_pages = jax.lax.div(len_ref[bi] + (page - 1),
                                   jnp.int32(page))
         pi = jnp.minimum(i, jnp.maximum(valid_pages - 1, 0))
-        return (0, tbl_ref[bi, pi], 0, 0)
+        return (Z, tbl_ref[bi, pi], Z, Z)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, pps),
         in_specs=[
-            pl.BlockSpec((1, nh, dh), lambda bi, i, *_: (bi, 0, 0)),
+            pl.BlockSpec((1, nh, dh), lambda bi, i, *_: (bi, Z, Z)),
             pl.BlockSpec((kvh, 1, page, dh), page_map),
             pl.BlockSpec((kvh, 1, page, dh), page_map),
         ],
-        out_specs=pl.BlockSpec((1, nh, dh), lambda bi, i, *_: (bi, 0, 0)),
+        out_specs=pl.BlockSpec((1, nh, dh), lambda bi, i, *_: (bi, Z, Z)),
         scratch_shapes=[
             pltpu.VMEM((kvh, group), jnp.float32),
             pltpu.VMEM((kvh, group), jnp.float32),
@@ -203,8 +210,22 @@ def paged_attention_decode_kernel(q, k_pages, v_pages, lengths,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, nh, dh), q.dtype),
+        name="paged_decode",
         interpret=interpret,
     )(lengths, block_tables, q, k_pages, v_pages)
+
+
+def resolve_backend(backend: str = "auto") -> str:
+    """The path ``backend`` names: ``"auto"`` is the compiled kernel
+    where Pallas kernels compile (``pallas_mode() == "compiled"``) and
+    the jnp reference elsewhere; the explicit names pass through."""
+    if backend == "auto":
+        return "kernel" if pallas_mode() == "compiled" else "reference"
+    if backend not in ("kernel", "reference", "interpret"):
+        raise ValueError(
+            f"paged_attention_decode: unknown backend {backend!r} "
+            f"(use 'auto', 'kernel', 'reference' or 'interpret')")
+    return backend
 
 
 def paged_attention_decode(q, k_pages, v_pages, lengths, block_tables, *,
@@ -220,22 +241,16 @@ def paged_attention_decode(q, k_pages, v_pages, lengths, block_tables, *,
         (including the just-written token). Length 0 rows (inactive
         serving slots) return zeros instead of NaN.
       block_tables: ``[B, pages_per_seq]`` int32 physical page ids.
-      backend: ``"auto"`` (kernel on TPU, jnp reference elsewhere),
-        ``"kernel"``, ``"reference"``, or ``"interpret"`` (kernel under
-        the Pallas interpreter — the CPU-CI equivalence path).
+      backend: ``"auto"`` (see :func:`resolve_backend`), ``"kernel"``,
+        ``"reference"``, or ``"interpret"`` (kernel under the Pallas
+        interpreter — the CPU-CI equivalence path).
 
     Returns ``[B, NH, DH]`` in q.dtype.
     """
-    if backend == "auto":
-        backend = ("kernel" if jax.default_backend() == "tpu"
-                   else "reference")
+    backend = resolve_backend(backend)
     if backend == "reference":
         return paged_attention_decode_reference(
             q, k_pages, v_pages, lengths, block_tables, sm_scale=sm_scale)
-    if backend in ("kernel", "interpret"):
-        return paged_attention_decode_kernel(
-            q, k_pages, v_pages, lengths, block_tables, sm_scale=sm_scale,
-            interpret=(backend == "interpret"))
-    raise ValueError(
-        f"paged_attention_decode: unknown backend {backend!r} "
-        f"(use 'auto', 'kernel', 'reference' or 'interpret')")
+    return paged_attention_decode_kernel(
+        q, k_pages, v_pages, lengths, block_tables, sm_scale=sm_scale,
+        interpret=(backend == "interpret"))

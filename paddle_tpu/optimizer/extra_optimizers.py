@@ -167,7 +167,7 @@ class NAdam(Optimizer):
             self._accum("moment1", p)
             self._accum("moment2", p)
             self._accum("mu_product", p,
-                        init=jnp.ones(p._value.shape, jnp.float32))
+                        init=jnp.ones_like(p._value, dtype=jnp.float32))
             self._master(p)
 
     def _update_param(self, p, grad, lr):
@@ -181,7 +181,8 @@ class NAdam(Optimizer):
         # seeded to ones at creation; never use 0 as an init sentinel (the
         # product legitimately underflows toward 0 late in training)
         mu_prod_prev = self._accum(
-            "mu_product", p, init=jnp.ones(p._value.shape, jnp.float32))
+            "mu_product", p,
+            init=jnp.ones_like(p._value, dtype=jnp.float32))
         mu_prod = mu_prod_prev * mu_t
         self._set_accum("mu_product", p, mu_prod)
         m = self._accum("moment1", p)

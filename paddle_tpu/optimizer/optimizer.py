@@ -86,8 +86,12 @@ class Optimizer:
     def _accum(self, name: str, p: Parameter, init=None):
         store = self._accumulators[name]
         if id(p) not in store:
+            # zeros_like, not zeros(shape): the accumulator takes its
+            # parameter's sharding, so the state of a sharded parameter
+            # is never whole on one device
             store[id(p)] = (
-                jnp.zeros(p._value.shape, jnp.float32) if init is None else init
+                jnp.zeros_like(p._value, dtype=jnp.float32)
+                if init is None else init
             )
         return store[id(p)]
 

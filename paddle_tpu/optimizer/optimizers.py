@@ -238,7 +238,7 @@ class Adagrad(Optimizer):
     def _update_param(self, p, grad, lr):
         m = self._accum(
             "moment", p,
-            init=jnp.full(p._value.shape, self._init_acc, jnp.float32),
+            init=jnp.full_like(p._value, self._init_acc, dtype=jnp.float32),
         )
         master = self._master(p)
         p32 = master if master is not None else p._value.astype(jnp.float32)

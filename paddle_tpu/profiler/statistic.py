@@ -79,7 +79,10 @@ def op_class(base_name: str) -> str:
         return "convolution"
     if "dot" in n or "matmul" in n or "gemm" in n:
         return "matmul"
-    if n.startswith("_") or "custom-call" in n:
+    if (n.startswith(("_", "flash_", "rms_norm_", "paged_"))
+            or "custom-call" in n):
+        # the repo's kernels carry their pallas_call name= as the HLO
+        # instruction name (flash_fwd.3, rms_norm_bwd.1, paged_decode.2)
         return "custom-call (pallas)"
     if n.startswith(("copy", "slice", "async-copy", "dynamic-slice",
                      "dynamic-update-slice", "bitcast", "transpose",

@@ -250,7 +250,11 @@ class ServeEngine:
         self._clock = clock if clock is not None else time.perf_counter
         self.max_blocks_per_seq = -(-self.max_seq_len // self.block_size)
         self.pool = BlockPool(num_blocks, block_size)
-        self._backend = attention_backend
+        from ..ops.pallas.paged_attention import resolve_backend
+
+        #: the attention path every compiled step of this engine takes:
+        #: "kernel" (Mosaic-compiled), "interpret" or "reference"
+        self.attention_backend = resolve_backend(attention_backend)
 
         self._static = {k: v for k, v in p.items()
                         if not hasattr(v, "dtype")
@@ -1035,7 +1039,7 @@ class ServeEngine:
         def attn(q, _k, _v, kc, vc):
             return paged_attention_decode(
                 q, kc, vc, lengths, tables,
-                backend=self._backend).reshape(b, nh * self._dh)
+                backend=self.attention_backend).reshape(b, nh * self._dh)
 
         out, new_caches = self._stack_layers(p, x, rope, caches,
                                              safe_slot, attn)
@@ -1180,7 +1184,7 @@ class ServeEngine:
         def attn(q, _k, _v, kc, vc):
             return paged_attention_decode(
                 q, kc, vc, lengths, tables_rep,
-                backend=self._backend).reshape(tp, nh * dh)
+                backend=self.attention_backend).reshape(tp, nh * dh)
 
         out, new_caches = self._stack_layers(p, x, rope, caches,
                                              safe_slot, attn)

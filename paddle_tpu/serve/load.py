@@ -81,14 +81,20 @@ def warm_engine(engine: ServeEngine, max_prompt_len=None):
         lens.append(b)              # a len-b prompt fills bucket b exactly
         b *= 2
     lens.append(cap)                # the final (possibly capped) bucket
-    for n in dict.fromkeys(lens):
-        if n < 1:
-            continue
+    for i, n in enumerate(n for n in dict.fromkeys(lens) if n >= 1):
+        # the first token comes out of the prefill, so a one-token
+        # request never runs a decode step: the first (shortest) warm
+        # request asks for two, which compiles the decode step too
         req = engine.submit(np.arange(n) % (vocab - 1) + 1,
-                            max_new_tokens=1, warmup=True)
+                            max_new_tokens=2 if i == 0 else 1, warmup=True)
         engine.run()
         if req.state != "FINISHED":   # pragma: no cover — engine contract
             raise RuntimeError("warm-up request did not finish")
+        if engine._prefix is not None:
+            # the warm prompts all start alike: forget this one, or the
+            # next would mount its blocks and prefill only a suffix,
+            # leaving its own cold bucket to compile inside the load
+            engine._prefix.reset(engine.pool)
     if engine._prefix is not None:
         # suffix-prefill buckets: a prompt that shares its first block
         # with a resident one prefills only the suffix, which buckets

@@ -174,3 +174,28 @@ def test_tuple_samples_tensorized():
     x, y = next(iter(dl))
     assert isinstance(x, paddle.Tensor) and isinstance(y, paddle.Tensor)
     assert list(x.shape) == [4, 4] and list(y.shape) == [4]
+
+
+class PlatformProbeDataset(Dataset):
+    """Reports the JAX platform pin each worker process was started with."""
+
+    def __getitem__(self, idx):
+        import os
+
+        return np.int64(os.environ.get("JAX_PLATFORMS") == "cpu")
+
+    def __len__(self):
+        return 8
+
+
+def test_workers_cannot_open_the_accelerator(monkeypatch):
+    """The chip belongs to the training process. Workers are spawned
+    with JAX_PLATFORMS=cpu whatever the parent runs on, and the parent's
+    own environment is left as it was."""
+    import os
+
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    dl = DataLoader(PlatformProbeDataset(), batch_size=4, num_workers=2)
+    pinned = np.concatenate([b.numpy() for b in dl])
+    assert pinned.tolist() == [1] * 8
+    assert "JAX_PLATFORMS" not in os.environ

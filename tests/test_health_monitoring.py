@@ -286,13 +286,41 @@ class TestBenchCompare:
         p.write_text(json.dumps(rows))
         return str(p)
 
-    def test_real_records_r04_to_r05_pass(self, capsys):
+    def test_driver_record_pair_within_noise_passes(self, tmp_path,
+                                                    capsys):
+        """Two records in the driver's format — metric lines riding a
+        truncated ``tail`` between log lines, a cell moving inside the
+        noise band, a cell that only the newer record has — pass."""
         bc = _load_tool("bench_compare")
-        r04 = os.path.join(REPO_ROOT, "BENCH_r04.json")
-        r05 = os.path.join(REPO_ROOT, "BENCH_r05.json")
-        if not (os.path.exists(r04) and os.path.exists(r05)):
-            pytest.skip("BENCH records not present")
-        assert bc.main([r04, r05]) == 0
+
+        def record(n, rows):
+            lines = ['e": {"truncated head of an earlier line"}}']
+            for metric, value, unit in rows:
+                lines.append(json.dumps(
+                    {"metric": metric, "value": value, "unit": unit,
+                     "vs_baseline": 1.0}))
+                lines.append("WARNING:2026-01-01 00:00:00,000:jax: a log "
+                             "line between the metric lines")
+            return self._write(tmp_path, f"BENCH_r{n:02d}.json", {
+                "n": n, "cmd": "python bench.py", "rc": 0,
+                "tail": "\n".join(lines)})
+
+        old = record(4, [
+            ("resnet50 train images/sec/chip (bs=256)", 2129.8,
+             "images/sec/chip"),
+            ("moe-474M step time (bs=4 seq=4096)", 165.8, "ms/step"),
+            ("llama-645M pretrain tokens/sec/chip (bs=4)", 31386.0,
+             "tokens/sec/chip")])
+        new = record(5, [
+            ("resnet50 train images/sec/chip (bs=256)", 2087.8,
+             "images/sec/chip"),                          # -2.0%: noise
+            ("moe-474M step time (bs=4 seq=4096)", 165.9, "ms/step"),
+            ("decode-645M greedy tokens/sec/chip (bs=8)", 3995.0,
+             "tokens/sec/chip"),                          # new cell
+            ("llama-645M pretrain tokens/sec/chip (bs=4)", 31389.5,
+             "tokens/sec/chip")])
+        assert bc.latest_bench_records(str(tmp_path)) == [old, new]
+        assert bc.main([old, new]) == 0
         assert "0 regression(s)" in capsys.readouterr().out
 
     def test_constructed_regression_exits_nonzero(self, tmp_path,

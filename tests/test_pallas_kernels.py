@@ -738,3 +738,94 @@ class TestFlashKeyBias:
         gB = _flash_bwd_bhsd(q, k, v, oB, lB, do, None, biasB, **kw)
         for a, b in zip(g1, gB):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# ---------------------------------------------------------------------------
+# sharded programs: the kernels run per shard (ops/kernel_partition.py)
+# ---------------------------------------------------------------------------
+class TestShardedProgram:
+    """A Mosaic kernel cannot be partitioned by XLA, so a model sharded
+    by ``llama_shard_plan`` runs its kernels under shard_map. On the CPU
+    mesh the same wrapping runs the interpreter: the dp2 x mp2 step must
+    equal the unsharded one."""
+
+    def _step(self, sharded):
+        import paddle_tpu.distributed as dist
+        import paddle_tpu.optimizer as opt
+        from paddle_tpu.models import (LlamaConfig, LlamaForCausalLM,
+                                       llama_shard_plan)
+
+        paddle.seed(7)
+        cfg = LlamaConfig.tiny(
+            vocab_size=256, hidden_size=128, intermediate_size=256,
+            num_hidden_layers=1, num_attention_heads=2,
+            num_key_value_heads=2, max_position_embeddings=128)
+        model = LlamaForCausalLM(cfg)
+        ids_np = np.random.RandomState(0).randint(
+            0, 256, (4, 128)).astype("int64")
+        if sharded:
+            mesh = dist.ProcessMesh(np.arange(4).reshape(2, 2),
+                                    ["dp", "mp"])
+            llama_shard_plan(model, mesh)
+            ids = dist.shard_tensor(ids_np, mesh,
+                                    [dist.Shard(0), dist.Replicate()])
+        else:
+            ids = paddle.to_tensor(ids_np)
+        optimizer = opt.AdamW(learning_rate=1e-2,
+                              parameters=model.parameters())
+
+        @paddle.jit.to_static(full_graph=True)
+        def train_step(ids, labels):
+            loss, _ = model(ids, labels=labels)
+            loss.backward()
+            optimizer.step()
+            optimizer.clear_grad()
+            return loss
+
+        losses = [float(train_step(ids, ids)) for _ in range(3)]
+        return losses, train_step, model, optimizer
+
+    def test_sharded_step_equals_unsharded(self):
+        from paddle_tpu.core import flags
+
+        flags.set_flags({"pallas_force_interpret": True})
+        try:
+            ref, _, _, _ = self._step(False)
+            got, step, _, _ = self._step(True)
+            text = step.lowered()[0].as_text()
+        finally:
+            flags.set_flags({"pallas_force_interpret": False})
+        np.testing.assert_allclose(got, ref, rtol=2e-4)
+        assert ref[-1] < ref[0]
+        # the kernels really were inside shard_map on per-shard shapes
+        # (head dim 64: [B/dp, H/mp, S, D] = [2, 1, 128, 64])
+        assert "2x1x128x64" in text
+
+    def test_adam_moments_take_parameter_sharding(self):
+        """No accumulator of a sharded parameter may be whole on one
+        device — before the first step as well as after it."""
+        import paddle_tpu.distributed as dist
+        import paddle_tpu.optimizer as opt
+        from paddle_tpu.models import (LlamaConfig, LlamaForCausalLM,
+                                       llama_shard_plan)
+
+        model = LlamaForCausalLM(LlamaConfig.tiny(
+            vocab_size=256, hidden_size=128, intermediate_size=256,
+            num_hidden_layers=1, num_attention_heads=2,
+            num_key_value_heads=2))
+        model.bfloat16()
+        mesh = dist.ProcessMesh(np.arange(4).reshape(2, 2), ["dp", "mp"])
+        llama_shard_plan(model, mesh)
+        optimizer = opt.AdamW(learning_rate=1e-2,
+                              parameters=model.parameters(),
+                              multi_precision=True)
+        optimizer._ensure_accumulators()
+        w = model.llama.layers[0].self_attn.q_proj.weight
+        assert len(w._value.sharding.device_set) == 4
+        stores = dict(optimizer._accumulators,
+                      master=optimizer._master_weights)
+        for name in ("moment1", "moment2", "master"):
+            acc = stores[name][id(w)]
+            assert acc.sharding == w._value.sharding, (name, acc.sharding)
+            shard = acc.addressable_shards[0].data
+            assert shard.size * 2 == acc.size, name     # split over mp

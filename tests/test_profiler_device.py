@@ -288,6 +288,9 @@ class TestDeviceStatistics:
         assert op_class("fusion") == "fusion"
         assert op_class("dot_general") == "matmul"
         assert op_class("_flash_fwd_bhsd") == "custom-call (pallas)"
+        for kernel in ("flash_fwd", "flash_bwd_dkv", "rms_norm_bwd",
+                       "paged_decode", "flash_varlen_fwd"):
+            assert op_class(kernel) == "custom-call (pallas)"
         assert op_class("copy-start") == "data-movement"
         assert op_class("all-reduce") == "collective"
 

@@ -255,3 +255,51 @@ class TestToStaticFallback:
             loss = step(_t(np.random.RandomState(1).rand(3, 2).astype("f4")))
         assert np.isfinite(float(loss))
         assert not np.allclose(lin.weight.numpy(), w0)
+
+    def test_device_failure_on_first_call_raises(self, monkeypatch):
+        """Only a failure to TRACE may fall back. A JaxRuntimeError —
+        what the installed JAX raises for an out-of-memory program, a
+        Mosaic refusal or a run-time fault — must come through
+        to_static on the first call, even with full_graph=False: an
+        eager rerun would hide the device behind a slow step."""
+        import jax
+
+        calls = []
+
+        @paddle.jit.to_static
+        def f(x):
+            calls.append(1)
+            return x * 2.0
+
+        class _Refuses:
+            def __init__(self, jitted):
+                self.trace = jitted.trace
+
+            def __call__(self, *a, **k):
+                raise jax.errors.JaxRuntimeError(
+                    "RESOURCE_EXHAUSTED: fake: ran out of memory in "
+                    "memory space hbm")
+
+        # compile normally, then make the executable refuse to run
+        orig_compile = type(f)._compile
+
+        def compile_then_break(self, *a, **k):
+            entry = orig_compile(self, *a, **k)
+            entry.jitted = _Refuses(entry.jitted)
+            return entry
+
+        monkeypatch.setattr(type(f), "_compile", compile_then_break)
+        with pytest.raises(jax.errors.JaxRuntimeError,
+                           match="RESOURCE_EXHAUSTED"):
+            f(_t(np.ones(3, dtype="float32")))
+        assert calls == [1]          # traced once, never re-run eagerly
+
+    def test_runtime_error_while_tracing_raises(self):
+        import jax
+
+        @paddle.jit.to_static
+        def f(x):
+            raise jax.errors.JaxRuntimeError("INTERNAL: fake device fault")
+
+        with pytest.raises(jax.errors.JaxRuntimeError):
+            f(_t(np.ones(3, dtype="float32")))

@@ -96,9 +96,8 @@ def main(argv=None) -> int:
                          "after the run")
     args = ap.parse_args(argv)
 
-    import jax
-
     import paddle_tpu as paddle
+    from paddle_tpu.device import chip
     from paddle_tpu.models import LlamaForCausalLM
     from paddle_tpu.serve import ServeEngine, run_load
     from paddle_tpu.serve.load import default_serving_setup, warm_engine
@@ -108,7 +107,10 @@ def main(argv=None) -> int:
 
         obs.enable()
 
-    on_tpu = any(d.platform != "cpu" for d in jax.devices())
+    chip.setup_compile_cache()
+    device = chip.device_info()
+    print(json.dumps({"device": device}), flush=True)
+    on_tpu = device["platform"] == "tpu"
     paddle.seed(0)
     # defaults shared with bench.py --config serve (ONE serving shape)
     config, defaults = default_serving_setup(on_tpu)
