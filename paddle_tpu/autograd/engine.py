@@ -17,6 +17,7 @@ import threading
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from ..core import dispatch
@@ -103,6 +104,19 @@ def _wrap_grad(grad, like):
     return Tensor._from_value(grad)
 
 
+class _LayerScope(threading.local):
+    """Path of the `nn.Layer`s whose forward is running on this thread
+    (`gpt/layers.3/attn`): `Layer.__call__` keeps it beside the
+    `jax.named_scope` it opens, and a GradNode remembers it, so that the
+    backward of an op runs under `bwd/<path>` although `backward()` is
+    called outside every layer."""
+
+    path = ""
+
+
+layer_scope = _LayerScope()
+
+
 class GradNode:
     """One recorded primitive application (GradNodeBase analog).
 
@@ -120,6 +134,7 @@ class GradNode:
         "out_hooks",
         "capture_slots",
         "name_hint",
+        "scope",
     )
 
     def __init__(self, prim_name, static, saved, out_avals, in_edges,
@@ -139,6 +154,7 @@ class GradNode:
         self.out_hooks: Dict[int, List[Callable]] = {}
         self.capture_slots: Dict[int, Any] = {}
         self.name_hint = prim_name
+        self.scope = layer_scope.path
 
     def release(self):
         self.saved = None
@@ -326,9 +342,11 @@ def run_backward(
                 )
             saved = (node.saved.unpack()
                      if isinstance(node.saved, _SavedPacked) else node.saved)
-            in_grads = dispatch.call_vjp(
-                node.prim_name, grads_out, saved, node.static
-            )
+            with jax.named_scope(
+                    f"bwd/{node.scope}" if node.scope else "bwd"):
+                in_grads = dispatch.call_vjp(
+                    node.prim_name, grads_out, saved, node.static
+                )
             if not retain_graph:
                 node.release()
 

@@ -21,7 +21,6 @@ from __future__ import annotations
 import functools
 import inspect
 import threading
-import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -275,6 +274,8 @@ class StaticFunction:
         self._donate = donate_state
         self._input_spec = input_spec
         self._full_graph = full_graph
+        self._label = getattr(fn, "__name__", "?")
+        self._ring = _obs.tracing.ring(f"jit.{self._label}", "steps")
 
     def __get__(self, instance, owner):
         if instance is None:
@@ -295,89 +296,118 @@ class StaticFunction:
         return tuple(l.training for l in layers)
 
     def __call__(self, *args, **kwargs):
+        """One call is the span ``jit.call`` with children ``jit.lookup``
+        (discovery, flattening, the cache key; a miss builds the entry),
+        ``jit.state`` (gathering state, lr and step arrays),
+        ``jit.dispatch`` (the compiled call until it returns; on a first
+        run the trace and the XLA compile) and ``jit.writeback``: one
+        clock pair each, on any live profiler trace, and one step record
+        a call in ``observability.tracing``'s ring ``jit.<function>``."""
         if not _to_static_enabled or in_tracing():
             return self._fn(*args, **kwargs)
-        layers, optimizers = _discover(self._fn, args, kwargs)
-        arrays: List[Any] = []
-        template = _flatten_args((args, kwargs), arrays)
-        key = (template, _aval_key(arrays), self._mode_key(layers),
-               tuple(id(o) for o in optimizers))
-        entry = self._cache.get(key)
-        fn_label = getattr(self._fn, "__name__", "?")
-        if entry is None:
-            if _obs.state.on:
-                _M_JIT_COMPILES.inc(fn=fn_label)
-            entry = self._compile(template, arrays, layers, optimizers, args, kwargs)
-            self._cache[key] = entry
-        elif _obs.state.on:
-            _M_JIT_HITS.inc(fn=fn_label)
-        if entry.fallback:
-            # counted once at the transition below, not per call
-            return self._fn(*args, **kwargs)
-        # runtime invocation
-        state = [s.get() for s in entry.slots]
-        lr_vals = jnp.asarray(
-            [o.get_lr() for o in entry.optimizers], jnp.float32
-        ) if entry.optimizers else jnp.zeros((0,), jnp.float32)
-        steps = jnp.asarray(
-            [o._step_count + 1 for o in entry.optimizers], jnp.float32
-        ) if entry.optimizers else jnp.zeros((0,), jnp.float32)
-        rng = generator.next_key("local_seed")
-        first_run = not entry.ran_ok  # first run pays jax trace + XLA compile
-        t0 = time.perf_counter()
-        call_args = (state, arrays, rng, lr_vals, steps)
-        if first_run:
-            # shapes + placements of this signature, for lowered() (taken
-            # before the call donates the state buffers); an uncommitted
-            # array follows the others, as it does in the call
-            entry.call_avals = jax.tree.map(
-                lambda a: jax.ShapeDtypeStruct(
-                    a.shape, a.dtype,
-                    sharding=a.sharding if a.committed else None),
-                call_args)
-        if first_run and not self._full_graph:
-            try:
-                # trace only (the call below reuses the cached trace)
-                entry.jitted.trace(*call_args)
-            except jax.errors.JaxRuntimeError:
-                raise
-            except Exception as e:  # noqa: BLE001 — SOT-style graph break
-                # Reference contract (jit/sot program_translator.py:711): an
-                # untraceable construct (data-dependent Python control flow,
-                # reverse-mode through a while_loop, ...) must not crash the
-                # user's function — fall back to eager for this signature.
-                # Only a failure to TRACE falls back. Whatever the device or
-                # its compilers refuse — a Mosaic kernel, an out-of-memory
-                # program, a fault while running — raises from the call
-                # below: an eager rerun would hide it behind a slow step
-                # that still prints a number (and the donated state may be
-                # gone). Note the failed trace already ran the function's
-                # Python body, so Python-level side effects execute twice on
-                # a fallback call.
-                import warnings
-
-                warnings.warn(
-                    f"to_static: tracing '{fn_label}' "
-                    f"failed ({type(e).__name__}: {e}); falling back to eager "
-                    "execution for this input signature. Pass full_graph=True "
-                    "to make this an error.")
-                entry.fallback = True
-                if _obs.state.on:
-                    _M_JIT_FALLBACKS.inc(fn=fn_label)
+        fn_label = self._label
+        with _obs.span("jit.call", fn=fn_label) as whole:
+            with _obs.span("jit.lookup") as lookup:
+                layers, optimizers = _discover(self._fn, args, kwargs)
+                arrays: List[Any] = []
+                template = _flatten_args((args, kwargs), arrays)
+                key = (template, _aval_key(arrays), self._mode_key(layers),
+                       tuple(id(o) for o in optimizers))
+                entry = self._cache.get(key)
+                if entry is None:
+                    if _obs.state.on:
+                        _M_JIT_COMPILES.inc(fn=fn_label)
+                    entry = self._compile(template, arrays, layers,
+                                          optimizers, args, kwargs)
+                    self._cache[key] = entry
+                elif _obs.state.on:
+                    _M_JIT_HITS.inc(fn=fn_label)
+            if entry.fallback:
+                # counted once at the transition below, not per call
                 return self._fn(*args, **kwargs)
-        out_arrays, new_state = entry.jitted(*call_args)
-        entry.ran_ok = True
-        if first_run and _obs.state.on:
-            dt = time.perf_counter() - t0
-            _M_JIT_COMPILE_SECONDS.observe(dt, fn=fn_label)
-            _obs.emit("jit.compile", fn=fn_label, seconds=dt,
-                      n_inputs=len(arrays), n_state=len(entry.slots))
-        for s, v in zip(entry.slots, new_state):
-            s.set(v)
-        # replay python-side step-count increments observed at trace time
-        for o, d in zip(entry.optimizers, entry.step_deltas):
-            o._step_count += d
-        return _unflatten_out(entry.out_template_box[0], out_arrays)
+            # runtime invocation
+            with _obs.span("jit.state") as gather:
+                state = [s.get() for s in entry.slots]
+                lr_vals = jnp.asarray(
+                    [o.get_lr() for o in entry.optimizers], jnp.float32
+                ) if entry.optimizers else jnp.zeros((0,), jnp.float32)
+                steps = jnp.asarray(
+                    [o._step_count + 1 for o in entry.optimizers],
+                    jnp.float32
+                ) if entry.optimizers else jnp.zeros((0,), jnp.float32)
+                rng = generator.next_key("local_seed")
+                call_args = (state, arrays, rng, lr_vals, steps)
+            first_run = not entry.ran_ok  # pays jax trace + XLA compile
+            if first_run:
+                # shapes + placements of this signature, for lowered()
+                # (taken before the call donates the state buffers); an
+                # uncommitted array follows the others, as it does in
+                # the call
+                entry.call_avals = jax.tree.map(
+                    lambda a: jax.ShapeDtypeStruct(
+                        a.shape, a.dtype,
+                        sharding=a.sharding if a.committed else None),
+                    call_args)
+            with _obs.span("jit.dispatch", compiled=first_run) as dispatch:
+                if first_run and not self._full_graph \
+                        and not self._traces(entry, call_args, fn_label):
+                    return self._fn(*args, **kwargs)
+                out_arrays, new_state = entry.jitted(*call_args)
+            entry.ran_ok = True
+            if first_run and _obs.state.on:
+                _M_JIT_COMPILE_SECONDS.observe(dispatch.seconds,
+                                               fn=fn_label)
+                _obs.emit("jit.compile", fn=fn_label,
+                          seconds=dispatch.seconds, n_inputs=len(arrays),
+                          n_state=len(entry.slots))
+            with _obs.span("jit.writeback") as writeback:
+                for s, v in zip(entry.slots, new_state):
+                    s.set(v)
+                # replay python-side step-count increments observed at
+                # trace time
+                for o, d in zip(entry.optimizers, entry.step_deltas):
+                    o._step_count += d
+                out = _unflatten_out(entry.out_template_box[0], out_arrays)
+        secs = {"lookup": lookup.seconds, "state": gather.seconds,
+                "dispatch": dispatch.seconds,
+                "writeback": writeback.seconds}
+        secs["other"] = whole.seconds - sum(secs.values())
+        self._ring.append({"begin": whole.start, "end": whole.end,
+                           "seconds": secs})
+        return out
+
+    def _traces(self, entry, call_args, fn_label) -> bool:
+        """Trace only (the call reuses the cached trace); False once this
+        signature has fallen back to eager."""
+        try:
+            entry.jitted.trace(*call_args)
+        except jax.errors.JaxRuntimeError:
+            raise
+        except Exception as e:  # noqa: BLE001 — SOT-style graph break
+            # Reference contract (jit/sot program_translator.py:711): an
+            # untraceable construct (data-dependent Python control flow,
+            # reverse-mode through a while_loop, ...) must not crash the
+            # user's function — fall back to eager for this signature.
+            # Only a failure to TRACE falls back. Whatever the device or
+            # its compilers refuse — a Mosaic kernel, an out-of-memory
+            # program, a fault while running — raises from the call
+            # itself: an eager rerun would hide it behind a slow step
+            # that still prints a number (and the donated state may be
+            # gone). Note the failed trace already ran the function's
+            # Python body, so Python-level side effects execute twice on
+            # a fallback call.
+            import warnings
+
+            warnings.warn(
+                f"to_static: tracing '{fn_label}' "
+                f"failed ({type(e).__name__}: {e}); falling back to eager "
+                "execution for this input signature. Pass full_graph=True "
+                "to make this an error.")
+            entry.fallback = True
+            if _obs.state.on:
+                _M_JIT_FALLBACKS.inc(fn=fn_label)
+            return False
+        return True
 
     # ------------------------------------------------------------------
     def _compile(self, template, arrays, layers, optimizers, args, kwargs):

@@ -72,7 +72,7 @@ class LayerList(Layer):
         layers.insert(index, layer)
         self._sub_layers.clear()
         for i, l in enumerate(layers):
-            self._sub_layers[str(i)] = l
+            self.add_sublayer(str(i), l)
         return self
 
     def extend(self, layers):

@@ -10,9 +10,11 @@ from __future__ import annotations
 from collections import OrderedDict
 from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..autograd.engine import layer_scope
 from ..core.tensor import Parameter, Tensor
 
 
@@ -23,6 +25,26 @@ class HookRemoveHelper:
 
     def remove(self):
         self._hooks.pop(self._id, None)
+
+
+def _name_scope_of(parent: "Layer", name: str, sub: "Layer"):
+    """`parent` holds `sub` as `name`: that is the `jax.named_scope` its
+    forward runs under. A bare container (a LayerList: no forward of its
+    own, so it never opens a scope) puts its own name before its
+    children's, `layers.3`, whether they join before or after it got that
+    name; one that has no name (a slice of a LayerList) renames nothing.
+    A layer held under two names keeps the last."""
+    held = parent.__dict__.get("_scope")
+    if type(parent).forward is Layer.forward:
+        if held is not None:
+            name = f"{held}.{name}"
+        elif sub.__dict__.get("_scope") is not None:
+            return
+    object.__setattr__(sub, "_scope", name)
+    if type(sub).forward is Layer.forward:
+        for k, child in sub._sub_layers.items():
+            if child is not None:
+                object.__setattr__(child, "_scope", f"{name}.{k}")
 
 
 class Layer:
@@ -60,6 +82,7 @@ class Layer:
             layers[name] = value
             params and params.pop(name, None)
             object.__setattr__(self, name, value)
+            _name_scope_of(self, name, value)
         else:
             if params is not None and name in params and value is None:
                 params[name] = None
@@ -78,6 +101,8 @@ class Layer:
     def add_sublayer(self, name: str, sublayer: "Layer"):
         self._sub_layers[name] = sublayer
         object.__setattr__(self, name, sublayer)
+        if sublayer is not None:
+            _name_scope_of(self, name, sublayer)
         return sublayer
 
     def register_buffer(self, name: str, tensor: Optional[Tensor], persistable: bool = True):
@@ -294,7 +319,18 @@ class Layer:
             result = hook(self, inputs)
             if result is not None:
                 inputs = result if isinstance(result, tuple) else (result,)
-        outputs = self.forward(*inputs, **kwargs)
+        # the name under which the parent holds this layer (the class's
+        # for a root), so that a compiled program's op metadata, and with
+        # it XProf and profiler.scope_seconds, tell `layers.3/attn` from
+        # `layers.4/linear1`; nothing at run time in a compiled step
+        name = self.__dict__.get("_scope") or self._name_scope
+        outer = layer_scope.path
+        layer_scope.path = f"{outer}/{name}" if outer else name
+        try:
+            with jax.named_scope(name):
+                outputs = self.forward(*inputs, **kwargs)
+        finally:
+            layer_scope.path = outer
         for hook in list(self._forward_post_hooks.values()):
             result = hook(self, inputs, outputs)
             if result is not None:

@@ -141,6 +141,7 @@ def reset():
     flight.recorder.clear()
     _clear_watermarks()
     health._reset_active()
+    tracing.clear_rings()
     for fn in _reset_hooks:
         fn()
 

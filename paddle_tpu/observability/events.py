@@ -16,6 +16,7 @@ import collections
 import time
 from typing import Any, Dict, List, Optional
 
+from ..profiler.utils import RecordEvent
 from . import _gate, flight
 from .metrics import Histogram
 
@@ -84,39 +85,51 @@ def ring_len() -> int:
 class span:
     """Context manager bracketing a named runtime moment.
 
-    Always opens a ``profiler.RecordEvent`` (so the moment shows up in
-    any active host/device trace); when observability is on it also
-    feeds ``histogram`` with the elapsed seconds and emits an ``event``
-    record carrying ``fields`` plus the measured duration.
+    Always opens a ``profiler.RecordEvent`` carrying ``fields`` as its
+    attributes (so the moment shows up in any live host/device trace,
+    whoever started it), and takes ONE pair of clock reads: ``start``,
+    ``end`` and ``seconds`` are what every consumer of the moment is fed
+    from — the caller's step record and always-on histograms after the
+    block, and, when observability is on, ``histogram`` and the
+    ``event`` record emitted here. ``clock`` is the owner's injectable
+    clock (default ``time.perf_counter``, the clock the engine, the
+    benchmark's harness and its tracer all read).
     """
 
     __slots__ = ("name", "_hist", "_hist_labels", "_event", "_fields",
-                 "_rec", "_t0", "seconds")
+                 "_rec", "_clock", "start", "end", "seconds")
 
     def __init__(self, name: str, *, histogram: Optional[Histogram] = None,
                  hist_labels: Optional[Dict[str, Any]] = None,
-                 event: Optional[str] = None, **fields):
+                 event: Optional[str] = None, clock=time.perf_counter,
+                 **fields):
         self.name = name
         self._hist = histogram
         self._hist_labels = hist_labels or {}
         self._event = event
         self._fields = fields
+        self._clock = clock
         self._rec = None
+        self.start = self.end = None
         self.seconds = 0.0
 
-    def __enter__(self):
-        from ..profiler.utils import RecordEvent
+    def note(self, **fields):
+        """Attributes known only inside the block."""
+        self._fields.update(fields)
+        if self._rec is not None:
+            self._rec.annotate(**fields)
 
-        self._rec = RecordEvent(self.name)
+    def __enter__(self):
+        self._rec = RecordEvent(self.name, attrs=self._fields)
         self._rec.begin()
-        self._t0 = time.perf_counter()
+        self.start = self._clock()
         return self
 
     def __exit__(self, *exc):
-        self.seconds = time.perf_counter() - self._t0
-        if self._rec is not None:
-            self._rec.end()
-            self._rec = None
+        self.end = self._clock()
+        self.seconds = self.end - self.start
+        self._rec.end()
+        self._rec = None
         if _gate.state.on:
             if self._hist is not None:
                 self._hist.observe(self.seconds, **self._hist_labels)

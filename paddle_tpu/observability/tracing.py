@@ -31,6 +31,10 @@ Three consumers sit on top:
   worst-latency requests keep their full span trees with a per-phase
   breakdown ("p99 request spent 82% in queue"), attached to SLO-breach
   flight dumps by ``observability/slo.py``.
+- **Step and request records** (:func:`ring`): what the engine and
+  ``jit.to_static`` measure of every step and request, tracer or not,
+  in bounded rings that outlive them — read by the benchmark's
+  per-layer metrics.
 - **Decode-gap accounting**: host-side time between consecutive decode
   steps while slots were runnable (``trace.decode_gap_seconds``) — the
   signal behind the ROADMAP's fused-decode item, linted as PTL404 by
@@ -58,7 +62,7 @@ __all__ = [
     "Span", "RequestTrace", "ServeTracer", "TailExemplars",
     "validate_trace", "check_tracing_overhead", "render_phase_table",
     "render_serve_trace", "trace_enabled_from_env", "TRACE_ENV",
-    "TRACE_CODES", "PHASES",
+    "TRACE_CODES", "PHASES", "ring", "clear_rings", "RING_LEN",
 ]
 
 TRACE_ENV = "PADDLE_TPU_TRACE"
@@ -100,6 +104,32 @@ M_OVERHEAD = registry.gauge(
     "trace.overhead_pct",
     "tokens/sec cost of tracing: 100*(off-on)/off measured by the "
     "bench tracing-overhead guard (PTL402 above tolerance)")
+
+
+# --- step and request records: always on, bounded, outlive their owner ---
+
+#: entries a ring keeps; at a step of 100 ms that is the last 7 minutes
+RING_LEN = 4096
+
+_rings: Dict[tuple, collections.deque] = {}
+
+
+def ring(owner: str, kind: str) -> collections.deque:
+    """The bounded ring of ``kind`` records (``"steps"``, ``"requests"``)
+    kept under ``owner`` — an engine's ``name``, ``jit.<function>`` —
+    created on first use. Like the registry's series it is keyed by name
+    and outlives the object that fills it, so a reader can ask for it
+    after the engine is freed; two owners of one name share it."""
+    r = _rings.get((owner, kind))
+    if r is None:
+        r = _rings[(owner, kind)] = collections.deque(maxlen=RING_LEN)
+    return r
+
+
+def clear_rings():
+    """``obs.reset()`` support: empty every ring (owners keep theirs)."""
+    for r in _rings.values():
+        r.clear()
 
 
 def trace_enabled_from_env() -> bool:
@@ -311,11 +341,15 @@ class ServeTracer:
         req.trace = RequestTrace(req.id, req.submit_time)
         req.trace.begin_phase("queue", req.submit_time)
 
-    def on_admit(self, req, slot: int, resumed: bool):
+    def on_admit(self, req, slot: int, resumed: bool,
+                 t: Optional[float] = None):
+        """``t`` is the engine's one clock read at the admission site
+        (the same that ``Request.admit_time`` gets)."""
         tr = req.trace
         if tr is None:
             return
-        t = self._clock()
+        if t is None:
+            t = self._clock()
         if resumed:
             tr.begin_phase("resume", t, slot=slot,
                            preemptions=req.preemptions)

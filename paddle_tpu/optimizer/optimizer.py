@@ -131,6 +131,7 @@ class Optimizer:
         return pg
 
     @no_grad()
+    @jax.named_scope("optimizer")   # sets the update apart from the layers
     def step(self):
         params_grads = self._params_grads()
         if self._grad_clip is not None:

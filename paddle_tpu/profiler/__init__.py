@@ -8,6 +8,7 @@ from .profiler import (Profiler, ProfilerState, ProfilerTarget, SummaryView,
                        export_chrome_tracing, export_protobuf, get_profiler,
                        make_scheduler)
 from .utils import RecordEvent, in_profiler_mode, load_profiler_result
+from .scopes import scope_of, scope_seconds
 from .statistic import (collect_device_statistic, device_summary_table,
                         op_class, statistic_from_trace, summary_table)
 
@@ -17,7 +18,7 @@ __all__ = [
     "export_chrome_tracing", "export_protobuf", "load_profiler_result",
     "in_profiler_mode", "get_profiler", "collect_device_statistic",
     "device_summary_table", "op_class", "statistic_from_trace",
-    "summary_table",
+    "summary_table", "scope_seconds", "scope_of",
 ]
 
 

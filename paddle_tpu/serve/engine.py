@@ -56,6 +56,21 @@ only from the scheduler path, never inside a compiled step, so
 ``observability.slo.SloMonitor`` evaluated at every step boundary.
 Both, plus all request timestamps, read the injectable ``clock``
 (default ``time.perf_counter``) so load tests can run on a fake clock.
+
+Always on, under the engine's ``name`` in ``observability.tracing``'s
+bounded rings (they outlive the engine, as the registry's series do):
+one STEP RECORD a ``step()`` — begin, end and the seconds by phase
+(``admit``, ``prefill``, ``ensure_blocks``, ``dispatch``, ``wait``,
+``emit`` and the ``other`` that is left: they sum to the step) — and one
+REQUEST RECORD a request (id, submit, admit, first token, finish, the
+warm-up flag). Counts (tokens, preemptions, programs dispatched) are NOT
+copied into the records: the registry's counters hold them, and the
+spans carry them as attributes. Each phase is an ``observability.span`` (``serve.step``
+> ``serve.admit`` > ``serve.prefill``, ``serve.ensure_blocks``,
+``serve.decode.dispatch`` / ``.wait`` / ``.emit``) with ONE clock pair,
+which also lands on the host plane of any live profiler trace; the
+records, the ``serve.*_seconds`` histograms and the tracer's decode
+steps are all fed from that one measurement.
 """
 from __future__ import annotations
 
@@ -137,6 +152,11 @@ QUEUED = "QUEUED"
 RUNNING = "RUNNING"
 FINISHED = "FINISHED"
 
+#: what a step record's seconds are split into; ``other`` is the rest of
+#: the step (gauges, SLO and health hooks, the clock reads themselves)
+STEP_PHASES = ("admit", "prefill", "ensure_blocks", "dispatch", "wait",
+               "emit", "other")
+
 
 @dataclass
 class Request:
@@ -148,6 +168,7 @@ class Request:
     eos_token_id: Optional[int] = None
     temperature: float = 0.0               # 0.0 = greedy
     submit_time: float = 0.0
+    admit_time: Optional[float] = None     # FIRST admission into a slot
     first_token_time: Optional[float] = None
     finish_time: Optional[float] = None
     finish_reason: Optional[str] = None
@@ -170,6 +191,9 @@ class Request:
     # span tree (observability.tracing.RequestTrace) when the engine
     # runs with tracing enabled; None otherwise
     trace: Optional[object] = field(default=None, repr=False)
+    # this request's entry in the engine's request ring (always on),
+    # filled in as the request moves
+    record: Optional[dict] = field(default=None, repr=False)
 
     @property
     def n_prompt(self) -> int:
@@ -311,6 +335,12 @@ class ServeEngine:
         # lifetime totals the step-boundary SLO evaluation differences
         self._n_tokens = 0
         self._n_preempts = 0
+        self._n_steps = 0
+        self._step_ring = obs.tracing.ring(self.name, "steps")
+        self._request_ring = obs.tracing.ring(self.name, "requests")
+        # seconds by phase of the step under way (a prefill outside any
+        # step, as warm-up makes them, adds to a dict nobody reads)
+        self._secs = dict.fromkeys(STEP_PHASES, 0.0)
         self._key = jax.random.PRNGKey(seed)
         self._rng = np.random.default_rng(seed)
         # the caches are DONATED (argument 1 after the bound self):
@@ -407,6 +437,10 @@ class ServeEngine:
             ids=[int(t) for t in prompt], warmup=bool(warmup))
         self._next_id += 1
         self.queue.append(req)
+        req.record = {
+            "id": req.id, "submit": req.submit_time, "admit": None,
+            "first_token": None, "finish": None, "warmup": req.warmup}
+        self._request_ring.append(req.record)
         if self.tracer is not None and not req.warmup:
             self.tracer.on_submit(req)
         _M_QUEUE_DEPTH.set(len(self.queue), engine=self.name)
@@ -431,27 +465,41 @@ class ServeEngine:
         serving_real_work = self.slo is not None and any(
             not r.warmup for r in self._live_requests())
         tok0, pre0 = self._n_tokens, self._n_preempts
-        self._admit()
-        n_active = self.n_active
-        if n_active:
-            if self.decode_burst > 1:
-                self._decode_burst_once()
-            else:
-                self._decode_once()
-        _M_QUEUE_DEPTH.set(len(self.queue), engine=self.name)
-        _M_POOL_OCCUPANCY.set(round(self.pool.occupancy, 4),
+        secs = self._secs = dict.fromkeys(STEP_PHASES, 0.0)
+        with self._span("serve.step", step=self._n_steps,
+                        queued=len(self.queue)) as whole:
+            with self._span("serve.admit") as sp:
+                sp.note(admitted=self._admit())
+            secs["admit"] = sp.seconds - secs["prefill"]
+            n_active = self.n_active
+            if n_active:
+                if self.decode_burst > 1:
+                    self._decode_burst_once()
+                else:
+                    self._decode_once()
+            whole.note(n_active=n_active)
+            _M_QUEUE_DEPTH.set(len(self.queue), engine=self.name)
+            _M_POOL_OCCUPANCY.set(round(self.pool.occupancy, 4),
+                                  engine=self.name)
+            _M_BATCH_FILL.set(round(n_active / self.max_slots, 4),
                               engine=self.name)
-        _M_BATCH_FILL.set(round(n_active / self.max_slots, 4),
-                          engine=self.name)
-        if serving_real_work:
-            # step-boundary SLO evaluation — skipped while the only
-            # work is compile-warming (whose throughput/TTFT would
-            # bill XLA, not serving)
-            self.slo.on_step(tokens=self._n_tokens - tok0,
-                             preemptions=self._n_preempts - pre0,
-                             now=self._clock())
-        obs.health.maybe_on_step(self._clock())
+            if serving_real_work:
+                # step-boundary SLO evaluation — skipped while the only
+                # work is compile-warming (whose throughput/TTFT would
+                # bill XLA, not serving)
+                self.slo.on_step(tokens=self._n_tokens - tok0,
+                                 preemptions=self._n_preempts - pre0,
+                                 now=self._clock())
+            obs.health.maybe_on_step(self._clock())
+        self._n_steps += 1
+        secs["other"] = whole.seconds - sum(secs.values())
+        self._step_ring.append(
+            {"begin": whole.start, "end": whole.end, "seconds": secs})
         return n_active
+
+    def _span(self, name: str, **attrs):
+        """A phase of the step, on the engine's clock."""
+        return obs.span(name, clock=self._clock, **attrs)
 
     def _live_requests(self):
         for r in self.queue:
@@ -499,11 +547,12 @@ class ServeEngine:
         duplicate of the final matched block, so no stream ever writes
         KV that another stream reads."""
         bs = self.block_size
+        admitted = 0
         while self.queue:
             slot = self._free_slot()
             if slot is None:
                 _M_STALLS.inc(engine=self.name, reason="no_free_slot")
-                return
+                break
             req = self.queue[0]
             # resumed streams re-prefill prompt+generated minus the
             # pending last token; fresh streams prefill the prompt.
@@ -537,8 +586,9 @@ class ServeEngine:
                     self._prefix.note_cached(
                         self.pool.release(read_only, retain=read_only))
                 _M_STALLS.inc(engine=self.name, reason="no_free_blocks")
-                return
+                break
             self.queue.popleft()
+            admitted += 1
             fresh = self._alloc_blocks(need)
             req.blocks = list(read_only) + fresh
             req.shared_blocks = len(read_only)
@@ -564,9 +614,12 @@ class ServeEngine:
             row = np.zeros(self.max_blocks_per_seq, np.int32)
             row[:len(req.blocks)] = req.blocks
             self._tables[slot] = row
+            now = self._clock()
+            if req.admit_time is None:
+                req.admit_time = req.record["admit"] = now
             if self.tracer is not None:
                 self.tracer.on_admit(req, slot,
-                                     resumed=req.n_generated > 0)
+                                     resumed=req.n_generated > 0, t=now)
             # shared tokens are resident KV the suffix attends to but
             # never recomputes; under CoW the suffix is the last token
             start = (n_pre - 1) if cow else len(read_only) * bs
@@ -579,6 +632,7 @@ class ServeEngine:
             self._temps[slot] = req.temperature
             self._eos[slot] = (-1 if req.eos_token_id is None
                                else req.eos_token_id)
+        return admitted
 
     def _alloc_blocks(self, n: int) -> List[int]:
         """Pool alloc with prefix-cache eviction backing it: when the
@@ -602,14 +656,14 @@ class ServeEngine:
 
         suffix = prefill_ids[start:]
         n = len(suffix)
-        bucket = max(8, 1 << (n - 1).bit_length())   # pow2 length buckets
-        bucket = min(bucket, self.max_seq_len)
+        bucket = self._bucket(n)
         if self.tracer is not None:
             self.tracer.on_prefill(req, bucket=bucket, tokens=n)
-        padded = np.zeros((1, bucket), np.int32)
-        padded[0, :n] = suffix
-        req.prefilled_tokens += n
-        with _M_PREFILL_SECONDS.time(engine=self.name):
+        with self._span("serve.prefill", request=req.id, bucket=bucket,
+                        tokens=n) as sp:
+            padded = np.zeros((1, bucket), np.int32)
+            padded[0, :n] = suffix
+            req.prefilled_tokens += n
             if start == 0:
                 self._caches, logits = self._prefill_fn(
                     self._arrays, self._caches, jnp.asarray(padded),
@@ -619,24 +673,30 @@ class ServeEngine:
                     self._arrays, self._caches, jnp.asarray(padded),
                     jnp.int32(n), jnp.int32(start),
                     jnp.asarray(self._tables[req.slot]))
-        if req.n_generated == 0:
-            # fresh stream: its FIRST token comes from the prefill
-            # logits (this is the TTFT moment); resumed streams already
-            # hold their pending token, the logits are discarded
-            tok = self._sample_host(np.asarray(logits), req.temperature)
-            now = self._clock()
-            req.first_token_time = now
-            if not req.warmup:
-                _M_TTFT.observe(now - req.submit_time, engine=self.name)
-                if self.slo is not None:
-                    self.slo.observe_ttft(now - req.submit_time, now=now)
-            if self.tracer is not None:
-                self.tracer.on_first_token(req, now)
-            self._append_token(req, tok)
-        else:
-            # resumed streams append nothing here; their just-refilled
-            # full blocks still need trie registration
-            self._register_full_blocks(req)
+            if req.n_generated == 0:
+                # fresh stream: its FIRST token comes from the prefill
+                # logits (this is the TTFT moment); resumed streams
+                # already hold their pending token, the logits are
+                # discarded
+                tok = self._sample_host(np.asarray(logits),
+                                        req.temperature)
+                now = self._clock()
+                req.first_token_time = req.record["first_token"] = now
+                if not req.warmup:
+                    _M_TTFT.observe(now - req.submit_time,
+                                    engine=self.name)
+                    if self.slo is not None:
+                        self.slo.observe_ttft(now - req.submit_time,
+                                              now=now)
+                if self.tracer is not None:
+                    self.tracer.on_first_token(req, now)
+                self._append_token(req, tok)
+            else:
+                # resumed streams append nothing here; their
+                # just-refilled full blocks still need trie registration
+                self._register_full_blocks(req)
+        _M_PREFILL_SECONDS.observe(sp.seconds, engine=self.name)
+        self._secs["prefill"] += sp.seconds
         if self.tracer is not None and req.state is not FINISHED:
             self.tracer.on_decode_begin(req)
 
@@ -706,6 +766,7 @@ class ServeEngine:
         req.state = FINISHED
         req.finish_reason = reason
         req.finish_time = self._clock() if now is None else now
+        req.record["finish"] = req.finish_time
         self.finished.append(req)
         _M_FINISHED.inc(engine=self.name, reason=reason)
         _M_REQUEST_SECONDS.observe(req.finish_time - req.submit_time,
@@ -779,35 +840,58 @@ class ServeEngine:
         import jax
         import jax.numpy as jnp
 
-        self._ensure_blocks()
-        active_np = np.array([r is not None for r in self._slots], bool)
+        active_np = self._ensure_blocks_timed()
         if not active_np.any():
             return                # everyone was preempted away
-        self._key, sub = jax.random.split(self._key)
-        t0 = self._clock()
-        with _M_DECODE_SECONDS.time(engine=self.name):
+        with self._span("serve.decode.dispatch", burst=1) as dispatch:
+            self._key, sub = jax.random.split(self._key)
             nxt, self._caches = self._decode_fn(
                 self._arrays, self._caches, jnp.asarray(self._tokens),
                 jnp.asarray(self._lens), jnp.asarray(active_np),
                 jnp.asarray(self._tables), jnp.asarray(self._temps), sub)
+        with self._span("serve.decode.wait") as wait:
             nxt = np.asarray(nxt)
-        t1 = self._clock()
-        _M_DECODE_STEPS.inc(engine=self.name)
+        with self._span("serve.decode.emit") as emit:
+            tok0 = self._n_tokens
+            for slot, req in enumerate(self._slots):
+                if req is None:
+                    continue
+                self._lens[slot] += 1
+                self._append_token(req, int(nxt[slot]))
+                if req.state is not FINISHED:
+                    self._tokens[slot] = req.ids[-1]
+            emit.note(tokens=self._n_tokens - tok0)
+        self._decode_done(1, dispatch, wait, emit)
+
+    def _ensure_blocks_timed(self, lookahead: int = 1) -> np.ndarray:
+        """``_ensure_blocks`` with any preemption it causes, as one
+        phase of the step; returns the active mask it leaves."""
+        pre0 = self._n_preempts
+        with self._span("serve.ensure_blocks") as sp:
+            self._ensure_blocks(lookahead)
+            sp.note(preemptions=self._n_preempts - pre0)
+        self._secs["ensure_blocks"] += sp.seconds
+        return np.array([r is not None for r in self._slots], bool)
+
+    def _decode_done(self, n: int, dispatch, wait, emit):
+        """Feed everything that reads one decode program's times from
+        the three spans round it: the step record, the histogram, the
+        counters and the tracer's engine lane."""
+        secs = self._secs
+        secs["dispatch"] += dispatch.seconds
+        secs["wait"] += wait.seconds
+        secs["emit"] += emit.seconds
+        _M_DECODE_SECONDS.observe(wait.end - dispatch.start,
+                                  engine=self.name)
+        _M_DECODE_STEPS.inc(n, engine=self.name)
         _M_HOST_RT.inc(engine=self.name)
-        for slot, req in enumerate(self._slots):
-            if req is None:
-                continue
-            self._lens[slot] += 1
-            self._append_token(req, int(nxt[slot]))
-            if req.state is not FINISHED:
-                self._tokens[slot] = req.ids[-1]
         if self.tracer is not None:
             # active_after = runnable slots LEFT BEHIND by this step —
             # the gap to the next step only counts as host-side stall
             # (PTL404) when someone was still waiting to decode
-            self.tracer.on_decode_step(t0, t1,
+            self.tracer.on_decode_step(dispatch.start, wait.end,
                                        active_after=self.n_active,
-                                       queued=len(self.queue))
+                                       queued=len(self.queue), tokens=n)
 
     def _pick_burst_len(self) -> int:
         """Adaptive burst length: never cross a block boundary (the
@@ -839,51 +923,46 @@ class ServeEngine:
         import jax
         import jax.numpy as jnp
 
-        self._ensure_blocks(lookahead=self.decode_burst)
-        active_np = np.array([r is not None for r in self._slots], bool)
+        active_np = self._ensure_blocks_timed(lookahead=self.decode_burst)
         if not active_np.any():
             return                # everyone was preempted away
         n = self._pick_burst_len()
         self.burst_lens_used.add(n)
-        # pre-split the SAME per-step key schedule the unbursted loop
-        # draws, so burst=N and burst=1 sample identical streams
-        subs = []
-        for _ in range(n):
-            self._key, sub = jax.random.split(self._key)
-            subs.append(sub)
-        t0 = self._clock()
-        with _M_DECODE_SECONDS.time(engine=self.name):
+        with self._span("serve.decode.dispatch", burst=n) as dispatch:
+            # pre-split the SAME per-step key schedule the unbursted
+            # loop draws, so burst=N and burst=1 sample identical streams
+            subs = []
+            for _ in range(n):
+                self._key, sub = jax.random.split(self._key)
+                subs.append(sub)
             ys, emitted, self._caches = self._burst_fn(
                 n, self._arrays, self._caches,
                 jnp.asarray(self._tokens), jnp.asarray(self._lens),
                 jnp.asarray(active_np), jnp.asarray(self._tables),
                 jnp.asarray(self._temps), jnp.asarray(self._eos),
                 jnp.stack(subs))
+        with self._span("serve.decode.wait") as wait:
             ys = np.asarray(ys)
             emitted = np.asarray(emitted)
-        t1 = self._clock()
-        _M_DECODE_STEPS.inc(n, engine=self.name)
-        _M_HOST_RT.inc(engine=self.name)
-        per_step = (t1 - t0) / n
-        n_emitted = 0
-        for slot, req in enumerate(self._slots):
-            if req is None:
-                continue
-            for j in range(int(emitted[slot])):
-                self._lens[slot] += 1
-                n_emitted += 1
-                self._append_token(req, int(ys[j, slot]),
-                                   now=t0 + per_step * (j + 1))
-                if req.state is FINISHED:
-                    break
-            if req.state is not FINISHED:
-                self._tokens[slot] = req.ids[-1]
+        with self._span("serve.decode.emit") as emit:
+            t0 = dispatch.start
+            per_step = (wait.end - t0) / n
+            n_emitted = 0
+            for slot, req in enumerate(self._slots):
+                if req is None:
+                    continue
+                for j in range(int(emitted[slot])):
+                    self._lens[slot] += 1
+                    n_emitted += 1
+                    self._append_token(req, int(ys[j, slot]),
+                                       now=t0 + per_step * (j + 1))
+                    if req.state is FINISHED:
+                        break
+                if req.state is not FINISHED:
+                    self._tokens[slot] = req.ids[-1]
+            emit.note(tokens=n_emitted)
         _M_BURST_TOKENS.inc(n_emitted, engine=self.name)
-        if self.tracer is not None:
-            self.tracer.on_decode_step(t0, t1,
-                                       active_after=self.n_active,
-                                       queued=len(self.queue),
-                                       tokens=n)
+        self._decode_done(n, dispatch, wait, emit)
 
     def warm_burst(self, n: int):
         """Compile the ``n``-step fused burst against idle slot state
@@ -900,6 +979,39 @@ class ServeEngine:
             jnp.asarray(self._temps), jnp.asarray(self._eos), keys)
 
     # -- compiled steps ----------------------------------------------------
+    def _bucket(self, n: int) -> int:
+        """The pow2 length bucket an ``n``-token prefill is padded to."""
+        return min(max(8, 1 << (n - 1).bit_length()), self.max_seq_len)
+
+    def lowered(self, prompt_lens=()) -> dict:
+        """The jax ``Lowered`` decode step (``"decode"``) and the cold
+        prefill program of the bucket of each of ``prompt_lens``
+        (``"prefill.<bucket>"``), lowered from the engine's own arrays as
+        ``StaticFunction.lowered()`` lowers from its call's: for
+        ``.as_text()`` / ``.compile().as_text()``, whose ``op_name``
+        metadata carries the scopes ``profiler.scope_seconds`` joins a
+        trace with. Lowering re-traces (``decode_traces`` and
+        ``prefill_traces`` count it) but runs nothing and donates
+        nothing."""
+        import jax
+        import jax.numpy as jnp
+
+        def avals(*args):
+            return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=getattr(a, "sharding", None)),
+                args)
+
+        state = (self._arrays, self._caches)
+        out = {"decode": self._decode_fn.lower(*avals(
+            *state, jnp.asarray(self._tokens), jnp.asarray(self._lens),
+            jnp.zeros(self.max_slots, bool), jnp.asarray(self._tables),
+            jnp.asarray(self._temps), self._key))}
+        for b in sorted({self._bucket(int(n)) for n in prompt_lens}):
+            out[f"prefill.{b}"] = self._prefill_fn.lower(*avals(
+                *state, jnp.zeros((1, b), jnp.int32), jnp.int32(1),
+                jnp.asarray(self._tables[0])))
+        return out
+
     def _scatter_kv(self, kc, vc, k_new, v_new, safe_slot):
         """Write per-row K/V ([rows, kvh, dh]) into the pool at flat
         slot ids (out-of-range ids drop — that is how inactive slots
@@ -952,40 +1064,57 @@ class ServeEngine:
         pool attention vs in-prompt causal softmax), so it is the only
         thing they provide. Returns (normed hidden [rows, H],
         new caches)."""
+        import jax
+
         rows = x.shape[0]
         nh, kvh, dh = self._nh, self._nkv, self._dh
         dtype = self._dtype
 
+        # scopes by hand, as nn.Layer.__call__ gives them to the eager
+        # stack: they are what the op metadata of the compiled steps, and
+        # with it XProf and profiler.scope_seconds, name device time by
+        scope = jax.named_scope
         new_caches = []
-        for lp, (kc, vc) in zip(p["layers"], caches):
-            if self._is_llama:
-                h = _gen._rms(x, lp["ln1"], p["eps"], dtype)
-                q = (h @ lp["wq"]).reshape(rows, nh, dh)
-                k = (h @ lp["wk"]).reshape(rows, kvh, dh)
-                v = (h @ lp["wv"]).reshape(rows, kvh, dh)
-                q, k = self._rope(q, k, *rope)
-            else:
-                h = _gen._ln(x, lp["ln1_w"], lp["ln1_b"], p["eps"],
-                             dtype)
-                qkv = (h @ lp["wqkv"] + lp["bqkv"]).reshape(
-                    rows, 3, nh, dh)
-                q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]
-            kc, vc = self._scatter_kv(kc, vc, k, v, safe_slot)
+        for i, (lp, (kc, vc)) in enumerate(zip(p["layers"], caches)):
+            with scope(f"layer{i}/qkv"):
+                if self._is_llama:
+                    h = _gen._rms(x, lp["ln1"], p["eps"], dtype)
+                    q = (h @ lp["wq"]).reshape(rows, nh, dh)
+                    k = (h @ lp["wk"]).reshape(rows, kvh, dh)
+                    v = (h @ lp["wv"]).reshape(rows, kvh, dh)
+                    q, k = self._rope(q, k, *rope)
+                else:
+                    h = _gen._ln(x, lp["ln1_w"], lp["ln1_b"], p["eps"],
+                                 dtype)
+                    qkv = (h @ lp["wqkv"] + lp["bqkv"]).reshape(
+                        rows, 3, nh, dh)
+                    q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]
+            with scope(f"layer{i}/scatter_kv"):
+                kc, vc = self._scatter_kv(kc, vc, k, v, safe_slot)
             new_caches.append((kc, vc))
-            ctx = attn(q, k, v, kc, vc)
+            with scope(f"layer{i}/attn"):
+                ctx = attn(q, k, v, kc, vc)
+            with scope(f"layer{i}/out"):
+                if self._is_llama:
+                    x = x + ctx.astype(dtype) @ lp["wo"]
+                else:
+                    x = x + ctx.astype(dtype) @ lp["wo"] + lp["bo"]
+            with scope(f"layer{i}/ffn"):
+                if self._is_llama:
+                    x = x + _gen._llama_ffn(
+                        _gen._rms(x, lp["ln2"], p["eps"], dtype), lp,
+                        dtype)
+                else:
+                    x = x + _gen._gpt_ffn(
+                        _gen._ln(x, lp["ln2_w"], lp["ln2_b"], p["eps"],
+                                 dtype), lp, dtype)
+        with scope("final_norm"):
             if self._is_llama:
-                x = x + ctx.astype(dtype) @ lp["wo"]
-                x = x + _gen._llama_ffn(
-                    _gen._rms(x, lp["ln2"], p["eps"], dtype), lp, dtype)
+                out = _gen._rms(x, p["norm"], p["eps"], dtype)
             else:
-                x = x + ctx.astype(dtype) @ lp["wo"] + lp["bo"]
-                x = x + _gen._gpt_ffn(
-                    _gen._ln(x, lp["ln2_w"], lp["ln2_b"], p["eps"],
-                             dtype), lp, dtype)
-        if self._is_llama:
-            return _gen._rms(x, p["norm"], p["eps"], dtype), new_caches
-        return (_gen._ln(x, p["normf_w"], p["normf_b"], p["eps"], dtype),
-                new_caches)
+                out = _gen._ln(x, p["normf_w"], p["normf_b"], p["eps"],
+                               dtype)
+        return out, new_caches
 
     def _decode_impl(self, arrays, caches, tokens, lens, active, tables,
                      temps, key):
@@ -1013,6 +1142,7 @@ class ServeEngine:
         """The decode-tick math, shared VERBATIM by the single-step jit
         and every tick of the fused burst scan — op-for-op identity is
         what makes burst=N token-for-token equal to burst=1."""
+        import jax
         import jax.numpy as jnp
 
         from ..ops.pallas.paged_attention import paged_attention_decode
@@ -1023,13 +1153,14 @@ class ServeEngine:
         nh = self._nh
         nb, bs = self.pool.num_blocks, self.block_size
 
-        x = jnp.take(p["embed"], tokens, axis=0)          # [B, H]
-        pos = lens.astype(jnp.int32)
-        rope = None
-        if self._is_llama:
-            rope = self._rope_rows(pos)
-        else:
-            x = x + jnp.take(p["wpe"], pos, axis=0)
+        with jax.named_scope("embed"):
+            x = jnp.take(p["embed"], tokens, axis=0)      # [B, H]
+            pos = lens.astype(jnp.int32)
+            rope = None
+            if self._is_llama:
+                rope = self._rope_rows(pos)
+            else:
+                x = x + jnp.take(p["wpe"], pos, axis=0)
         lengths = jnp.where(active, pos + 1, 0)
         bi = jnp.clip(pos // bs, 0, self.max_blocks_per_seq - 1)
         phys = jnp.take_along_axis(tables, bi[:, None], axis=1)[:, 0]
@@ -1043,8 +1174,10 @@ class ServeEngine:
 
         out, new_caches = self._stack_layers(p, x, rope, caches,
                                              safe_slot, attn)
-        logits = _gen._head_logits(p, out).astype(jnp.float32)   # [B, V]
-        nxt = _gen._sample_slot_tokens(logits, temps, key)
+        with jax.named_scope("head"):
+            logits = _gen._head_logits(p, out).astype(jnp.float32)  # [B, V]
+        with jax.named_scope("sample"):
+            nxt = _gen._sample_slot_tokens(logits, temps, key)
         return nxt, new_caches
 
     def _burst_impl(self, n, arrays, caches, tokens, lens, active,
@@ -1107,12 +1240,13 @@ class ServeEngine:
 
         positions = jnp.arange(tp, dtype=jnp.int32)
         valid = positions < n                              # [Tp]
-        x = jnp.take(p["embed"], ids, axis=0)[0]           # [Tp, H]
-        rope = None
-        if self._is_llama:
-            rope = self._rope_rows(positions)
-        else:
-            x = x + jnp.take(p["wpe"], positions, axis=0)
+        with jax.named_scope("embed"):
+            x = jnp.take(p["embed"], ids, axis=0)[0]       # [Tp, H]
+            rope = None
+            if self._is_llama:
+                rope = self._rope_rows(positions)
+            else:
+                x = x + jnp.take(p["wpe"], positions, axis=0)
         # causal within the prompt; pad rows see themselves only (their
         # K/V never reach the pool and their logits are never read)
         causal = (positions[None, :] <= positions[:, None]) \
@@ -1136,9 +1270,10 @@ class ServeEngine:
 
         out, new_caches = self._stack_layers(p, x, rope, caches,
                                              safe_slot, attn)
-        h_last = jnp.take(out, n - 1, axis=0)              # [H]
-        logits = _gen._head_logits(p, h_last[None, :])[0]
-        return new_caches, logits.astype(jnp.float32)
+        with jax.named_scope("head"):
+            h_last = jnp.take(out, n - 1, axis=0)          # [H]
+            logits = _gen._head_logits(p, h_last[None, :])[0]
+            return new_caches, logits.astype(jnp.float32)
 
     def _suffix_prefill_impl(self, arrays, caches, ids, n, start,
                              table_row):
@@ -1151,6 +1286,7 @@ class ServeEngine:
         just-written suffix rows — scatter precedes attention per
         layer, exactly as in decode. ``start`` is jit data, so this
         compiles once per pow2 suffix bucket."""
+        import jax
         import jax.numpy as jnp
 
         from ..ops.pallas.paged_attention import paged_attention_decode
@@ -1167,12 +1303,13 @@ class ServeEngine:
         offs = jnp.arange(tp, dtype=jnp.int32)
         positions = start + offs                           # absolute
         valid = offs < n
-        x = jnp.take(p["embed"], ids, axis=0)[0]           # [Tp, H]
-        rope = None
-        if self._is_llama:
-            rope = self._rope_rows(positions)
-        else:
-            x = x + jnp.take(p["wpe"], positions, axis=0)
+        with jax.named_scope("embed"):
+            x = jnp.take(p["embed"], ids, axis=0)[0]       # [Tp, H]
+            rope = None
+            if self._is_llama:
+                rope = self._rope_rows(positions)
+            else:
+                x = x + jnp.take(p["wpe"], positions, axis=0)
 
         bi = jnp.clip(positions // bs, 0, self.max_blocks_per_seq - 1)
         slot = jnp.take(table_row, bi) * bs + positions % bs
@@ -1188,9 +1325,10 @@ class ServeEngine:
 
         out, new_caches = self._stack_layers(p, x, rope, caches,
                                              safe_slot, attn)
-        h_last = jnp.take(out, n - 1, axis=0)              # [H]
-        logits = _gen._head_logits(p, h_last[None, :])[0]
-        return new_caches, logits.astype(jnp.float32)
+        with jax.named_scope("head"):
+            h_last = jnp.take(out, n - 1, axis=0)          # [H]
+            logits = _gen._head_logits(p, h_last[None, :])[0]
+            return new_caches, logits.astype(jnp.float32)
 
     def _cow_impl(self, caches, src, dst):
         """Copy-on-write: duplicate one physical block's K/V across
