@@ -103,6 +103,8 @@ def sizes(tiny):
             flash_bias=(8, 12, 1024, 64), flash_prefill=(8, 16, 128, 128),
             varlen=(8192, 16, 128), rms=((4, 2048, 2048), (8, 2048)),
             paged=dict(b=8, nh=16, dh=128, pages=96, page=128, pps=8),
+            kv_write=dict(kvh=12, pages=736, page=128, dh=128, slots=128,
+                          prompt=2048),
             serve_requests=8, serve_new=(8, 24))
     return dict(
         llama=dict(vocab_size=512, hidden_size=128, intermediate_size=256,
@@ -113,6 +115,7 @@ def sizes(tiny):
         flash_bias=(2, 2, 256, 64), flash_prefill=(2, 2, 128, 64),
         varlen=(512, 2, 64), rms=((2, 64, 128), (8, 128)),
         paged=dict(b=4, nh=4, dh=64, pages=24, page=16, pps=4),
+        kv_write=dict(kvh=2, pages=24, page=16, dh=64, slots=4, prompt=64),
         serve_requests=4, serve_new=(3, 6))
 
 
@@ -315,6 +318,53 @@ def _paged_case(c, kv_heads, *, b, nh, dh, pages, page, pps, tol=2e-2):
             bool((np.asarray(got[0].astype(jnp.float32)) == 0).all()))
 
 
+def _kv_write_case(c, *, kvh, pages, page, dh, slots, prompt):
+    """The in-place K/V write against the scatter it replaced, bit for
+    bit: a decode step's rows (one a stream, some slots inactive), a
+    suffix prefill's (consecutive from a free start) and a cold prefill's
+    blocks, at the benchmark cell's pool shape."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.core.flags import pallas_mode
+    from paddle_tpu.ops.pallas.kv_write import kv_write, kv_write_reference
+
+    backend = "kernel" if pallas_mode() == "compiled" else "interpret"
+    rng = np.random.RandomState(0)
+    pool = jax.random.normal(jax.random.PRNGKey(1), (kvh, pages, page, dh),
+                             jnp.bfloat16)
+    blocks = rng.permutation(pages)
+    per = prompt // page + 1
+    table = blocks[:per]
+
+    def stream(start, n, bucket):
+        pos = start + np.arange(bucket)
+        slot = table[np.minimum(pos // page, per - 1)] * page + pos % page
+        return np.where(np.arange(bucket) < n, slot, pages * page)
+
+    decode = blocks[:slots] * page + rng.randint(0, page, slots)
+    decode[::5] = pages * page                    # inactive slots
+    cases = (("decode rows", decode, False),
+             ("suffix prefill rows", stream(page // 2 + 3, prompt - 9,
+                                            prompt), False),
+             ("cold prefill blocks", stream(0, prompt - page // 3, prompt),
+              True))
+    for label, slot_ids, fresh in cases:
+        rows = jax.random.normal(jax.random.PRNGKey(len(slot_ids)),
+                                 (len(slot_ids), kvh, dh), jnp.bfloat16)
+        ids = jnp.asarray(slot_ids, jnp.int32)
+        got = jax.jit(lambda p, r, s: kv_write(
+            p, r, s, rows_start_blocks=fresh, backend=backend))(
+                pool, rows, ids)
+        want = jax.jit(kv_write_reference)(pool, rows, ids)
+        c.check(f"kv_write {label}: {len(slot_ids)} rows into "
+                f"{tuple(pool.shape)} equal the scatter bit for bit",
+                bool(jnp.array_equal(got, want)),
+                f"{int(jnp.sum(jnp.any(got != pool, axis=(0, 3))))} rows "
+                f"of the pool changed")
+
+
 def phase_kernels(tiny):
     import paddle_tpu  # noqa: F401
     from paddle_tpu.core.flags import pallas_mode
@@ -347,6 +397,7 @@ def phase_kernels(tiny):
     p = sz["paged"]
     _paged_case(c, p["nh"], **p)
     _paged_case(c, p["nh"] // 4, **p)
+    _kv_write_case(c, **sz["kv_write"])
     c.finish()
     return {}
 

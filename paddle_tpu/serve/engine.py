@@ -122,6 +122,10 @@ _M_DECODE_TRACES = obs.counter(
     "traced — slot churn must keep this at 1 per engine")
 _M_PREFILL_TRACES = obs.counter(
     "serve.prefill_traces", "prefill compiles, by length bucket")
+_M_KV_WRITE_TRACES = obs.counter(
+    "serve.kv_write_traces", "compiled programs by the path their K/V "
+    "rows take into the pool (ops/pallas/kv_write.py: rows, blocks or "
+    "reference), one count a traced program")
 _M_TTFT = obs.histogram(
     "serve.ttft_seconds", "submit -> first generated token wall time "
     "(queue wait included)")
@@ -983,49 +987,68 @@ class ServeEngine:
         """The pow2 length bucket an ``n``-token prefill is padded to."""
         return min(max(8, 1 << (n - 1).bit_length()), self.max_seq_len)
 
-    def lowered(self, prompt_lens=()) -> dict:
-        """The jax ``Lowered`` decode step (``"decode"``) and the cold
+    def lowered(self, prompt_lens=(), *, suffix_lens=(), bursts=(),
+                cow=False, device=None) -> dict:
+        """The jax ``Lowered`` decode step (``"decode"``), the cold
         prefill program of the bucket of each of ``prompt_lens``
-        (``"prefill.<bucket>"``), lowered from the engine's own arrays as
-        ``StaticFunction.lowered()`` lowers from its call's: for
+        (``"prefill.<bucket>"``), the suffix prefill of each of
+        ``suffix_lens`` (``"suffix_prefill.<bucket>"``), the fused burst
+        of each length in ``bursts`` (``"burst.<n>"``) and, with ``cow``,
+        the copy-on-write (``"cow"``), lowered from the engine's own arrays
+        as ``StaticFunction.lowered()`` lowers from its call's: for
         ``.as_text()`` / ``.compile().as_text()``, whose ``op_name``
         metadata carries the scopes ``profiler.scope_seconds`` joins a
-        trace with. Lowering re-traces (``decode_traces`` and
+        trace with. ``device`` lowers for that device in place of the
+        arrays' own: a compile-only TPU of
+        ``jax.experimental.topologies``, whose compiler then says what
+        the chip would run. Lowering re-traces (``decode_traces`` and
         ``prefill_traces`` count it) but runs nothing and donates
         nothing."""
         import jax
         import jax.numpy as jnp
 
+        sharding = (None if device is None
+                    else jax.sharding.SingleDeviceSharding(device))
+
         def avals(*args):
             return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-                a.shape, a.dtype, sharding=getattr(a, "sharding", None)),
-                args)
+                a.shape, a.dtype,
+                sharding=sharding or getattr(a, "sharding", None)), args)
 
         state = (self._arrays, self._caches)
-        out = {"decode": self._decode_fn.lower(*avals(
-            *state, jnp.asarray(self._tokens), jnp.asarray(self._lens),
-            jnp.zeros(self.max_slots, bool), jnp.asarray(self._tables),
-            jnp.asarray(self._temps), self._key))}
+        slots = (jnp.asarray(self._tokens), jnp.asarray(self._lens),
+                 jnp.zeros(self.max_slots, bool),
+                 jnp.asarray(self._tables), jnp.asarray(self._temps))
+        row = jnp.asarray(self._tables[0])
+        out = {"decode": self._decode_fn.lower(
+            *avals(*state, *slots, self._key))}
         for b in sorted({self._bucket(int(n)) for n in prompt_lens}):
             out[f"prefill.{b}"] = self._prefill_fn.lower(*avals(
-                *state, jnp.zeros((1, b), jnp.int32), jnp.int32(1),
-                jnp.asarray(self._tables[0])))
+                *state, jnp.zeros((1, b), jnp.int32), jnp.int32(1), row))
+        for b in sorted({self._bucket(int(n)) for n in suffix_lens}):
+            out[f"suffix_prefill.{b}"] = self._suffix_prefill_fn.lower(
+                *avals(*state, jnp.zeros((1, b), jnp.int32), jnp.int32(1),
+                       jnp.int32(1), row))
+        for n in sorted({int(n) for n in bursts}):
+            out[f"burst.{n}"] = self._burst_fn.lower(n, *avals(
+                *state, *slots, jnp.asarray(self._eos),
+                jax.random.split(self._key, n)))
+        if cow:
+            out["cow"] = self._cow_fn.lower(*avals(
+                self._caches, jnp.int32(0), jnp.int32(0)))
         return out
 
-    def _scatter_kv(self, kc, vc, k_new, v_new, safe_slot):
+    def _scatter_kv(self, kc, vc, k_new, v_new, safe_slot, fresh):
         """Write per-row K/V ([rows, kvh, dh]) into the pool at flat
-        slot ids (out-of-range ids drop — that is how inactive slots
-        and pad rows are fenced off the pool)."""
-        nb, bs = self.pool.num_blocks, self.block_size
-        kvh, dh = self._nkv, self._dh
-        kc_f = kc.reshape(kvh, nb * bs, dh)
-        vc_f = vc.reshape(kvh, nb * bs, dh)
-        kc_f = kc_f.at[:, safe_slot, :].set(
-            k_new.transpose(1, 0, 2), mode="drop")
-        vc_f = vc_f.at[:, safe_slot, :].set(
-            v_new.transpose(1, 0, 2), mode="drop")
-        return (kc_f.reshape(kvh, nb, bs, dh),
-                vc_f.reshape(kvh, nb, bs, dh))
+        slot ids, in place and in the pool's own layout (out-of-range
+        ids drop — that is how inactive slots and pad rows are fenced
+        off the pool). ``fresh``: the rows start their stream (a cold
+        prefill), so they go in whole blocks."""
+        from ..ops.pallas.kv_write import kv_write
+
+        kw = dict(slots=safe_slot, rows_start_blocks=fresh,
+                  backend=self.attention_backend)
+        return kv_write(kc, k_new, **kw), kv_write(vc, v_new, **kw)
 
     def _rope_rows(self, pos):
         """cos/sin rows at per-row positions ``pos`` — computed ONCE
@@ -1055,18 +1078,26 @@ class ServeEngine:
              + rotate_half(k.astype(jnp.float32), True) * sin)
         return q.astype(self._dtype), k.astype(self._dtype)
 
-    def _stack_layers(self, p, x, rope, caches, safe_slot, attn):
+    def _stack_layers(self, p, x, rope, caches, safe_slot, attn,
+                      fresh=False):
         """ONE transformer stack for BOTH compiled steps: family
         norm/projection, rope, K/V scatter into the pool, attention
         via the provided closure, residual + FFN, final norm. ``x`` is
         [rows, H]; ``attn(q, k, v, kc, vc) -> [rows, nh*dh]`` is the
         only thing decode and prefill legitimately differ in (paged
         pool attention vs in-prompt causal softmax), so it is the only
-        thing they provide. Returns (normed hidden [rows, H],
-        new caches)."""
+        thing they provide, but for ``fresh``: whether row ``i`` is
+        position ``i`` of its stream (see ``_scatter_kv``). Returns
+        (normed hidden [rows, H], new caches)."""
         import jax
 
+        from ..ops.pallas.kv_write import kv_write_path
+
         rows = x.shape[0]
+        # executes at TRACE time only, once a compiled program
+        _M_KV_WRITE_TRACES.inc(engine=self.name, path=kv_write_path(
+            rows, self.block_size, rows_start_blocks=fresh,
+            backend=self.attention_backend))
         nh, kvh, dh = self._nh, self._nkv, self._dh
         dtype = self._dtype
 
@@ -1090,7 +1121,7 @@ class ServeEngine:
                         rows, 3, nh, dh)
                     q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]
             with scope(f"layer{i}/scatter_kv"):
-                kc, vc = self._scatter_kv(kc, vc, k, v, safe_slot)
+                kc, vc = self._scatter_kv(kc, vc, k, v, safe_slot, fresh)
             new_caches.append((kc, vc))
             with scope(f"layer{i}/attn"):
                 ctx = attn(q, k, v, kc, vc)
@@ -1269,7 +1300,7 @@ class ServeEngine:
                 v_rep.astype(jnp.float32)).reshape(tp, nh * dh)
 
         out, new_caches = self._stack_layers(p, x, rope, caches,
-                                             safe_slot, attn)
+                                             safe_slot, attn, fresh=True)
         with jax.named_scope("head"):
             h_last = jnp.take(out, n - 1, axis=0)          # [H]
             logits = _gen._head_logits(p, h_last[None, :])[0]

@@ -30,6 +30,18 @@ def _model(**kw):
     return m
 
 
+def _gpt():
+    from paddle_tpu.models import GPTConfig, GPTForCausalLM
+
+    paddle.seed(5)
+    cfg = GPTConfig.tiny(vocab_size=83, hidden_size=32,
+                         num_hidden_layers=2, num_attention_heads=4,
+                         max_position_embeddings=64)
+    m = GPTForCausalLM(cfg)
+    m.eval()
+    return m
+
+
 def _solo(model, prompt, n_new, **kw):
     """The oracle: the same prompt through a solo generate() call."""
     out = model.generate(paddle.to_tensor(prompt[None].astype("int64")),
@@ -274,14 +286,7 @@ class TestPreemptionAndQueueing:
 
 class TestGptServe:
     def test_gpt_streams_match_solo_generate(self):
-        from paddle_tpu.models import GPTConfig, GPTForCausalLM
-
-        paddle.seed(5)
-        cfg = GPTConfig.tiny(vocab_size=83, hidden_size=32,
-                             num_hidden_layers=2, num_attention_heads=4,
-                             max_position_embeddings=64)
-        model = GPTForCausalLM(cfg)
-        model.eval()
+        model = _gpt()
         rng = np.random.RandomState(4)
         eng = ServeEngine(model, max_slots=2, block_size=4,
                           num_blocks=24, max_seq_len=32, name="gpt")
@@ -572,6 +577,94 @@ class TestDecodeBursts:
         assert burst["start"] < rb.finish_time < burst["end"]
         assert rb.finish_time == pytest.approx(
             burst["start"] + per * n_decode)
+
+
+def _kv_paths(engine):
+    c = obs.registry.get("serve.kv_write_traces")
+    return {p: c.value(engine=engine, path=p) or 0
+            for p in ("rows", "blocks", "reference")}
+
+
+class TestKvWriteBackends:
+    """ISSUE 26: the in-place K/V write (ops/pallas/kv_write.py) under
+    the Pallas interpreter serves the tokens the scatter serves, token
+    for token, through every compiled program that writes the pool.
+    Blocks of 16 rows in float32 are two tiles: prompts up to 8 tokens
+    go in by rows, longer ones by blocks."""
+
+    GEO = dict(block_size=16, max_seq_len=64)
+    SCENARIOS = {
+        # five streams over three slots, then a pool too small for two
+        "churn": dict(max_slots=3, num_blocks=12,
+                      plans=[(7, 6), (19, 9), (11, 5), (5, 8), (33, 4)]),
+        "preempt": dict(max_slots=2, num_blocks=3,
+                        plans=[(14, 12), (9, 10), (5, 6)]),
+        "burst4": dict(max_slots=3, num_blocks=12, decode_burst=4,
+                       plans=[(7, 9), (3, 12), (21, 6)]),
+        # a shared 32-token head (suffix prefill), then the first
+        # prompt again, block-aligned (copy-on-write)
+        "prefix": dict(max_slots=2, num_blocks=12, prefix_cache=True,
+                       plans=[(32, 5), (37, 6), (40, 4), (32, 5)],
+                       shared=32),
+    }
+
+    def _serve(self, model, vocab, name, backend, *, plans, shared=0,
+               **geo):
+        rng = np.random.RandomState(21)
+        head = rng.randint(1, vocab, shared)
+        eng = ServeEngine(model, name=name, attention_backend=backend,
+                          **self.GEO, **geo)
+        reqs = []
+        for n, k in plans:
+            prompt = np.concatenate(
+                [head, rng.randint(1, vocab, n - shared)]).astype(np.int64)
+            reqs.append(eng.submit(prompt, max_new_tokens=k))
+            eng.step()                    # staggered arrivals
+        eng.run(max_steps=3000)
+        assert all(r.state == "FINISHED" for r in reqs)
+        return eng, reqs
+
+    @pytest.mark.parametrize("scenario", list(SCENARIOS))
+    @pytest.mark.parametrize("family", ["gpt", "llama_gqa"])
+    def test_interpret_serves_the_reference_tokens(self, family, scenario):
+        # the Llama has 4 q heads on 2 kv heads
+        model, vocab = (_gpt(), 83) if family == "gpt" else (_model(), 97)
+        out = {}
+        for backend in ("reference", "interpret"):
+            name = f"kvw-{family}-{scenario}-{backend}"
+            eng, reqs = self._serve(model, vocab, name, backend,
+                                    **self.SCENARIOS[scenario])
+            out[backend] = [r.output_ids for r in reqs]
+            paths = _kv_paths(name)
+            programs = eng.decode_traces + eng.prefill_traces
+            if backend == "reference":
+                assert paths == {"rows": 0, "blocks": 0,
+                                 "reference": programs}
+            else:
+                assert paths["reference"] == 0
+                assert paths["rows"] + paths["blocks"] == programs
+                assert paths["rows"] >= eng.decode_traces
+                assert paths["blocks"] >= 1
+        assert out["interpret"] == out["reference"]
+        if scenario == "preempt":
+            assert sum(r.preemptions for r in reqs) > 0
+        if scenario == "prefix":
+            assert obs.registry.get("serve.cow_copies").value(
+                engine=name) >= 1
+            assert obs.registry.get("serve.prefix_hits").value(
+                engine=name) >= 2
+
+    def test_each_program_counts_its_path(self):
+        """One count a traced program: the decode step, a burst and a
+        suffix prefill write by rows, and so does a cold prefill under a
+        block; a cold prefill of a block or more writes by blocks."""
+        eng = ServeEngine(_gpt(), name="kvw-paths", max_slots=2,
+                          num_blocks=8, attention_backend="interpret",
+                          **self.GEO)
+        eng.lowered(prompt_lens=(5, 20, 40), suffix_lens=(20,),
+                    bursts=(2,), cow=True)
+        assert _kv_paths("kvw-paths") == {
+            "rows": 1 + 1 + 1 + 1, "blocks": 2, "reference": 0}
 
 
 class TestLoadGenerator:

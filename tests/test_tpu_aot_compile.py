@@ -15,6 +15,7 @@ engine use. Standalone (tier-1 may stop by timeout before this file):
     JAX_PLATFORMS=cpu python -m pytest tests/test_tpu_aot_compile.py -q
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -142,6 +143,88 @@ def test_paged_decode_compiles(topo, kv_heads):
         pages, pages, _on(topo, (8,), jnp.int32),
         _on(topo, (8, 8), jnp.int32))
     assert "tpu_custom_call" in text
+
+
+def _pool_sized_copies(hlo, pool_shape):
+    """``copy`` instructions of a compiled text whose result holds as many
+    elements as the pool, whatever view of it they copy."""
+    size = int(np.prod(pool_shape))
+    found = []
+    for line in hlo.splitlines():
+        m = re.search(r"= \w+\[([\d,]+)\]\S* copy\(", line)
+        if m and np.prod([int(d) for d in m.group(1).split(",")]) >= size:
+            found.append(line.strip()[:160])
+    return found
+
+
+def _aliased_params(hlo):
+    """Parameter numbers the compiled module aliases to a result."""
+    m = re.search(r"input_output_alias=\{(.*?)\}, entry_computation_layout",
+                  hlo)
+    return ({int(p) for p in re.findall(r"\}: \((\d+),", m.group(1))}
+            if m else set())
+
+
+@pytest.mark.parametrize("kv_heads", [12, 8])
+def test_kv_write_compiles(topo, kv_heads):
+    """The in-place K/V write at the benchmark cell's pool, rows (the
+    decode step's 128, a suffix prefill's 2048) and blocks: Mosaic takes
+    the kernel, no path copies the pool and each returns its argument's
+    buffer."""
+    from paddle_tpu.ops.pallas.kv_write import (kv_write_blocks,
+                                                kv_write_kernel)
+
+    pool = _on(topo, (kv_heads, 736, 128, 128), BF16)
+    for fn, n_rows in ((kv_write_kernel, 128), (kv_write_kernel, 2048),
+                       (kv_write_blocks, 2048)):
+        lowered = jax.jit(fn, donate_argnums=(0,)).lower(
+            pool, _on(topo, (n_rows, kv_heads, 128), BF16),
+            _on(topo, (n_rows,), jnp.int32))
+        assert ("tpu_custom_call" in lowered.as_text()) == (
+            fn is kv_write_kernel)
+        hlo = lowered.compile().as_text()
+        assert not _pool_sized_copies(hlo, pool.shape)
+        assert _aliased_params(hlo) == {0}
+
+
+def test_engine_programs_copy_no_pool(topo):
+    """Two layers at the benchmark cell's serving geometry (12 kv heads of
+    128, 736 blocks of 128, 128 slots, 2048 positions): no compiled step
+    of ``ServeEngine`` holds a ``copy`` the size of a K or V pool, and
+    every donated pool is aliased to its result. With the scatter on the
+    pool's flat view (the ``reference`` write) XLA:TPU re-laid each pool
+    out and back in every program: 76% of both serving cells' device
+    time (PERF.md, PR 26). This keeps the copies from coming back."""
+    import paddle_tpu as paddle
+    from paddle_tpu.models import GPTConfig, GPTForCausalLM
+    from paddle_tpu.serve import ServeEngine
+
+    paddle.seed(0)
+    model = GPTForCausalLM(GPTConfig(
+        vocab_size=50257, hidden_size=1536, num_hidden_layers=2,
+        num_attention_heads=12, intermediate_size=6144,
+        max_position_embeddings=2048, hidden_dropout_prob=0.0,
+        attention_probs_dropout_prob=0.0))
+    model.eval()
+    model.to(dtype="bfloat16")
+    eng = ServeEngine(model, max_slots=128, block_size=128, num_blocks=736,
+                      max_seq_len=2048, prefix_cache=True, name="aot-pool",
+                      trace=False, slo=False)
+    assert eng.attention_backend == "kernel"
+    pool = (12, 736, 128, 128)
+    assert eng._caches[0][0].shape == pool
+    lowered = eng.lowered(prompt_lens=(64, 2048), suffix_lens=(64,),
+                          bursts=(2,), device=topo.devices[0])
+    assert sorted(lowered) == ["burst.2", "decode", "prefill.2048",
+                               "prefill.64", "suffix_prefill.64"]
+    # the caches are the flat arguments after the weights' leaves
+    n_arrays = len(jax.tree.leaves(eng._arrays))
+    caches = set(range(n_arrays, n_arrays + 4))
+    for name, low in lowered.items():
+        hlo = low.compile().as_text()
+        assert not _pool_sized_copies(hlo, pool), name
+        assert caches <= _aliased_params(hlo), name
+        assert "kv_write" in hlo, name
 
 
 class _TopoMesh:
