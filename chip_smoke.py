@@ -105,6 +105,14 @@ def sizes(tiny):
             paged=dict(b=8, nh=16, dh=128, pages=96, page=128, pps=8),
             kv_write=dict(kvh=12, pages=736, page=128, dh=128, slots=128,
                           prompt=2048),
+            # K-EXAONE's widths: a decode step's and a prefill piece's
+            # tokens on 16 of 128 experts; 64/8 heads over rings of two
+            # pages; a window of 128 in a 2,048 bucket
+            moe=dict(tokens=(128, 2048), width=6144, expert=2048, held=16,
+                     experts=128, top_k=8),
+            ring=dict(b=128, nh=64, kvh=8, dh=128, page=128, ring=2,
+                      window=128),
+            flash_band=((1, 64, 2048, 128), 8, 128),
             serve_requests=8, serve_new=(8, 24))
     return dict(
         llama=dict(vocab_size=512, hidden_size=128, intermediate_size=256,
@@ -116,6 +124,10 @@ def sizes(tiny):
         varlen=(512, 2, 64), rms=((2, 64, 128), (8, 128)),
         paged=dict(b=4, nh=4, dh=64, pages=24, page=16, pps=4),
         kv_write=dict(kvh=2, pages=24, page=16, dh=64, slots=4, prompt=64),
+        moe=dict(tokens=(8, 32), width=64, expert=32, held=3, experts=8,
+                 top_k=2),
+        ring=dict(b=4, nh=4, kvh=2, dh=64, page=16, ring=3, window=30),
+        flash_band=((1, 4, 256, 64), 2, 40),
         serve_requests=4, serve_new=(3, 6))
 
 
@@ -365,6 +377,95 @@ def _kv_write_case(c, *, kvh, pages, page, dh, slots, prompt):
                 f"of the pool changed")
 
 
+def _moe_case(c, *, tokens, width, expert, held, experts, top_k, tol=2e-2):
+    """The held experts' grouped products against `lax.ragged_dot` on
+    the same layout, and no assignment lost."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.core.flags import pallas_mode
+    from paddle_tpu.ops.pallas.moe_experts import experts_ffn
+
+    backend = "kernel" if pallas_mode() == "compiled" else "interpret"
+    ks = jax.random.split(jax.random.PRNGKey(held), 4)
+    gate_up = 0.02 * jax.random.normal(
+        ks[0], (held, width, 2 * expert), jnp.float32).astype(jnp.bfloat16)
+    down = 0.02 * jax.random.normal(
+        ks[1], (held, expert, width), jnp.float32).astype(jnp.bfloat16)
+    rng = np.random.RandomState(0)
+    for t in tokens:
+        x = jax.random.normal(ks[2], (t, width), jnp.bfloat16)
+        w = jax.random.uniform(ks[3], (t, top_k), jnp.float32)
+        chosen = jnp.asarray(np.stack([
+            rng.choice(experts, top_k, replace=False) for _ in range(t)]),
+            jnp.int32)
+        got, sizes = jax.jit(lambda *a: experts_ffn(
+            *a, first=1, backend=backend))(x, w, chosen, gate_up, down)
+        want, _ = jax.jit(lambda *a: experts_ffn(
+            *a, first=1, backend="reference"))(x, w, chosen, gate_up, down)
+        c.close(tol, f"moe_experts {t} tokens x {top_k} on {held} of "
+                     f"{experts} experts", got, want)
+        landed = int(((np.asarray(chosen) >= 1)
+                      & (np.asarray(chosen) < 1 + held)).sum())
+        c.check(f"moe_experts {t} tokens: every held assignment counted",
+                int(np.asarray(sizes).sum()) == landed, f"{landed}")
+
+
+def _ring_case(c, *, b, nh, kvh, dh, page, ring, window, tol=2e-2):
+    """Decode attention over a ring of pages with a lower bound."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.core.flags import pallas_mode
+    from paddle_tpu.ops.pallas.paged_attention import (
+        paged_attention_decode_kernel, paged_attention_decode_reference)
+
+    ks = jax.random.split(jax.random.PRNGKey(ring), 3)
+    q = jax.random.normal(ks[0], (b, nh, dh), jnp.bfloat16)
+    kp = jax.random.normal(ks[1], (kvh, b * ring, page, dh), jnp.bfloat16)
+    vp = jax.random.normal(ks[2], (kvh, b * ring, page, dh), jnp.bfloat16)
+    lengths = np.random.RandomState(1).randint(0, 40 * page, b)
+    lengths[:4] = [0, 1, page, window + 1]
+    lengths = jnp.asarray(lengths, jnp.int32)
+    rings = jnp.arange(b * ring, dtype=jnp.int32).reshape(b, ring)
+    kw = dict(starts=jnp.maximum(lengths - window, 0), ring=True)
+    got = jax.jit(lambda *a: paged_attention_decode_kernel(
+        *a, interpret=pallas_mode() != "compiled", **kw))(
+            q, kp, vp, lengths, rings)
+    c.close(tol, f"paged decode {nh}/{kvh} heads over rings of {ring} "
+                 f"pages, window {window}", got,
+            paged_attention_decode_reference(
+                *(x.astype(jnp.float32) for x in (q, kp, vp)), lengths,
+                rings, **kw))
+
+
+def _band_case(c, shape, kv_heads, window, tol=2e-2):
+    """The banded causal flash forward against the masked softmax."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas.flash_attention import _flash_fwd_bhsd
+
+    b, h, s, d = shape
+    ks = jax.random.split(jax.random.PRNGKey(window), 3)
+    q = jax.random.normal(ks[0], shape, jnp.bfloat16)
+    k = jax.random.normal(ks[1], (b, kv_heads, s, d), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (b, kv_heads, s, d), jnp.bfloat16)
+    got, _ = _flash_fwd_bhsd(q, k, v, causal=True, scale=d ** -0.5,
+                             window=window)
+    f32 = lambda a: jnp.repeat(a.astype(jnp.float32), h // a.shape[1], 1)
+    scores = jnp.einsum("bhqd,bhkd->bhqk", f32(q), f32(k),
+                        precision="highest") * d ** -0.5
+    i, j = jnp.arange(s)[:, None], jnp.arange(s)[None, :]
+    probs = jax.nn.softmax(jnp.where((j <= i) & (i - j < window), scores,
+                                     -jnp.inf), -1)
+    c.close(tol, f"flash causal band {window} {shape} over {kv_heads} kv "
+                 f"heads", got,
+            jnp.einsum("bhqk,bhkd->bhqd", probs, f32(v), precision="highest"))
+
+
 def phase_kernels(tiny):
     import paddle_tpu  # noqa: F401
     from paddle_tpu.core.flags import pallas_mode
@@ -398,6 +499,9 @@ def phase_kernels(tiny):
     _paged_case(c, p["nh"], **p)
     _paged_case(c, p["nh"] // 4, **p)
     _kv_write_case(c, **sz["kv_write"])
+    _moe_case(c, **sz["moe"])
+    _ring_case(c, **sz["ring"])
+    _band_case(c, *sz["flash_band"])
     c.finish()
     return {}
 

@@ -24,3 +24,6 @@ from .unet_diffusion import (
 from .ernie_moe import (
     ErnieMoeConfig, ErnieMoeForCausalLM, ErnieMoeModel, ernie_moe_shard_plan,
 )
+from .exaone_moe import (
+    ExaoneMoeConfig, ExaoneMoeForCausalLM, ExaoneMoeModel,
+)

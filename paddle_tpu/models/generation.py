@@ -40,13 +40,33 @@ output exactly equal to the target's greedy by construction).
 """
 from __future__ import annotations
 
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import numpy as np
 
 from ..core.tensor import Tensor
 
 __all__ = ["generate", "generate_speculative"]
+
+
+class LayerSpec(NamedTuple):
+    """What ``ServeEngine._stack_layers`` reads of one layer. The
+    defaults are a Llama layer's; a family whose layers differ among
+    themselves gives one a layer under ``specs`` in its parameter view
+    (``_exaone_decode_params``)."""
+
+    norm: str = "rms"              # "rms" | "layer"
+    placement: str = "pre"         # norms before ("pre") or after a sub-layer
+    proj: str = "split"            # "split" wq/wk/wv | "fused_bias" wqkv+bqkv
+    rope: bool = True
+    qk_norm: bool = False
+    window: Optional[int] = None   # None: full attention
+    ffn: str = "swiglu"            # "swiglu" | "gelu" | "moe"
+
+
+#: a GPT-2 layer
+GPT_LAYER = LayerSpec(norm="layer", proj="fused_bias", rope=False,
+                      ffn="gelu")
 
 
 def _llama_decode_params(model):
@@ -343,8 +363,52 @@ def _gpt_cached_forward(p, tokens, caches, pos, s_max, pads=None,
     return (out if return_all else out[:, -1, :]), new_caches
 
 
+def _exaone_decode_params(model):
+    """The EXAONE-MoE parameter view: the Llama view's names where the
+    leaves mean the same, ``specs`` (one ``LayerSpec`` a layer: attention
+    kind and window, RoPE or none, q/k norm, norm placement, dense or
+    expert FFN) and ``moe`` (the router's statics and the held experts)
+    for ``ServeEngine``; no dense-cache forward (serving is paged)."""
+    cfg = model.config
+    layers = []
+    for l, layer in enumerate(model.exaone.layers):
+        a, m = layer.self_attn, layer.mlp
+        lp = dict(
+            wq=a.q_proj.weight._value, wk=a.k_proj.weight._value,
+            wv=a.v_proj.weight._value, wo=a.o_proj.weight._value,
+            qn=a.q_norm.weight._value, kn=a.k_norm.weight._value,
+            ln1=layer.post_attention_layernorm.weight._value,
+            ln2=layer.post_feedforward_layernorm.weight._value)
+        if cfg.is_sparse(l):
+            lp.update(router=m.gate.weight._value,
+                      router_bias=m.gate.e_score_correction_bias._value,
+                      gate_up=m.experts.gate_up_proj._value,
+                      down=m.experts.down_proj._value)
+            m = m.shared_experts
+        lp.update(wg=m.gate_proj.weight._value, wu=m.up_proj.weight._value,
+                  wd=m.down_proj.weight._value)
+        layers.append(lp)
+    return dict(
+        embed=model.exaone.embed_tokens.weight._value,
+        norm=model.exaone.norm.weight._value,
+        head=model.lm_head.weight._value,
+        layers=layers,
+        nh=cfg.num_attention_heads, nkv=cfg.num_key_value_heads,
+        dh=cfg.head_dim, eps=cfg.rms_norm_eps, theta=cfg.rope_theta,
+        specs=tuple(cfg.layer_spec(l)
+                    for l in range(cfg.num_hidden_layers)),
+        prefill="flash",
+        moe=dict(top_k=cfg.num_experts_per_tok,
+                 scale=cfg.routed_scaling_factor,
+                 norm_topk=cfg.norm_topk_prob, first=cfg.experts_held[0],
+                 count=cfg.experts_held[1], num_experts=cfg.num_experts),
+    )
+
+
 def _decode_family(model):
     """(params, cached_forward) for a supported causal-LM family."""
+    if hasattr(model, "exaone"):
+        return _exaone_decode_params(model), None
     if hasattr(model, "llama"):
         return _llama_decode_params(model), _cached_forward
     if hasattr(model, "gpt"):

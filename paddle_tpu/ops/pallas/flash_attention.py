@@ -106,10 +106,21 @@ def _kv_head_map(g: int):
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
+def _band_blocks(i, block_q, block_k, offset, window):
+    """(first, last) key block that query block ``i`` sees under a causal
+    mask with a window: query ``r`` sees keys ``(r + offset - window,
+    r + offset]``. int32 throughout (``jax_enable_x64`` is on)."""
+    i32 = type(Z)
+    lo = jnp.maximum(i * i32(block_q) + i32(offset - window + 1), i32(0))
+    hi = i * i32(block_q) + i32(block_q - 1 + offset)
+    return jax.lax.div(lo, i32(block_k)), jax.lax.div(hi, i32(block_k))
+
+
 def _fwd_kernel(*refs, scale, causal, block_q, block_k, nk, offset,
-                rate, n_heads, has_bias=False):
+                rate, n_heads, has_bias=False, window=None):
     # offset = Sk - Sq: bottom-right-aligned causal mask (query i attends
-    # keys <= i + offset), matching paddle/XLA semantics for Sq != Sk
+    # keys <= i + offset), matching paddle/XLA semantics for Sq != Sk.
+    # window (causal only): and keys > i + offset - window, a band
     refs = list(refs)
     q_ref, k_ref, v_ref = refs[:3]
     n = 3
@@ -147,6 +158,8 @@ def _fwd_kernel(*refs, scale, causal, block_q, block_k, nk, offset,
             cols = j * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
             s = jnp.where(rows + offset >= cols, s, NEG_INF)
+            if window is not None:
+                s = jnp.where(rows + offset - cols < window, s, NEG_INF)
         m_prev = m_scr[:, :1]
         l_prev = l_scr[:, :1]
         m_cur = jnp.max(s, axis=-1, keepdims=True)
@@ -173,7 +186,14 @@ def _fwd_kernel(*refs, scale, causal, block_q, block_k, nk, offset,
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
 
-    if causal:
+    if causal and window is not None:
+        # skip blocks outside the band on either side
+        first, last = _band_blocks(i, block_q, block_k, offset, window)
+
+        @pl.when((j >= first) & (j <= last))
+        def _():
+            _compute()
+    elif causal:
         # skip blocks strictly above the (offset) diagonal
         @pl.when(j * block_k <= i * block_q + (block_q - 1) + offset)
         def _():
@@ -239,9 +259,11 @@ def _split_extras(extras, has_bias, rate, shard_axes):
 
 
 def _fwd_local(q, k, v, *extras, causal, scale, rate, has_bias, interpret,
-               shard_axes=()):
+               shard_axes=(), window=None):
     """One shard's forward: q [B,H,Sq,D]; k,v [B,Hkv,Sk,D] ->
-    (out [B,H,Sq,D], lse [B,H,Sq])."""
+    (out [B,H,Sq,D], lse [B,H,Sq]). ``window`` (with ``causal``): a
+    query sees its last ``window`` keys only; key blocks outside that
+    band are neither computed nor fetched (forward only)."""
     key_bias, seed = _split_extras(extras, has_bias, rate, shard_axes)
     B, H, Sq, D = q.shape
     Hkv, Sk = k.shape[1], k.shape[2]
@@ -254,13 +276,25 @@ def _fwd_local(q, k, v, *extras, causal, scale, rate, has_bias, interpret,
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal,
         block_q=block_q, block_k=block_k, nk=nk, offset=Sk - Sq,
-        rate=rate, n_heads=H, has_bias=has_bias)
+        rate=rate, n_heads=H, has_bias=has_bias,
+        **({} if window is None else {"window": int(window)}))
+    if window is None:
+        kv_map = lambda b, h, i, j: (b, kv_head(h), j, Z)
+    else:
+        if not causal or has_bias or rate > 0.0:
+            raise ValueError("flash forward: a window needs causal=True "
+                             "and takes neither key bias nor dropout")
+
+        def kv_map(b, h, i, j):
+            # a block outside the band is pinned to the nearest inside
+            # it, which the step before or after holds: no DMA
+            first, last = _band_blocks(i, block_q, block_k, Sk - Sq,
+                                       int(window))
+            return (b, kv_head(h), jnp.clip(j, first, last), Z)
     in_specs = [
             pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, Z)),
-            pl.BlockSpec((1, 1, block_k, D),
-                         lambda b, h, i, j: (b, kv_head(h), j, Z)),
-            pl.BlockSpec((1, 1, block_k, D),
-                         lambda b, h, i, j: (b, kv_head(h), j, Z)),
+            pl.BlockSpec((1, 1, block_k, D), kv_map),
+            pl.BlockSpec((1, 1, block_k, D), kv_map),
     ]
     inputs = [q, k, v]
     if key_bias is not None:
@@ -313,26 +347,33 @@ def _fwd_local(q, k, v, *extras, causal, scale, rate, has_bias, interpret,
 _JIT_STATICS = ("causal", "scale", "dropout_rate", "partition", "interpret")
 
 
-@functools.partial(jax.jit, static_argnames=_JIT_STATICS)
+@functools.partial(jax.jit, static_argnames=_JIT_STATICS + ("window",))
 def _flash_fwd_jit(q, k, v, seed, key_bias, *, causal, scale, dropout_rate,
-                   partition, interpret):
+                   partition, interpret, window=None):
     extras = [x for x in (key_bias, seed) if x is not None]
+    band = {} if window is None else {"window": window}
     return _run_flash(
         _fwd_local, "xkk", "xl", (q, k, v, *extras), partition=partition,
         causal=causal, scale=scale, rate=dropout_rate,
-        has_bias=key_bias is not None, interpret=interpret)
+        has_bias=key_bias is not None, interpret=interpret, **band)
 
 
 def _flash_fwd_bhsd(q, k, v, seed=None, key_bias=None, *, causal, scale,
-                    dropout_rate=0.0, partition=None):
+                    dropout_rate=0.0, partition=None, window=None,
+                    interpret=None):
     """q: [B,H,Sq,D]; k,v: [B,Hkv,Sk,D] -> (out [B,H,Sq,D], lse [B,H,Sq]).
     seed: int32 [1] dropout seed, required when dropout_rate > 0.
     key_bias: [B, Sk] additive logit bias broadcast over heads/rows (the
     padding-mask pattern), added BEFORE the causal mask/softmax.
-    partition: the :class:`KernelPartition` of a sharded program."""
+    partition: the :class:`KernelPartition` of a sharded program.
+    window: with ``causal``, a query sees its last ``window`` keys (a
+    sliding-window layer's band; forward only, as serving needs it).
+    interpret: None follows ``pallas_mode()``."""
     return _flash_fwd_jit(q, k, v, seed, key_bias, causal=causal,
                           scale=scale, dropout_rate=dropout_rate,
-                          partition=partition, interpret=_interpret())
+                          partition=partition, window=window,
+                          interpret=(_interpret() if interpret is None
+                                     else interpret))
 
 
 # ---------------------------------------------------------------------------

@@ -32,6 +32,15 @@ kernel strips the machinery down to the decode case:
   their lanes masked out of the softmax, so ragged batches cost the
   masked lanes only.
 
+A WINDOW is a lower bound beside the length: with ``starts`` ``[B]`` a
+row attends positions ``[starts, lengths)`` only. A window layer's cache
+may be a RING (``ring=True``): the table then holds ``R`` pages a row for
+good, position ``p`` lives in page ``(p // page_size) % R``, and page
+``i`` of the table holds the newest logical page ``<= (lengths - 1) //
+page_size`` that is congruent to ``i``; what an older lap left in it lies
+past ``lengths`` or before ``starts`` and is masked. Without ``starts``
+the kernel is the one it was, operand for operand.
+
 Layouts match jax's kernel convention: ``k_pages``/``v_pages`` are
 ``[KVH, total_pages, page_size, DH]`` (the serve engine stores its pool
 this way; ``_bmha_fwd``'s ``[nb, kvh, bs, dh]`` transposes into it).
@@ -53,8 +62,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+import numpy as np
+
 from ...core.flags import pallas_mode
 from .flash_attention import Z
+
+_i32 = np.int32
 
 __all__ = [
     "paged_attention_decode",
@@ -64,7 +77,15 @@ __all__ = [
 ]
 
 
-def _check_shapes(q, k_pages, v_pages, lengths, block_tables):
+def _check_shapes(q, k_pages, v_pages, lengths, block_tables,
+                  starts=None, ring=False):
+    if starts is not None and starts.shape != lengths.shape:
+        raise ValueError(
+            f"starts must be shaped as lengths {lengths.shape}, got "
+            f"{starts.shape}")
+    if ring and starts is None:
+        raise ValueError("a ring of pages needs `starts`: without a lower "
+                         "bound an older lap's rows would be read")
     if q.ndim != 3:
         raise ValueError(f"q must be [B, NH, DH], got {q.shape}")
     if k_pages.ndim != 4 or v_pages.shape != k_pages.shape:
@@ -89,15 +110,25 @@ def _check_shapes(q, k_pages, v_pages, lengths, block_tables):
             f"{block_tables.shape}")
 
 
+def _ring_first_pos(lengths, page, pps):
+    """[B, pps] first position of the logical page each ring entry holds
+    (see the module docstring); negative where the row has not reached
+    that entry yet."""
+    cur = jnp.maximum(lengths.astype(jnp.int32) - 1, 0) // page
+    i = jnp.arange(pps, dtype=jnp.int32)
+    return (cur[:, None] - (cur[:, None] - i[None, :]) % pps) * page
+
+
 def paged_attention_decode_reference(q, k_pages, v_pages, lengths,
-                                     block_tables, *, sm_scale=None):
+                                     block_tables, *, sm_scale=None,
+                                     starts=None, ring=False):
     """jnp gather reference: the masked-softmax program the kernel must
     match (one q token per row, GQA by repeat, -inf beyond ``lengths``).
 
     This is the CPU-CI code path AND the equivalence oracle promoted
     from tools/paged_kernel_probe.py. fp32 softmax, output in q.dtype.
     """
-    _check_shapes(q, k_pages, v_pages, lengths, block_tables)
+    _check_shapes(q, k_pages, v_pages, lengths, block_tables, starts, ring)
     b, nh, dh = q.shape
     kvh, _, page, _ = k_pages.shape
     pps = block_tables.shape[1]
@@ -113,7 +144,13 @@ def paged_attention_decode_reference(q, k_pages, v_pages, lengths,
         v_rows = jnp.repeat(v_rows, nh // kvh, axis=2)
     scores = jnp.einsum("bhd,bshd->bhs", q.astype(jnp.float32),
                         k_rows.astype(jnp.float32)) * scale
-    valid = jnp.arange(s_pad)[None, :] < lengths[:, None]
+    pos = jnp.arange(s_pad)[None, :]
+    if ring:
+        pos = (_ring_first_pos(lengths, page, pps)[:, :, None]
+               + jnp.arange(page)[None, None, :]).reshape(b, s_pad)
+    valid = pos < lengths[:, None]
+    if starts is not None:
+        valid &= pos >= starts[:, None]
     scores = jnp.where(valid[:, None, :], scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1)
     # a zero-length row is fully masked -> NaN; serve engines carry such
@@ -123,8 +160,11 @@ def paged_attention_decode_reference(q, k_pages, v_pages, lengths,
                       v_rows.astype(jnp.float32)).astype(q.dtype)
 
 
-def _decode_kernel_body(len_ref, tbl_ref, q_ref, k_ref, v_ref, o_ref,
-                        m_scr, l_scr, acc_scr, *, kvh, group, page, scale):
+def _decode_kernel_body(len_ref, tbl_ref, *refs, kvh, group, page, scale,
+                        windowed=False, ring=0):
+    if windowed:
+        start_ref, *refs = refs
+    q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr = refs
     b = pl.program_id(0)
     i = pl.program_id(1)
 
@@ -143,9 +183,17 @@ def _decode_kernel_body(len_ref, tbl_ref, q_ref, k_ref, v_ref, o_ref,
     s = jax.lax.dot_general(
         q, k, (((2,), (2,)), ((0,), (0,))),
         preferred_element_type=jnp.float32) * scale     # [KVH, G, PAGE]
-    pos = i * page + jax.lax.broadcasted_iota(
+    first = i * page
+    if ring:
+        # the newest logical page <= the row's last that lies in entry i
+        n = _i32(ring)                  # the ring's pages (static)
+        cur = jax.lax.div(jnp.maximum(length - 1, 0), _i32(page))
+        first = (cur - jax.lax.rem(cur - i + n, n)) * _i32(page)
+    pos = first + jax.lax.broadcasted_iota(
         jnp.int32, (kvh, group, page), 2)
     in_len = pos < length
+    if windowed:
+        in_len &= pos >= start_ref[b]
     s = jnp.where(in_len, s, -jnp.inf)
 
     m_prev = m_scr[:]
@@ -169,9 +217,9 @@ def _decode_kernel_body(len_ref, tbl_ref, q_ref, k_ref, v_ref, o_ref,
 
 def paged_attention_decode_kernel(q, k_pages, v_pages, lengths,
                                   block_tables, *, sm_scale=None,
-                                  interpret=False):
+                                  interpret=False, starts=None, ring=False):
     """The Pallas kernel proper (TPU; ``interpret=True`` on CPU)."""
-    _check_shapes(q, k_pages, v_pages, lengths, block_tables)
+    _check_shapes(q, k_pages, v_pages, lengths, block_tables, starts, ring)
     b, nh, dh = q.shape
     kvh, _npages, page, _ = k_pages.shape
     pps = block_tables.shape[1]
@@ -180,7 +228,12 @@ def paged_attention_decode_kernel(q, k_pages, v_pages, lengths,
     lengths = lengths.astype(jnp.int32)
     block_tables = block_tables.astype(jnp.int32)
 
-    def page_map(bi, i, len_ref, tbl_ref):
+    windowed = starts is not None
+
+    def page_map(bi, i, len_ref, tbl_ref, *_):
+        if ring:
+            # every entry of a ring is a page of the row's own
+            return (Z, tbl_ref[bi, i], Z, Z)
         # clamp fully-masked trailing pages to the row's last valid page
         # so no out-of-range pool page is ever fetched; their lanes are
         # masked out of the softmax by `in_len` anyway
@@ -190,7 +243,7 @@ def paged_attention_decode_kernel(q, k_pages, v_pages, lengths,
         return (Z, tbl_ref[bi, pi], Z, Z)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3 if windowed else 2,
         grid=(b, pps),
         in_specs=[
             pl.BlockSpec((1, nh, dh), lambda bi, i, *_: (bi, Z, Z)),
@@ -205,14 +258,18 @@ def paged_attention_decode_kernel(q, k_pages, v_pages, lengths,
         ],
     )
     kernel = functools.partial(_decode_kernel_body, kvh=kvh, group=group,
-                               page=page, scale=scale)
+                               page=page, scale=scale,
+                               **(dict(windowed=True,
+                                       ring=pps if ring else 0)
+                                  if windowed else {}))
+    bounds = (starts.astype(jnp.int32),) if windowed else ()
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, nh, dh), q.dtype),
         name="paged_decode",
         interpret=interpret,
-    )(lengths, block_tables, q, k_pages, v_pages)
+    )(lengths, block_tables, *bounds, q, k_pages, v_pages)
 
 
 def resolve_backend(backend: str = "auto") -> str:
@@ -229,7 +286,8 @@ def resolve_backend(backend: str = "auto") -> str:
 
 
 def paged_attention_decode(q, k_pages, v_pages, lengths, block_tables, *,
-                           sm_scale=None, backend="auto"):
+                           sm_scale=None, backend="auto", starts=None,
+                           ring=False):
     """Paged-attention for ONE decode step.
 
     Args:
@@ -244,13 +302,19 @@ def paged_attention_decode(q, k_pages, v_pages, lengths, block_tables, *,
       backend: ``"auto"`` (see :func:`resolve_backend`), ``"kernel"``,
         ``"reference"``, or ``"interpret"`` (kernel under the Pallas
         interpreter — the CPU-CI equivalence path).
+      starts: ``[B]`` int32 or None — the first position a row attends
+        (a sliding window's lower bound).
+      ring: ``block_tables`` is a ring of pages a row (module docstring);
+        needs ``starts``.
 
     Returns ``[B, NH, DH]`` in q.dtype.
     """
     backend = resolve_backend(backend)
+    window = {} if starts is None else dict(starts=starts, ring=ring)
     if backend == "reference":
         return paged_attention_decode_reference(
-            q, k_pages, v_pages, lengths, block_tables, sm_scale=sm_scale)
+            q, k_pages, v_pages, lengths, block_tables, sm_scale=sm_scale,
+            **window)
     return paged_attention_decode_kernel(
         q, k_pages, v_pages, lengths, block_tables, sm_scale=sm_scale,
-        interpret=(backend == "interpret"))
+        interpret=(backend == "interpret"), **window)

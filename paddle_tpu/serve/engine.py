@@ -148,6 +148,18 @@ _M_COW = obs.counter(
 _M_BURST_TOKENS = obs.counter(
     "serve.burst_tokens", "tokens generated inside fused multi-step "
     "decode bursts (the on-chip lax.scan path)")
+_M_MOE_ROUTED = obs.counter(
+    "serve.moe_tokens_routed", "decoded tokens that passed a sparse "
+    "layer's router (tokens x sparse layers)")
+_M_MOE_HELD = obs.counter(
+    "serve.moe_assignments_held", "of those tokens' top-k assignments, "
+    "the ones that fell on an expert this engine holds")
+_M_MOE_MAX = obs.counter(
+    "serve.moe_expert_tokens_max", "a decode step's largest held "
+    "expert's tokens, summed over steps, by sparse layer")
+_M_MOE_SUM = obs.counter(
+    "serve.moe_expert_tokens_sum", "a decode step's tokens on all held "
+    "experts, summed over steps, by sparse layer")
 _M_HOST_RT = obs.counter(
     "serve.host_roundtrips", "host->device decode dispatches — one "
     "per burst, so decode_burst=N cuts this ~N x per token")
@@ -179,6 +191,8 @@ class Request:
     state: str = QUEUED
     ids: List[int] = field(default_factory=list)   # prompt + generated
     blocks: List[int] = field(default_factory=list)
+    # the slot's ring in the window layers' pool (engines with such layers)
+    window_blocks: List[int] = field(default_factory=list)
     slot: Optional[int] = None
     admit_seq: int = -1                    # recency rank for eviction
     preemptions: int = 0
@@ -221,7 +235,16 @@ class Request:
 
 class ServeEngine:
     """Continuous-batching server over a paged KV pool (module docstring
-    has the admission/eviction contract). Llama and GPT families.
+    has the admission/eviction contract). Llama, GPT and EXAONE-MoE
+    families: ``_stack_layers`` reads one ``LayerSpec`` a layer (norm and
+    its placement, projections, RoPE, q/k norm, window, FFN kind).
+
+    Two kinds of cache: a full-attention layer's pool is the block table
+    the docstring describes (``num_blocks`` counts its blocks); a
+    sliding-window layer keeps a RING of ``ceil(window / block_size) + 1``
+    blocks a slot in a pool of its own (``window_pool``), taken with the
+    slot and given back with it, whatever the stream's length. A ring
+    cannot be shared, so ``prefix_cache`` is refused for such a model.
 
     Usage::
 
@@ -253,13 +276,26 @@ class ServeEngine:
         the PR-14 one-roundtrip-per-token loop)."""
         import jax
 
-        if not hasattr(model, "llama") and not hasattr(model, "gpt"):
+        if not any(hasattr(model, f) for f in ("llama", "gpt", "exaone")):
             raise NotImplementedError(
-                "ServeEngine supports the Llama and GPT families (the "
-                "paged-decode surface); MoE models decode on the dense "
-                f"path — got {type(model).__name__}")
-        self._is_llama = hasattr(model, "llama")
+                "ServeEngine supports the Llama and GPT families and "
+                "EXAONE-MoE (the paged-decode surface); capacity-padded "
+                "MoE models decode on the dense path — got "
+                f"{type(model).__name__}")
         p, _fwd = _gen._decode_family(model)
+        self._specs = p.get("specs") or (
+            (_gen.LayerSpec() if hasattr(model, "llama")
+             else _gen.GPT_LAYER,) * len(p["layers"]))
+        #: the layers whose FFN is sparse (their group sizes come back
+        #: from a decode step in this order)
+        self._sparse = [i for i, s in enumerate(self._specs)
+                        if s.ffn == "moe"]
+        windows = {s.window for s in self._specs if s.window is not None}
+        if len(windows) > 1:
+            raise NotImplementedError(
+                f"one window size an engine, got {sorted(windows)}")
+        #: the sliding layers' window, or None where every layer is full
+        self.window = windows.pop() if windows else None
         max_pos = p.get("max_positions")
         if max_pos is not None and max_seq_len > max_pos:
             raise ValueError(
@@ -278,6 +314,11 @@ class ServeEngine:
         self._clock = clock if clock is not None else time.perf_counter
         self.max_blocks_per_seq = -(-self.max_seq_len // self.block_size)
         self.pool = BlockPool(num_blocks, block_size)
+        #: blocks of a slot's ring, and the window layers' pool
+        self.ring_blocks = (0 if self.window is None
+                            else -(-self.window // self.block_size) + 1)
+        self.window_pool = (None if self.window is None else BlockPool(
+            self.max_slots * self.ring_blocks, block_size))
         from ..ops.pallas.paged_attention import resolve_backend
 
         #: the attention path every compiled step of this engine takes:
@@ -293,17 +334,20 @@ class ServeEngine:
         self._dtype = p["embed"].dtype
         import jax.numpy as jnp
 
-        self._caches = [
-            (jnp.zeros((self._nkv, self.pool.num_blocks, self.block_size,
-                        self._dh), self._dtype),
-             jnp.zeros((self._nkv, self.pool.num_blocks, self.block_size,
-                        self._dh), self._dtype))
-            for _ in range(self._L)]
+        def pool_of(spec):
+            pool = self.pool if spec.window is None else self.window_pool
+            return jnp.zeros((self._nkv, pool.num_blocks, self.block_size,
+                              self._dh), self._dtype)
+
+        self._caches = [(pool_of(s), pool_of(s)) for s in self._specs]
 
         # host-side slot state (jit DATA — shapes never change)
         self._slots: List[Optional[Request]] = [None] * self.max_slots
         self._tables = np.zeros(
             (self.max_slots, self.max_blocks_per_seq), np.int32)
+        # a slot's ring of window-layer blocks (no column where no layer
+        # has a window: the compiled steps then take the full table alone)
+        self._rings = np.zeros((self.max_slots, self.ring_blocks), np.int32)
         self._lens = np.zeros(self.max_slots, np.int32)
         self._tokens = np.zeros(self.max_slots, np.int32)
         self._temps = np.zeros(self.max_slots, np.float32)
@@ -315,6 +359,11 @@ class ServeEngine:
             prefix_cache = os.environ.get(
                 "PADDLE_TPU_PREFIX_CACHE", "").strip().lower() in (
                     "1", "true", "yes", "on")
+        if prefix_cache and self.window is not None:
+            raise NotImplementedError(
+                "prefix_cache with sliding-window layers: a slot's ring "
+                "of window blocks is overwritten as the stream grows and "
+                "cannot be shared between streams")
         self._prefix: Optional[PrefixCache] = (
             PrefixCache(self.block_size) if prefix_cache else None)
         if decode_burst is None:
@@ -425,12 +474,17 @@ class ServeEngine:
         # the last generated token is emitted but never written back,
         # so the KV working set is total - 1 positions
         need = self.pool.blocks_for_tokens(total - 1)
-        if need > self.pool.num_blocks:
+        if need > self.pool.num_blocks or (
+                self.window_pool is not None
+                and self.ring_blocks > self.window_pool.num_blocks):
             _M_REJECTED.inc(engine=self.name, reason="pool_too_small")
             raise ValueError(
                 f"submit: request needs {need} KV blocks "
                 f"(block_size={self.block_size}) but the whole pool is "
-                f"{self.pool.num_blocks} — it can never be admitted")
+                f"{self.pool.num_blocks} — it can never be admitted"
+                + ("" if self.window_pool is None else
+                   f" (and a ring of {self.ring_blocks} of the window "
+                   f"pool's {self.window_pool.num_blocks})"))
         req = Request(
             id=self._next_id, prompt=prompt,
             max_new_tokens=int(max_new_tokens),
@@ -485,6 +539,11 @@ class ServeEngine:
             _M_QUEUE_DEPTH.set(len(self.queue), engine=self.name)
             _M_POOL_OCCUPANCY.set(round(self.pool.occupancy, 4),
                                   engine=self.name)
+            if self.window_pool is not None:
+                for kind, pool in (("full", self.pool),
+                                   ("window", self.window_pool)):
+                    _M_POOL_OCCUPANCY.set(round(pool.occupancy, 4),
+                                          engine=self.name, kind=kind)
             _M_BATCH_FILL.set(round(n_active / self.max_slots, 4),
                               engine=self.name)
             if serving_real_work:
@@ -534,6 +593,22 @@ class ServeEngine:
         return self.finished
 
     # -- scheduling --------------------------------------------------------
+    def _table_args(self, slot: Optional[int] = None) -> tuple:
+        """The block tables as a compiled step takes them: the full
+        layers' table and, where layers have a window, the rings; one
+        slot's rows of each for a prefill."""
+        import jax.numpy as jnp
+
+        # copies (``jnp.array``), not views: on the CPU ``jnp.asarray`` may
+        # alias an aligned numpy buffer, and the scheduler writes these
+        # tables in place between steps (a served stream then read
+        # another's ring, one run in three under load)
+        pick = (lambda a: a) if slot is None else (lambda a: a[slot])
+        tables = (jnp.array(pick(self._tables)),)
+        if self.window is not None:
+            tables += (jnp.array(pick(self._rings)),)
+        return tables
+
     def _free_slot(self) -> Optional[int]:
         for i, r in enumerate(self._slots):
             if r is None:
@@ -581,7 +656,9 @@ class ServeEngine:
             need = self.pool.blocks_for_tokens(n_pre) - len(read_only)
             evictable = (self._prefix.evictable_blocks
                          if self._prefix is not None else 0)
-            if need > self.pool.free_blocks + evictable:
+            if need > self.pool.free_blocks + evictable or (
+                    self.window_pool is not None
+                    and self.ring_blocks > self.window_pool.free_blocks):
                 # head-of-line blocking is the FIFO contract: later
                 # (smaller) requests do NOT jump a starving head. Put
                 # the acquired prefix references back (registered
@@ -595,6 +672,9 @@ class ServeEngine:
             admitted += 1
             fresh = self._alloc_blocks(need)
             req.blocks = list(read_only) + fresh
+            if self.window_pool is not None:
+                req.window_blocks = self.window_pool.alloc(self.ring_blocks)
+                self._rings[slot] = req.window_blocks
             req.shared_blocks = len(read_only)
             if cow:
                 # fresh[0] sits at the divergence position: duplicate
@@ -671,7 +751,7 @@ class ServeEngine:
             if start == 0:
                 self._caches, logits = self._prefill_fn(
                     self._arrays, self._caches, jnp.asarray(padded),
-                    jnp.int32(n), jnp.asarray(self._tables[req.slot]))
+                    jnp.int32(n), self._table_args(req.slot))
             else:
                 self._caches, logits = self._suffix_prefill_fn(
                     self._arrays, self._caches, jnp.asarray(padded),
@@ -746,6 +826,9 @@ class ServeEngine:
         else:
             self.pool.free(req.blocks)
         req.blocks = []
+        if req.window_blocks:
+            self.window_pool.free(req.window_blocks)
+            req.window_blocks = []
 
     def _append_token(self, req: Request, tok: int,
                       now: Optional[float] = None):
@@ -781,6 +864,7 @@ class ServeEngine:
     def _clear_slot(self, slot: int):
         self._slots[slot] = None
         self._tables[slot] = 0
+        self._rings[slot] = 0
         self._lens[slot] = 0
         self._tokens[slot] = 0
         self._temps[slot] = 0.0
@@ -811,7 +895,8 @@ class ServeEngine:
         into; allocate at block boundaries, evicting youngest-first
         when the pool runs dry (a stream that is ITSELF the youngest
         self-preempts back to the queue rather than evicting an older
-        one).
+        one). This is the full layers' table alone: a slot's ring of
+        window blocks came with the slot and never grows.
 
         ``lookahead > 1`` (the fused-burst path) pre-allocates enough
         blocks for the next ``lookahead`` tokens so a stream one token
@@ -852,10 +937,11 @@ class ServeEngine:
             nxt, self._caches = self._decode_fn(
                 self._arrays, self._caches, jnp.asarray(self._tokens),
                 jnp.asarray(self._lens), jnp.asarray(active_np),
-                jnp.asarray(self._tables), jnp.asarray(self._temps), sub)
+                self._table_args(), jnp.asarray(self._temps), sub)
         with self._span("serve.decode.wait") as wait:
             nxt = np.asarray(nxt)
         with self._span("serve.decode.emit") as emit:
+            self._count_moe(nxt[self.max_slots:], int(active_np.sum()))
             tok0 = self._n_tokens
             for slot, req in enumerate(self._slots):
                 if req is None:
@@ -866,6 +952,19 @@ class ServeEngine:
                     self._tokens[slot] = req.ids[-1]
             emit.note(tokens=self._n_tokens - tok0)
         self._decode_done(1, dispatch, wait, emit)
+
+    def _count_moe(self, sizes: np.ndarray, n_tokens: int):
+        """Feed the ``serve.moe_*`` counters from the held experts' group
+        sizes of each sparse layer, which a decode program hands back
+        behind its tokens (nothing there for a dense model)."""
+        if not sizes.size:
+            return
+        sizes = sizes.reshape(len(self._sparse), -1)
+        _M_MOE_ROUTED.inc(n_tokens * len(self._sparse), engine=self.name)
+        _M_MOE_HELD.inc(int(sizes.sum()), engine=self.name)
+        for layer, row in zip(self._sparse, sizes):
+            _M_MOE_MAX.inc(int(row.max()), engine=self.name, layer=layer)
+            _M_MOE_SUM.inc(int(row.sum()), engine=self.name, layer=layer)
 
     def _ensure_blocks_timed(self, lookahead: int = 1) -> np.ndarray:
         """``_ensure_blocks`` with any preemption it causes, as one
@@ -942,7 +1041,7 @@ class ServeEngine:
             ys, emitted, self._caches = self._burst_fn(
                 n, self._arrays, self._caches,
                 jnp.asarray(self._tokens), jnp.asarray(self._lens),
-                jnp.asarray(active_np), jnp.asarray(self._tables),
+                jnp.asarray(active_np), self._table_args(),
                 jnp.asarray(self._temps), jnp.asarray(self._eos),
                 jnp.stack(subs))
         with self._span("serve.decode.wait") as wait:
@@ -979,7 +1078,7 @@ class ServeEngine:
         _, _, self._caches = self._burst_fn(
             int(n), self._arrays, self._caches,
             jnp.asarray(self._tokens), jnp.asarray(self._lens),
-            jnp.zeros(self.max_slots, bool), jnp.asarray(self._tables),
+            jnp.zeros(self.max_slots, bool), self._table_args(),
             jnp.asarray(self._temps), jnp.asarray(self._eos), keys)
 
     # -- compiled steps ----------------------------------------------------
@@ -1018,13 +1117,14 @@ class ServeEngine:
         state = (self._arrays, self._caches)
         slots = (jnp.asarray(self._tokens), jnp.asarray(self._lens),
                  jnp.zeros(self.max_slots, bool),
-                 jnp.asarray(self._tables), jnp.asarray(self._temps))
+                 self._table_args(), jnp.asarray(self._temps))
         row = jnp.asarray(self._tables[0])
         out = {"decode": self._decode_fn.lower(
             *avals(*state, *slots, self._key))}
         for b in sorted({self._bucket(int(n)) for n in prompt_lens}):
             out[f"prefill.{b}"] = self._prefill_fn.lower(*avals(
-                *state, jnp.zeros((1, b), jnp.int32), jnp.int32(1), row))
+                *state, jnp.zeros((1, b), jnp.int32), jnp.int32(1),
+                self._table_args(0)))
         for b in sorted({self._bucket(int(n)) for n in suffix_lens}):
             out[f"suffix_prefill.{b}"] = self._suffix_prefill_fn.lower(
                 *avals(*state, jnp.zeros((1, b), jnp.int32), jnp.int32(1),
@@ -1038,17 +1138,55 @@ class ServeEngine:
                 self._caches, jnp.int32(0), jnp.int32(0)))
         return out
 
-    def _scatter_kv(self, kc, vc, k_new, v_new, safe_slot, fresh):
+    def _scatter_kv(self, spec, kc, vc, k_new, v_new, slots, fresh):
         """Write per-row K/V ([rows, kvh, dh]) into the pool at flat
         slot ids, in place and in the pool's own layout (out-of-range
         ids drop — that is how inactive slots and pad rows are fenced
-        off the pool). ``fresh``: the rows start their stream (a cold
-        prefill), so they go in whole blocks."""
+        off the pool). ``slots`` gives the ids by the layer's kind of
+        cache (``_full_slots`` / ``_ring_slots``). ``fresh``: the rows
+        start their stream (a cold prefill), so they go in whole blocks;
+        of a window layer's only the ring's last blocks are written,
+        ``slots["window"]`` then being (first row, rows, their ids)."""
+        from jax import lax
+
         from ..ops.pallas.kv_write import kv_write
 
+        if spec.window is None:
+            safe_slot = slots["full"]
+        elif fresh:
+            start, rows, safe_slot = slots["window"]
+            k_new = lax.dynamic_slice_in_dim(k_new, start, rows, axis=0)
+            v_new = lax.dynamic_slice_in_dim(v_new, start, rows, axis=0)
+        else:
+            safe_slot = slots["window"]
         kw = dict(slots=safe_slot, rows_start_blocks=fresh,
                   backend=self.attention_backend)
         return kv_write(kc, k_new, **kw), kv_write(vc, v_new, **kw)
+
+    def _full_slots(self, table, positions, written):
+        """Flat pool slot of each position through a full layer's block
+        table (``table`` [rows, blocks] or one row); rows not
+        ``written`` get an id past the pool, which drops them."""
+        import jax.numpy as jnp
+
+        bs = self.block_size
+        bi = jnp.clip(positions // bs, 0, self.max_blocks_per_seq - 1)
+        phys = (jnp.take(table, bi) if table.ndim == 1 else
+                jnp.take_along_axis(table, bi[:, None], axis=1)[:, 0])
+        return jnp.where(written, phys * bs + positions % bs,
+                         self.pool.num_blocks * bs)
+
+    def _ring_slots(self, ring, positions, written):
+        """The same through a slot's ring: position ``p`` lies in entry
+        ``(p // block_size) % ring_blocks``."""
+        import jax.numpy as jnp
+
+        bs = self.block_size
+        ri = (positions // bs) % self.ring_blocks
+        phys = (jnp.take(ring, ri) if ring.ndim == 1 else
+                jnp.take_along_axis(ring, ri[:, None], axis=1)[:, 0])
+        return jnp.where(written, phys * bs + positions % bs,
+                         self.window_pool.num_blocks * bs)
 
     def _rope_rows(self, pos):
         """cos/sin rows at per-row positions ``pos`` — computed ONCE
@@ -1067,7 +1205,7 @@ class ServeEngine:
 
     def _rope(self, q, k, cos, sin):
         """Rotate q/k ([rows, heads, dh]) by precomputed cos/sin rows
-        (Llama families only)."""
+        (the layers whose spec says ``rope``)."""
         import jax.numpy as jnp
 
         from ..incubate.nn.functional._rope_common import rotate_half
@@ -1078,19 +1216,38 @@ class ServeEngine:
              + rotate_half(k.astype(jnp.float32), True) * sin)
         return q.astype(self._dtype), k.astype(self._dtype)
 
-    def _stack_layers(self, p, x, rope, caches, safe_slot, attn,
-                      fresh=False):
-        """ONE transformer stack for BOTH compiled steps: family
-        norm/projection, rope, K/V scatter into the pool, attention
-        via the provided closure, residual + FFN, final norm. ``x`` is
-        [rows, H]; ``attn(q, k, v, kc, vc) -> [rows, nh*dh]`` is the
-        only thing decode and prefill legitimately differ in (paged
-        pool attention vs in-prompt causal softmax), so it is the only
-        thing they provide, but for ``fresh``: whether row ``i`` is
-        position ``i`` of its stream (see ``_scatter_kv``). Returns
-        (normed hidden [rows, H], new caches)."""
+    def _embed(self, p, tokens, positions):
+        """Token rows and what the family adds to them of position:
+        (x, the rope rows or None)."""
+        import jax.numpy as jnp
+
+        x = jnp.take(p["embed"], tokens, axis=0)
+        if tokens.ndim == 2:            # a prefill's [1, bucket] ids
+            x = x[0]
+        rope = None
+        if any(s.rope for s in self._specs):
+            rope = self._rope_rows(positions)
+        if "wpe" in p:
+            x = x + jnp.take(p["wpe"], positions, axis=0)
+        return x, rope
+
+    def _stack_layers(self, p, x, rope, caches, slots, attn,
+                      fresh=False, valid=None):
+        """ONE transformer stack for BOTH compiled steps, read off each
+        layer's ``LayerSpec``: norm and projection, q/k norm, rope, K/V
+        scatter into the layer's kind of pool, attention via the
+        provided closure, residual + FFN (dense or expert), final norm.
+        ``x`` is [rows, H]; ``attn(spec, q, k, v, kc, vc) -> [rows,
+        nh*dh]`` is the only thing decode and prefill legitimately
+        differ in (paged pool attention vs in-prompt causal attention),
+        so it is the only thing they provide, but for ``fresh``: whether
+        row ``i`` is position ``i`` of its stream (see ``_scatter_kv``).
+        ``valid`` marks the rows that are tokens (a sparse layer routes
+        the others nowhere). Returns (normed hidden [rows, H], new
+        caches, the held experts' group sizes of each sparse layer)."""
         import jax
 
+        from ..models.exaone_moe import moe_ffn
         from ..ops.pallas.kv_write import kv_write_path
 
         rows = x.shape[0]
@@ -1100,52 +1257,71 @@ class ServeEngine:
             backend=self.attention_backend))
         nh, kvh, dh = self._nh, self._nkv, self._dh
         dtype = self._dtype
+        eps = p["eps"]
+
+        def norm(spec, x, lp, which):
+            if spec.norm == "rms":
+                return _gen._rms(x, lp[which], eps, dtype)
+            return _gen._ln(x, lp[which + "_w"], lp[which + "_b"], eps,
+                            dtype)
 
         # scopes by hand, as nn.Layer.__call__ gives them to the eager
         # stack: they are what the op metadata of the compiled steps, and
         # with it XProf and profiler.scope_seconds, name device time by
         scope = jax.named_scope
-        new_caches = []
-        for i, (lp, (kc, vc)) in enumerate(zip(p["layers"], caches)):
+        new_caches, moe_sizes = [], []
+        for i, (lp, spec, (kc, vc)) in enumerate(
+                zip(p["layers"], self._specs, caches)):
+            pre = spec.placement == "pre"
             with scope(f"layer{i}/qkv"):
-                if self._is_llama:
-                    h = _gen._rms(x, lp["ln1"], p["eps"], dtype)
+                h = norm(spec, x, lp, "ln1") if pre else x
+                if spec.proj == "split":
                     q = (h @ lp["wq"]).reshape(rows, nh, dh)
                     k = (h @ lp["wk"]).reshape(rows, kvh, dh)
                     v = (h @ lp["wv"]).reshape(rows, kvh, dh)
-                    q, k = self._rope(q, k, *rope)
                 else:
-                    h = _gen._ln(x, lp["ln1_w"], lp["ln1_b"], p["eps"],
-                                 dtype)
                     qkv = (h @ lp["wqkv"] + lp["bqkv"]).reshape(
                         rows, 3, nh, dh)
                     q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]
+                if spec.qk_norm:
+                    q = _gen._rms(q, lp["qn"], eps, dtype)
+                    k = _gen._rms(k, lp["kn"], eps, dtype)
+                if spec.rope:
+                    q, k = self._rope(q, k, *rope)
             with scope(f"layer{i}/scatter_kv"):
-                kc, vc = self._scatter_kv(kc, vc, k, v, safe_slot, fresh)
+                kc, vc = self._scatter_kv(spec, kc, vc, k, v, slots, fresh)
             new_caches.append((kc, vc))
             with scope(f"layer{i}/attn"):
-                ctx = attn(q, k, v, kc, vc)
+                ctx = attn(spec, q, k, v, kc, vc)
             with scope(f"layer{i}/out"):
-                if self._is_llama:
+                if spec.proj != "split":
+                    x = x + ctx.astype(dtype) @ lp["wo"] + lp["bo"]
+                elif pre:
                     x = x + ctx.astype(dtype) @ lp["wo"]
                 else:
-                    x = x + ctx.astype(dtype) @ lp["wo"] + lp["bo"]
+                    x = x + norm(spec, ctx.astype(dtype) @ lp["wo"], lp,
+                                 "ln1")
+            if spec.ffn == "moe":
+                f, sizes = moe_ffn(
+                    x, lp, p["moe"], dtype, valid=valid,
+                    backend=self.attention_backend, scope=f"layer{i}/moe")
+                moe_sizes.append(sizes)
+                with scope(f"layer{i}/moe/combine"):
+                    x = x + norm(spec, f, lp, "ln2")
+                continue
             with scope(f"layer{i}/ffn"):
-                if self._is_llama:
-                    x = x + _gen._llama_ffn(
-                        _gen._rms(x, lp["ln2"], p["eps"], dtype), lp,
-                        dtype)
+                ffn = _gen._llama_ffn if spec.ffn == "swiglu" \
+                    else _gen._gpt_ffn
+                if pre:
+                    x = x + ffn(norm(spec, x, lp, "ln2"), lp, dtype)
                 else:
-                    x = x + _gen._gpt_ffn(
-                        _gen._ln(x, lp["ln2_w"], lp["ln2_b"], p["eps"],
-                                 dtype), lp, dtype)
+                    x = x + norm(spec, ffn(x, lp, dtype), lp, "ln2")
         with scope("final_norm"):
-            if self._is_llama:
-                out = _gen._rms(x, p["norm"], p["eps"], dtype)
+            if self._specs[-1].norm == "rms":
+                out = _gen._rms(x, p["norm"], eps, dtype)
             else:
-                out = _gen._ln(x, p["normf_w"], p["normf_b"], p["eps"],
-                               dtype)
-        return out, new_caches
+                out = _gen._ln(x, p["normf_w"], p["normf_b"], eps, dtype)
+        return out, new_caches, moe_sizes
 
     def _decode_impl(self, arrays, caches, tokens, lens, active, tables,
                      temps, key):
@@ -1155,18 +1331,21 @@ class ServeEngine:
         sample. Shapes are fixed at [max_slots, ...]; slot churn is
         data, so this traces exactly once per engine (asserted via
         ``serve.decode_traces``). The caches are DONATED: the pool
-        updates in place instead of being copied per token."""
-        import jax
+        updates in place instead of being copied per token. A model
+        with sparse layers hands their held experts' group sizes back
+        behind the tokens, in the same array (one transfer)."""
         import jax.numpy as jnp
-
-        from ..ops.pallas.paged_attention import paged_attention_decode
 
         # executes at TRACE time only — the flatness counter the e2e
         # continuous-batching test pins at 1
         self.decode_traces += 1
         _M_DECODE_TRACES.inc(engine=self.name)
-        return self._decode_core(caches, tokens, lens, active, tables,
-                                 temps, key, arrays=arrays)
+        nxt, new_caches, moe_sizes = self._decode_core(
+            caches, tokens, lens, active, tables, temps, key, arrays=arrays)
+        if moe_sizes:
+            nxt = jnp.concatenate(
+                [nxt, jnp.stack(moe_sizes).reshape(-1).astype(nxt.dtype)])
+        return nxt, new_caches
 
     def _decode_core(self, caches, tokens, lens, active, tables, temps,
                      key, *, arrays=None, p=None):
@@ -1182,34 +1361,34 @@ class ServeEngine:
             p = {**arrays, **self._static}
         b = self.max_slots
         nh = self._nh
-        nb, bs = self.pool.num_blocks, self.block_size
+        table, *ring = tables
 
         with jax.named_scope("embed"):
-            x = jnp.take(p["embed"], tokens, axis=0)      # [B, H]
             pos = lens.astype(jnp.int32)
-            rope = None
-            if self._is_llama:
-                rope = self._rope_rows(pos)
-            else:
-                x = x + jnp.take(p["wpe"], pos, axis=0)
+            x, rope = self._embed(p, tokens, pos)         # [B, H]
         lengths = jnp.where(active, pos + 1, 0)
-        bi = jnp.clip(pos // bs, 0, self.max_blocks_per_seq - 1)
-        phys = jnp.take_along_axis(tables, bi[:, None], axis=1)[:, 0]
-        slot = phys * bs + pos % bs
-        safe_slot = jnp.where(active, slot, nb * bs)      # OOB drops
+        slots = {"full": self._full_slots(table, pos, active)}  # OOB drops
+        if ring:
+            slots["window"] = self._ring_slots(ring[0], pos, active)
+            starts = jnp.maximum(lengths - self.window, 0)
 
-        def attn(q, _k, _v, kc, vc):
+        def attn(spec, q, _k, _v, kc, vc):
+            if spec.window is None:
+                return paged_attention_decode(
+                    q, kc, vc, lengths, table,
+                    backend=self.attention_backend).reshape(
+                        b, nh * self._dh)
             return paged_attention_decode(
-                q, kc, vc, lengths, tables,
+                q, kc, vc, lengths, ring[0], starts=starts, ring=True,
                 backend=self.attention_backend).reshape(b, nh * self._dh)
 
-        out, new_caches = self._stack_layers(p, x, rope, caches,
-                                             safe_slot, attn)
+        out, new_caches, moe_sizes = self._stack_layers(
+            p, x, rope, caches, slots, attn, valid=active)
         with jax.named_scope("head"):
             logits = _gen._head_logits(p, out).astype(jnp.float32)  # [B, V]
         with jax.named_scope("sample"):
             nxt = _gen._sample_slot_tokens(logits, temps, key)
-        return nxt, new_caches
+        return nxt, new_caches, moe_sizes
 
     def _burst_impl(self, n, arrays, caches, tokens, lens, active,
                     tables, temps, eos_arr, keys):
@@ -1234,7 +1413,8 @@ class ServeEngine:
 
         def tick(carry, key):
             tokens, lens, active, emitted, caches = carry
-            nxt, caches = self._decode_core(
+            # (a sparse model's group sizes are not carried out of a burst)
+            nxt, caches, _ = self._decode_core(
                 caches, tokens, lens, active, tables, temps, key, p=p)
             hit = active & (eos_arr >= 0) & (nxt == eos_arr)
             carry = (jnp.where(active, nxt, tokens),
@@ -1250,12 +1430,16 @@ class ServeEngine:
             length=n)
         return ys, emitted, caches
 
-    def _prefill_impl(self, arrays, caches, ids, n, table_row):
+    def _prefill_impl(self, arrays, caches, ids, n, table_rows):
         """Prompt prefill for ONE stream: causal self-attention over
         the (bucket-padded) prompt, K/V scattered into this stream's
         pool blocks (donated — updated in place), last real token's
         logits returned. Compiles once per power-of-two length bucket
-        (``serve.prefill_traces``)."""
+        (``serve.prefill_traces``). A family whose view says
+        ``prefill="flash"`` attends through the flash forward kernel
+        (GQA by index map, a band on window layers) wherever kernels
+        run; the others, and every family on the reference backend,
+        through the float32 masked softmax."""
         import jax
         import jax.numpy as jnp
 
@@ -1266,41 +1450,62 @@ class ServeEngine:
         p = {**arrays, **self._static}
         tp = ids.shape[1]
         nh, kvh, dh = self._nh, self._nkv, self._dh
-        nb, bs = self.pool.num_blocks, self.block_size
+        bs = self.block_size
         group = nh // kvh
+        table_row, *ring_row = table_rows
 
         positions = jnp.arange(tp, dtype=jnp.int32)
         valid = positions < n                              # [Tp]
         with jax.named_scope("embed"):
-            x = jnp.take(p["embed"], ids, axis=0)[0]       # [Tp, H]
-            rope = None
-            if self._is_llama:
-                rope = self._rope_rows(positions)
-            else:
-                x = x + jnp.take(p["wpe"], positions, axis=0)
+            x, rope = self._embed(p, ids, positions)    # [Tp, H]
         # causal within the prompt; pad rows see themselves only (their
         # K/V never reach the pool and their logits are never read)
         causal = (positions[None, :] <= positions[:, None]) \
             & valid[None, :]                               # [Tq, Tk]
 
-        bi = jnp.clip(positions // bs, 0, self.max_blocks_per_seq - 1)
-        slot = jnp.take(table_row, bi) * bs + positions % bs
-        safe_slot = jnp.where(valid, slot, nb * bs)
+        slots = {"full": self._full_slots(table_row, positions, valid)}
+        if ring_row:
+            # only what the ring can hold is written: the blocks from
+            # ring_blocks - 1 before the prompt's last one on
+            rows = min(tp, self.ring_blocks * bs)
+            start = jnp.maximum(
+                (n - 1) // bs - (self.ring_blocks - 1), 0) * bs
+            kept = start + jnp.arange(rows, dtype=jnp.int32)
+            slots["window"] = (start, rows, self._ring_slots(
+                ring_row[0], kept, kept < n))
+        flash = (p.get("prefill") == "flash"
+                 and self.attention_backend != "reference")
 
-        def attn(q, k, v, _kc, _vc):
+        def attn(spec, q, k, v, _kc, _vc):
+            if flash:
+                from ..ops.pallas.flash_attention import _flash_fwd_bhsd
+
+                out, _ = _flash_fwd_bhsd(
+                    *(a.transpose(1, 0, 2)[None] for a in (q, k, v)),
+                    causal=True, scale=dh ** -0.5, window=spec.window,
+                    interpret=self.attention_backend == "interpret")
+                return out[0].transpose(1, 0, 2).reshape(tp, nh * dh)
+            seen = causal
+            if spec.window is not None:
+                # (a pad row far past the prompt would see no key at
+                # all; it keeps itself, so that no NaN reaches the V
+                # rows the next layer multiplies by 0)
+                seen = (seen & (positions[:, None] - positions[None, :]
+                                < spec.window)) | (
+                    positions[:, None] == positions[None, :])
             k_rep = jnp.repeat(k, group, axis=1) if group > 1 else k
             v_rep = jnp.repeat(v, group, axis=1) if group > 1 else v
             scores = jnp.einsum(
                 "qhd,khd->hqk", q.astype(jnp.float32),
                 k_rep.astype(jnp.float32)) * (dh ** -0.5)
-            scores = jnp.where(causal[None], scores, -jnp.inf)
+            scores = jnp.where(seen[None], scores, -jnp.inf)
             probs = jax.nn.softmax(scores, axis=-1)
             return jnp.einsum(
                 "hqk,khd->qhd", probs,
                 v_rep.astype(jnp.float32)).reshape(tp, nh * dh)
 
-        out, new_caches = self._stack_layers(p, x, rope, caches,
-                                             safe_slot, attn, fresh=True)
+        out, new_caches, _ = self._stack_layers(
+            p, x, rope, caches, slots, attn, fresh=True, valid=valid)
         with jax.named_scope("head"):
             h_last = jnp.take(out, n - 1, axis=0)          # [H]
             logits = _gen._head_logits(p, h_last[None, :])[0]
@@ -1329,33 +1534,25 @@ class ServeEngine:
         p = {**arrays, **self._static}
         tp = ids.shape[1]
         nh, dh = self._nh, self._dh
-        nb, bs = self.pool.num_blocks, self.block_size
 
         offs = jnp.arange(tp, dtype=jnp.int32)
         positions = start + offs                           # absolute
         valid = offs < n
         with jax.named_scope("embed"):
-            x = jnp.take(p["embed"], ids, axis=0)[0]       # [Tp, H]
-            rope = None
-            if self._is_llama:
-                rope = self._rope_rows(positions)
-            else:
-                x = x + jnp.take(p["wpe"], positions, axis=0)
+            x, rope = self._embed(p, ids, positions)    # [Tp, H]
 
-        bi = jnp.clip(positions // bs, 0, self.max_blocks_per_seq - 1)
-        slot = jnp.take(table_row, bi) * bs + positions % bs
-        safe_slot = jnp.where(valid, slot, nb * bs)
+        slots = {"full": self._full_slots(table_row, positions, valid)}
         lengths = jnp.where(valid, positions + 1, 0)       # causal
         tables_rep = jnp.broadcast_to(
             table_row[None, :], (tp, table_row.shape[0]))
 
-        def attn(q, _k, _v, kc, vc):
+        def attn(_spec, q, _k, _v, kc, vc):
             return paged_attention_decode(
                 q, kc, vc, lengths, tables_rep,
                 backend=self.attention_backend).reshape(tp, nh * dh)
 
-        out, new_caches = self._stack_layers(p, x, rope, caches,
-                                             safe_slot, attn)
+        out, new_caches, _ = self._stack_layers(
+            p, x, rope, caches, slots, attn, valid=valid)
         with jax.named_scope("head"):
             h_last = jnp.take(out, n - 1, axis=0)          # [H]
             logits = _gen._head_logits(p, h_last[None, :])[0]

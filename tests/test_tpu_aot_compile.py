@@ -227,6 +227,108 @@ def test_engine_programs_copy_no_pool(topo):
         assert "kv_write" in hlo, name
 
 
+@pytest.mark.parametrize("tokens", [128, 2048])
+def test_moe_experts_compiles(topo, tokens):
+    """The held experts' grouped products at the published widths (16
+    experts of [6144, 4096] gate-up and [2048, 6144] down), a decode
+    step's 128 x 8 rows and a prefill piece's 2,048 x 8: Mosaic takes the
+    kernel, the group sizes being data."""
+    from paddle_tpu.ops.pallas.moe_experts import buffer_rows, experts_ffn
+
+    def fn(x, w, experts, gate_up, down):
+        return experts_ffn(x, w, experts, gate_up, down, first=0)
+
+    text, compiled = _compile(
+        fn, _on(topo, (tokens, 6144), BF16),
+        _on(topo, (tokens, 8), jnp.float32), _on(topo, (tokens, 8), jnp.int32),
+        _on(topo, (16, 6144, 4096), BF16), _on(topo, (16, 2048, 6144), BF16))
+    assert text.count("tpu_custom_call") == 2 and "moe_experts" in text
+    assert buffer_rows(tokens, 8, 16, 128) == tokens * 8 + 16 * 128
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
+def test_paged_decode_window_ring_compiles(topo):
+    """The decode kernel with a lower bound over a ring of two pages, at
+    64 query heads over 8 key-value heads."""
+    from paddle_tpu.ops.pallas.paged_attention import (
+        paged_attention_decode_kernel)
+
+    def fn(q, k, v, lengths, rings, starts):
+        return paged_attention_decode_kernel(q, k, v, lengths, rings,
+                                             starts=starts, ring=True)
+
+    text, _ = _compile(
+        fn, _on(topo, (128, 64, 128), BF16),
+        _on(topo, (8, 256, 128, 128), BF16),
+        _on(topo, (8, 256, 128, 128), BF16), _on(topo, (128,), jnp.int32),
+        _on(topo, (128, 2), jnp.int32), _on(topo, (128,), jnp.int32))
+    assert "tpu_custom_call" in text and "paged_decode" in text
+
+
+def test_flash_forward_band_compiles(topo):
+    """The banded causal forward of a window layer's prefill at the
+    largest bucket: [1, 64, 8192, 128] over 8 key-value heads, window 128."""
+    from paddle_tpu.ops.pallas.flash_attention import _flash_fwd_bhsd
+
+    def fn(q, k, v):
+        return _flash_fwd_bhsd(q, k, v, causal=True, scale=128 ** -0.5,
+                               window=128)
+
+    text, _ = _compile(fn, _on(topo, (1, 64, 8192, 128), BF16),
+                       _on(topo, (1, 8, 8192, 128), BF16),
+                       _on(topo, (1, 8, 8192, 128), BF16))
+    assert "tpu_custom_call" in text and "flash_fwd" in text
+
+
+def _copies_shaped_like(hlo, pool_shape):
+    """``copy`` instructions whose result has the pool's dimensions, in
+    whatever order (a prompt's activations are larger than a ring pool,
+    so size alone does not tell them apart)."""
+    want = sorted(pool_shape)
+    found = []
+    for line in hlo.splitlines():
+        m = re.search(r"= \w+\[([\d,]+)\]\S* copy\(", line)
+        if m and sorted(int(d) for d in m.group(1).split(",")) == want:
+            found.append(line.strip()[:160])
+    return found
+
+
+def test_exaone_engine_programs_copy_no_pool(topo):
+    """Two layers of EXAONE-MoE at the published widths and the cell's
+    geometry (a dense sliding layer over a ring pool, a sparse full
+    layer over the block table): the decode step and the 64 and 2,048
+    prefill buckets hold no ``copy`` shaped like either pool, alias all
+    four donated pools, and run ``kv_write``, ``paged_decode``,
+    ``moe_experts`` and (prefill) ``flash_fwd``."""
+    from paddle_tpu.models.exaone_moe import (ExaoneMoeConfig,
+                                              ExaoneMoeForCausalLM)
+    from paddle_tpu.serve import ServeEngine
+
+    model = ExaoneMoeForCausalLM(ExaoneMoeConfig(
+        vocab_size=19200, num_hidden_layers=2,
+        layer_types=("sliding_attention", "full_attention"),
+        experts_held=(0, 16), dtype="bfloat16", deferred_init=True))
+    model.eval()
+    eng = ServeEngine(model, max_slots=128, block_size=128, num_blocks=1024,
+                      max_seq_len=8192, name="aot-exaone", trace=False,
+                      slo=False)
+    assert eng.attention_backend == "kernel" and eng.ring_blocks == 2
+    ring, table = (8, 256, 128, 128), (8, 1024, 128, 128)
+    assert [c[0].shape for c in eng._caches] == [ring, table]
+    lowered = eng.lowered(prompt_lens=(64, 2048), device=topo.devices[0])
+    n_arrays = len(jax.tree.leaves(eng._arrays))
+    caches = set(range(n_arrays, n_arrays + 4))
+    for name, low in lowered.items():
+        hlo = low.compile().as_text()
+        for pool in (ring, table):
+            assert not _copies_shaped_like(hlo, pool), name
+        assert not _pool_sized_copies(hlo, table), name
+        assert caches <= _aliased_params(hlo), name
+        for kernel in ("kv_write", "moe_experts") + (
+                ("paged_decode",) if name == "decode" else ("flash_fwd",)):
+            assert kernel in hlo, (name, kernel)
+
+
 class _TopoMesh:
     """The slice of ``ProcessMesh`` a KernelPartition needs, over
     compile-only devices (a ProcessMesh indexes ``jax.devices()``)."""

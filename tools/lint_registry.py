@@ -210,6 +210,10 @@ SERVE_REQUIRED_LABELS = {
     "serve.cow_copies": ("engine",),
     "serve.burst_tokens": ("engine",),
     "serve.host_roundtrips": ("engine",),
+    "serve.moe_tokens_routed": ("engine",),
+    "serve.moe_assignments_held": ("engine",),
+    "serve.moe_expert_tokens_max": ("engine", "layer"),
+    "serve.moe_expert_tokens_sum": ("engine", "layer"),
 }
 
 #: request-tracing / SLO label discipline (observability/tracing.py +
