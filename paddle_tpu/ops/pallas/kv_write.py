@@ -55,7 +55,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .flash_attention import Z
-from .paged_attention import resolve_backend
+from .paged_attention import _for, resolve_backend
 
 __all__ = ["kv_write", "kv_write_path", "kv_write_reference",
            "kv_write_kernel", "kv_write_blocks"]
@@ -67,16 +67,6 @@ _MAX_WAVE = 128
 
 
 _i32 = np.int32
-
-
-def _for(n, body, carry):
-    """``lax.fori_loop(0, n, body, carry)`` counting in int32: with
-    static bounds and ``jax_enable_x64`` on, fori_loop counts in int64,
-    which Mosaic does not lower."""
-    return lax.while_loop(
-        lambda c: c[0] < _i32(n),
-        lambda c: (c[0] + _i32(1), body(c[0], c[1])),
-        (_i32(0), carry))[1]
 
 
 def _check_shapes(pool, rows, slots):

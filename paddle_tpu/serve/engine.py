@@ -95,6 +95,14 @@ _M_QUEUE_DEPTH = obs.gauge(
     "serve.queue_depth", "requests waiting for a decode slot")
 _M_POOL_OCCUPANCY = obs.gauge(
     "serve.pool_occupancy", "fraction of KV pool blocks allocated")
+_M_PAGES_LIVE = obs.counter(
+    "serve.paged_pages_live", "pages the active streams hold at a decode "
+    "step (ceil(length / block_size), the step's own token counted), "
+    "summed over steps: what paged_decode walks")
+_M_PAGES_TABLE = obs.counter(
+    "serve.paged_pages_table", "entries of the full layers' block table "
+    "(max_slots x its width), summed over decode steps: what a walk of "
+    "the whole table would visit")
 _M_BATCH_FILL = obs.gauge(
     "serve.batch_fill", "active streams / max_slots at the last step")
 _M_TOKENS_PER_SEC = obs.gauge(
@@ -974,7 +982,12 @@ class ServeEngine:
             self._ensure_blocks(lookahead)
             sp.note(preemptions=self._n_preempts - pre0)
         self._secs["ensure_blocks"] += sp.seconds
-        return np.array([r is not None for r in self._slots], bool)
+        active = np.array([r is not None for r in self._slots], bool)
+        if active.any():          # a decode step follows
+            _M_PAGES_LIVE.inc(int((self._lens[active] // self.block_size
+                                   + 1).sum()), engine=self.name)
+            _M_PAGES_TABLE.inc(self._tables.size, engine=self.name)
+        return active
 
     def _decode_done(self, n: int, dispatch, wait, emit):
         """Feed everything that reads one decode program's times from

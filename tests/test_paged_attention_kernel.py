@@ -198,3 +198,129 @@ class TestKernel:
                             - np.asarray(ref, np.float32)))
         assert err < 0.05, \
             f"kernel diverges from masked-softmax reference: {err}"
+
+
+def _pool(seed, b, nh, kvh, dh, page, npages):
+    rng = np.random.default_rng(seed)
+    return (rng.normal(size=(b, nh, dh)).astype(np.float32),
+            rng.normal(size=(kvh, npages, page, dh)).astype(np.float32),
+            rng.normal(size=(kvh, npages, page, dh)).astype(np.float32))
+
+
+def _tables(seed, lens, page, pps, npages, junk):
+    """Each row's live entries are pages of its own, drawn without
+    replacement; an entry past them is ``junk``, which a walk of the
+    live pages alone never reads (out of the pool's range where the
+    case says so)."""
+    rng = np.random.default_rng(seed)
+    free = iter(rng.permutation(npages))
+    tbl = np.full((len(lens), pps), junk, np.int32)
+    for r, n in enumerate(lens):
+        held = -(-int(n) // page)
+        tbl[r, :held] = [next(free) for _ in range(held)]
+    return tbl
+
+
+PAGE = 8
+# what the loop over live pages can get wrong, by lengths and lower bounds
+# of a batch over a table 6 wide (None: no lower bound)
+LOOP_CASES = {
+    "empty-row-between-live-rows": ([13, 0, 22, 0, 0, 5], None),
+    "all-rows-empty": ([0, 0, 0], None),
+    "first-and-last-rows-empty": ([0, 9, 17, 0], None),
+    "on-a-page-edge-and-one-past": (
+        [PAGE, PAGE + 1, 2 * PAGE, 2 * PAGE + 1, 1, PAGE - 1], None),
+    "full-width-beside-one-page-rows": ([3, 6 * PAGE, 1, PAGE, 6 * PAGE - 1],
+                                        None),
+    "start-inside-first-live-page": ([20, 30, 48, 9], [3, 17, 1, 0]),
+    "start-past-first-page": ([20, 30, 48, 41], [8, 16, 33, 40]),
+    "start-at-or-past-length": ([20, 30, 0, 12], [20, 35, 0, 11]),
+}
+
+
+class TestLivePageLoop:
+    """The kernel under the interpreter against the reference, in float32
+    (so a page skipped, read twice or taken from a neighbour shows as a
+    gross error)."""
+
+    @staticmethod
+    def _close(args, **kw):
+        args = [jnp.asarray(a) for a in args]
+        kw = {k: (jnp.asarray(v) if k == "starts" else v)
+              for k, v in kw.items()}
+        out = paged_attention_decode_kernel(*args, interpret=True, **kw)
+        ref = paged_attention_decode_reference(*args, **kw)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=2e-5, atol=2e-5)
+        lens = np.asarray(args[3])
+        dead = lens == 0
+        if "starts" in kw:
+            dead |= np.asarray(kw["starts"]) >= lens
+        assert np.all(np.asarray(out)[dead] == 0.0)
+
+    @pytest.mark.parametrize("case", sorted(LOOP_CASES))
+    def test_table(self, case):
+        lens, starts = LOOP_CASES[case]
+        lens = np.asarray(lens, np.int32)
+        q, kp, vp = _pool(len(case), len(lens), 4, 2, 16, PAGE, 40)
+        # entries past a row's pages point out of the pool: the parent
+        # clamped them away, the loop must never reach them
+        tbl = _tables(1, lens, PAGE, 6, 40, junk=10 ** 6)
+        kw = {} if starts is None else dict(
+            starts=np.asarray(starts, np.int32))
+        self._close((q, kp, vp, lens, tbl), **kw)
+
+    @pytest.mark.parametrize("group", [1, 8])
+    def test_gqa_groups(self, group):
+        lens = np.array([0, 11, 48, PAGE, 25], np.int32)
+        q, kp, vp = _pool(group, len(lens), 2 * group, 2, 16, PAGE, 40)
+        self._close((q, kp, vp, lens, _tables(2, lens, PAGE, 6, 40, 0)))
+
+    @pytest.mark.parametrize("ring,lens", [
+        (2, [0, 1, 5, PAGE - 1, PAGE]),                 # under one page
+        (2, [PAGE + 1, 2 * PAGE - 1, 2 * PAGE, 12, 0]),  # into the second
+        (2, [2 * PAGE + 1, 5 * PAGE + 3, 9 * PAGE, 7 * PAGE - 1, 4]),
+        (3, [0, 3 * PAGE + 1, 10 * PAGE + 5, 2 * PAGE, 7]),   # laps of 3
+    ])
+    def test_ring(self, ring, lens):
+        lens = np.asarray(lens, np.int32)
+        window = (ring - 1) * PAGE
+        q, kp, vp = _pool(ring, len(lens), 8, 2, 16, PAGE, len(lens) * ring)
+        rings = np.random.default_rng(3).permutation(
+            len(lens) * ring).reshape(len(lens), ring).astype(np.int32)
+        self._close((q, kp, vp, lens, rings),
+                    starts=np.maximum(lens - window, 0), ring=True)
+
+    def test_ring_with_a_bound_older_than_the_ring(self):
+        """A lower bound that reaches behind what the ring still holds:
+        only the newest lap's pages are read, as the reference has it."""
+        lens = np.array([5 * PAGE + 2, 3 * PAGE, 2], np.int32)
+        q, kp, vp = _pool(9, 3, 4, 2, 16, PAGE, 6)
+        rings = np.arange(6, dtype=np.int32).reshape(3, 2)
+        self._close((q, kp, vp, lens, rings),
+                    starts=np.zeros(3, np.int32), ring=True)
+
+    @pytest.mark.parametrize("start", [0, 5, PAGE, 2 * PAGE + 3])
+    def test_suffix_prefill_shape(self, start):
+        """Many rows over one shared table, row ``i`` at length
+        ``start + i + 1``, the rows past the prompt empty."""
+        rows, n = 24, 19
+        lens = np.where(np.arange(rows) < n,
+                        start + np.arange(rows) + 1, 0).astype(np.int32)
+        q, kp, vp = _pool(start, rows, 4, 2, 16, PAGE, 12)
+        row = np.random.default_rng(4).permutation(12)[:6].astype(np.int32)
+        self._close((q, kp, vp, lens, np.broadcast_to(row, (rows, 6))))
+
+    def test_bf16_pool(self):
+        """The serving dtype: K and V at bf16, the softmax in float32."""
+        lens = np.array([0, 19, 48, 8, 33], np.int32)
+        q, kp, vp = (jnp.asarray(a, jnp.bfloat16)
+                     for a in _pool(5, 5, 8, 2, 16, PAGE, 40))
+        tbl = jnp.asarray(_tables(6, lens, PAGE, 6, 40, 0))
+        out = paged_attention_decode_kernel(q, kp, vp, jnp.asarray(lens),
+                                            tbl, interpret=True)
+        ref = paged_attention_decode_reference(q, kp, vp, jnp.asarray(lens),
+                                               tbl)
+        assert out.dtype == jnp.bfloat16
+        np.testing.assert_allclose(np.asarray(out, np.float32),
+                                   np.asarray(ref, np.float32), atol=2e-2)

@@ -242,6 +242,32 @@ class TestContinuousBatching:
         assert eng.pool.used_blocks == 0
 
 
+class TestPagedPagesCounters:
+    """``serve.paged_pages_live / serve.paged_pages_table``: the share of
+    the block table that the streams hold, which is the share of a walk
+    of the whole table that ``paged_decode`` now makes."""
+
+    def test_share_is_the_requests_lengths_over_the_table(self):
+        bs, slots, max_len = 4, 3, 40
+        eng = ServeEngine(_model(), max_slots=slots, block_size=bs,
+                          num_blocks=40, max_seq_len=max_len, name="pages")
+        rng = np.random.RandomState(3)
+        plans = [(7, 6), (3, 9), (12, 5), (5, 8), (9, 4)]
+        for n, k in plans:       # 5 streams over 3 slots, no preemption
+            eng.submit(rng.randint(1, 97, n), max_new_tokens=k)
+        eng.run(max_steps=200)
+        # a prompt of n tokens makes its first token in its prefill, then
+        # decodes k - 1 times at lengths n + 1 .. n + k - 1
+        live = sum(-(-(n + j) // bs)
+                   for n, k in plans for j in range(1, k))
+        value = lambda m: obs.registry.get(m).value(engine="pages")
+        steps = value("serve.decode_steps")
+        assert value("serve.paged_pages_live") == live
+        assert value("serve.paged_pages_table") == \
+            steps * slots * (max_len // bs)
+        assert 0.1 < live / (steps * slots * (max_len // bs)) < 0.5
+
+
 class TestPreemptionAndQueueing:
     def test_pool_pressure_preempts_youngest_and_still_matches_solo(self):
         model = _model()

@@ -130,6 +130,13 @@ class TestGroupShardedParallel:
 
 
 class TestFleetShardingIntegration:
+    @pytest.fixture(autouse=True)
+    def _reset_fleet(self):
+        # `fleet.init` leaves its topology behind as process state: a MoE
+        # layer built later in the same worker would take its "mp" axis
+        yield
+        dist.fleet.set_hybrid_communicate_group(None)
+
     def test_hybrid_topology_sharding_axis(self):
         import paddle_tpu.distributed.fleet as fleet
 

@@ -131,18 +131,27 @@ def test_rms_norm_fwd_bwd_compiles(topo, shape):
     assert text.count("tpu_custom_call") == 2
 
 
-@pytest.mark.parametrize("kv_heads", [16, 4])
-def test_paged_decode_compiles(topo, kv_heads):
-    # the serving shape: 8 slots, 96 pages of 128, 8 pages per sequence
+@pytest.mark.parametrize("slots,heads,kv_heads,blocks,width", [
+    (8, 16, 16, 96, 8),          # a small engine, and with GQA 16/4
+    (8, 16, 4, 96, 8),
+    (128, 12, 12, 736, 16),      # the Cerebras-GPT cells' decode step
+    (128, 64, 8, 4096, 64),      # K-EXAONE's full layer, max_seq_len 8,192
+])
+def test_paged_decode_compiles(topo, slots, heads, kv_heads, blocks, width):
+    """The decode kernel over pages of 128, up to the benchmark cells'
+    pools and tables: the pools stay in HBM (``pl.ANY``), so the compiled
+    call holds no copy of one, and the kernel keeps the name its
+    roofline's readers look for."""
     from paddle_tpu.ops.pallas.paged_attention import (
         paged_attention_decode_kernel)
 
-    pages = _on(topo, (kv_heads, 96, 128, 128), BF16)
-    text, _ = _compile(
-        paged_attention_decode_kernel, _on(topo, (8, 16, 128), BF16),
-        pages, pages, _on(topo, (8,), jnp.int32),
-        _on(topo, (8, 8), jnp.int32))
-    assert "tpu_custom_call" in text
+    pool = _on(topo, (kv_heads, blocks, 128, 128), BF16)
+    text, compiled = _compile(
+        paged_attention_decode_kernel, _on(topo, (slots, heads, 128), BF16),
+        pool, pool, _on(topo, (slots,), jnp.int32),
+        _on(topo, (slots, width), jnp.int32))
+    assert "tpu_custom_call" in text and "paged_decode" in text
+    assert not _pool_sized_copies(compiled.as_text(), pool.shape)
 
 
 def _pool_sized_copies(hlo, pool_shape):

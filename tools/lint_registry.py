@@ -214,6 +214,8 @@ SERVE_REQUIRED_LABELS = {
     "serve.moe_assignments_held": ("engine",),
     "serve.moe_expert_tokens_max": ("engine", "layer"),
     "serve.moe_expert_tokens_sum": ("engine", "layer"),
+    "serve.paged_pages_live": ("engine",),
+    "serve.paged_pages_table": ("engine",),
 }
 
 #: request-tracing / SLO label discipline (observability/tracing.py +
