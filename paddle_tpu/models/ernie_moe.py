@@ -116,6 +116,34 @@ class ErnieMoeModel(nn.Layer):
         return self.norm(hidden_states)
 
 
+def capacity_moe_ffn(h, lp, statics, dtype):
+    """Routed expert FFN of the decode view (``decoder_stack``'s
+    ``"capacity_moe"`` kind): EVAL GShard/naive routing (top-k softmax
+    gate, deterministic) through the same index-dispatch program the
+    model's own forward uses (moe_layer._moe_idx_ffn_fwd), so decode and
+    full-prefix forward route identically."""
+    import jax
+    import jax.numpy as jnp
+
+    from ..incubate.distributed.models.moe.gate import _capacity
+    from ..incubate.distributed.models.moe.moe_layer import _moe_idx_ffn_fwd
+
+    topk, factor, activation, normalize = statics
+    m = lp["moe"]
+    shape = h.shape
+    x = h.reshape(-1, shape[-1])
+    n, e = x.shape[0], m["gw"].shape[1]
+    probs = jax.nn.softmax(
+        (x @ m["gw"] + m["gb"]).astype(jnp.float32), axis=-1)
+    # the SHARED capacity rule (gate._capacity) over THIS call's tokens
+    cap = _capacity(n, e, topk, factor)
+    out = _moe_idx_ffn_fwd(
+        probs, x, m["w0"], m["b0"], m["w1"], m["b1"],
+        jax.random.PRNGKey(0), k=topk, capacity=cap,
+        activation=activation, normalize=normalize, random2=False)
+    return out.astype(dtype).reshape(shape)
+
+
 class ErnieMoeForCausalLM(nn.Layer):
     def __init__(self, config: ErnieMoeConfig, moe_group=None):
         super().__init__()
@@ -153,6 +181,67 @@ class ErnieMoeForCausalLM(nn.Layer):
 
     def num_parameters(self) -> int:
         return sum(p.size for p in self.parameters())
+
+    def decode_view(self):
+        """The parameter view ``models/decoder_stack.py`` runs over a KV
+        cache: Llama-style attention/norms, per-layer FFN either the
+        dense SwiGLU or a routed expert bank (``"capacity_moe"``, its
+        routing statics under ``moe_statics``). Generation runs the
+        gate's current-mode routing (eval: deterministic top-k, eval
+        capacity factor). Expert CAPACITY is computed over the tokens of
+        each decode call (prefill: B*prompt_len; steps: B) with the same
+        shared formula as the training forward — so decode matches the
+        model's full-prefix forward whenever no expert saturates (the
+        oracle-pinned regime); when capacity binds, drop behavior is
+        per-call, mirroring the reference's step-wise serving ops
+        (masked/block MHA process only the step's tokens too)."""
+        from .decoder_stack import LayerSpec
+
+        cfg = self.config
+        layers, specs, moe_statics = [], [], []
+        for layer in self.model.layers:
+            a = layer.self_attn
+            entry = dict(
+                ln1=layer.input_layernorm.weight._value,
+                wq=a.q_proj.weight._value, wk=a.k_proj.weight._value,
+                wv=a.v_proj.weight._value, wo=a.o_proj.weight._value,
+                ln2=layer.post_attention_layernorm.weight._value,
+            )
+            if layer.is_moe:
+                gate, ex = layer.mlp.gate, layer.mlp.experts
+                entry["moe"] = dict(
+                    gw=gate.weight._value, gb=gate.bias._value,
+                    w0=ex.w0._value, b0=ex.b0._value,
+                    w1=ex.w1._value, b1=ex.b1._value,
+                )
+                # routing statics live OUTSIDE the layer dict: the layers
+                # list rides as a jit ARGUMENT, and a string inside it
+                # would break tracing. _train_factor() already respects
+                # gate.training (GShard: capacity[0] train / [1] eval;
+                # Naive: flat factor).
+                moe_statics.append((int(gate.topk),
+                                    float(gate._train_factor()),
+                                    ex.activation, bool(gate._normalize)))
+            else:
+                m = layer.mlp
+                entry.update(wg=m.gate_proj.weight._value,
+                             wu=m.up_proj.weight._value,
+                             wd=m.down_proj.weight._value)
+                moe_statics.append(None)
+            specs.append(LayerSpec(
+                ffn="capacity_moe" if layer.is_moe else "swiglu"))
+            layers.append(entry)
+        return dict(
+            embed=self.model.embed_tokens.weight._value,
+            norm=self.model.norm.weight._value,
+            head=self.lm_head.weight._value,
+            layers=layers,
+            moe_statics=tuple(moe_statics),   # hashable -> static_cfg
+            nh=cfg.num_attention_heads, nkv=cfg.num_key_value_heads,
+            dh=cfg.hidden_size // cfg.num_attention_heads,
+            eps=cfg.rms_norm_eps, theta=cfg.rope_theta,
+            specs=tuple(specs),
+        )
 
     def generate(self, input_ids, max_new_tokens: int = 32,
                  do_sample: bool = False, temperature: float = 1.0,

@@ -31,9 +31,10 @@ sum goes on. Nothing here stands in for the other chips.
 
 The expert products run through ``ops/pallas/moe_experts`` (grouped by
 expert under fixed shapes, the group sizes being data). Serving goes
-through ``serve.ServeEngine`` (``generation._decode_family`` gives the
-parameter view and the per-layer description); the ``forward`` here is
-the plain whole-sequence pass the tests hold against the reference.
+through ``serve.ServeEngine``, which runs ``models/decoder_stack.py``
+over ``ExaoneMoeForCausalLM.decode_view()`` (the parameter view and one
+``LayerSpec`` a layer); the ``forward`` here is the plain whole-sequence
+pass the tests hold against the reference.
 Training of this family is not claimed: ``forward`` records no graph.
 """
 from __future__ import annotations
@@ -45,7 +46,7 @@ import numpy as np
 
 from .. import nn
 from ..core.tensor import Tensor
-from .generation import LayerSpec
+from .decoder_stack import LayerSpec
 
 __all__ = ["ExaoneMoeConfig", "ExaoneMoeForCausalLM", "ExaoneMoeModel",
            "ExaoneMoeExperts", "ExaoneMoeSparseBlock", "LayerSpec"]
@@ -162,7 +163,7 @@ def moe_ffn(h, lp, st, dtype, *, valid=None, backend="auto", scope=None):
     import jax.numpy as jnp
 
     from ..ops.pallas.moe_experts import experts_ffn, scoped
-    from .generation import _llama_ffn
+    from .decoder_stack import swiglu_ffn
 
     t = h.shape[0]
     if t > MOE_CHUNK and t % MOE_CHUNK == 0:
@@ -184,7 +185,7 @@ def moe_ffn(h, lp, st, dtype, *, valid=None, backend="auto", scope=None):
                                 first=st["first"], backend=backend,
                                 scope=scope)
     with named("shared"):
-        shared = _llama_ffn(h, lp, dtype)
+        shared = swiglu_ffn(h, lp, dtype)
     return (routed + shared.astype(jnp.float32)).astype(dtype), sizes
 
 
@@ -208,7 +209,7 @@ def forward_logits(p, ids):
 
     from ..incubate.nn.functional import _rope_tables
     from ..incubate.nn.functional._rope_common import rotate_half
-    from .generation import _head_logits, _llama_ffn, _rms
+    from .decoder_stack import head_logits, rms, swiglu_ffn
 
     dtype = p["embed"].dtype
     t = ids.shape[0]
@@ -217,8 +218,8 @@ def forward_logits(p, ids):
     cos, sin = cos[:, None, :], sin[:, None, :]
     x = jnp.take(p["embed"], ids, axis=0)
     for lp, spec in zip(p["layers"], p["specs"]):
-        q = _rms((x @ lp["wq"]).reshape(t, nh, dh), lp["qn"], eps, dtype)
-        k = _rms((x @ lp["wk"]).reshape(t, kvh, dh), lp["kn"], eps, dtype)
+        q = rms((x @ lp["wq"]).reshape(t, nh, dh), lp["qn"], eps, dtype)
+        k = rms((x @ lp["wk"]).reshape(t, kvh, dh), lp["kn"], eps, dtype)
         v = (x @ lp["wv"]).reshape(t, kvh, dh)
         if spec.rope:
             q, k = ((a.astype(jnp.float32) * cos
@@ -231,13 +232,13 @@ def forward_logits(p, ids):
         s = jnp.where(attention_mask(t, spec.window)[None], s, -jnp.inf)
         ctx = jnp.einsum("hqk,khd->qhd", jax.nn.softmax(s, -1),
                          v.astype(jnp.float32)).reshape(t, nh * dh)
-        x = x + _rms(ctx.astype(dtype) @ lp["wo"], lp["ln1"], eps, dtype)
+        x = x + rms(ctx.astype(dtype) @ lp["wo"], lp["ln1"], eps, dtype)
         if spec.ffn == "moe":
             f, _ = moe_ffn(x, lp, p["moe"], dtype)
         else:
-            f = _llama_ffn(x, lp, dtype)
-        x = x + _rms(f, lp["ln2"], eps, dtype)
-    return _head_logits(p, _rms(x, p["norm"], eps, dtype)).astype(
+            f = swiglu_ffn(x, lp, dtype)
+        x = x + rms(f, lp["ln2"], eps, dtype)
+    return head_logits(p, rms(x, p["norm"], eps, dtype)).astype(
         jnp.float32)
 
 
@@ -367,15 +368,56 @@ class ExaoneMoeForCausalLM(nn.Layer):
         self.exaone = ExaoneMoeModel(config)
         self.lm_head = _Linear(config, config.hidden_size, config.vocab_size)
 
+    def decode_view(self):
+        """The parameter view ``models/decoder_stack.py`` runs over a KV
+        cache: the Llama view's names where the leaves mean the same,
+        ``specs`` (one ``LayerSpec`` a layer: attention kind and window,
+        RoPE or none, q/k norm, norm placement, dense or expert FFN),
+        ``moe`` (the router's statics and the held experts) and
+        ``prefill="flash"`` for ``ServeEngine``."""
+        cfg = self.config
+        layers = []
+        for l, layer in enumerate(self.exaone.layers):
+            a, m = layer.self_attn, layer.mlp
+            lp = dict(
+                wq=a.q_proj.weight._value, wk=a.k_proj.weight._value,
+                wv=a.v_proj.weight._value, wo=a.o_proj.weight._value,
+                qn=a.q_norm.weight._value, kn=a.k_norm.weight._value,
+                ln1=layer.post_attention_layernorm.weight._value,
+                ln2=layer.post_feedforward_layernorm.weight._value)
+            if cfg.is_sparse(l):
+                lp.update(router=m.gate.weight._value,
+                          router_bias=m.gate.e_score_correction_bias._value,
+                          gate_up=m.experts.gate_up_proj._value,
+                          down=m.experts.down_proj._value)
+                m = m.shared_experts
+            lp.update(wg=m.gate_proj.weight._value,
+                      wu=m.up_proj.weight._value,
+                      wd=m.down_proj.weight._value)
+            layers.append(lp)
+        return dict(
+            embed=self.exaone.embed_tokens.weight._value,
+            norm=self.exaone.norm.weight._value,
+            head=self.lm_head.weight._value,
+            layers=layers,
+            nh=cfg.num_attention_heads, nkv=cfg.num_key_value_heads,
+            dh=cfg.head_dim, eps=cfg.rms_norm_eps, theta=cfg.rope_theta,
+            specs=tuple(cfg.layer_spec(l)
+                        for l in range(cfg.num_hidden_layers)),
+            prefill="flash",
+            moe=dict(top_k=cfg.num_experts_per_tok,
+                     scale=cfg.routed_scaling_factor,
+                     norm_topk=cfg.norm_topk_prob, first=cfg.experts_held[0],
+                     count=cfg.experts_held[1], num_experts=cfg.num_experts),
+        )
+
     def forward(self, input_ids):
         """[B, T, vocab] float32 logits (inference only: no graph)."""
         import jax.numpy as jnp
 
-        from .generation import _exaone_decode_params
-
         ids = input_ids._value if isinstance(input_ids, Tensor) \
             else jnp.asarray(np.asarray(input_ids))
-        p = _exaone_decode_params(self)
+        p = self.decode_view()
         return Tensor(jnp.stack([forward_logits(p, row) for row in ids]))
 
     def num_parameters(self) -> int:

@@ -135,26 +135,61 @@ class GPTForCausalLM(nn.Layer):
             self.lm_head = nn.Linear(config.hidden_size, config.vocab_size,
                                      bias_attr=False)
 
+    def decode_view(self):
+        """The parameter view ``models/decoder_stack.py`` runs over a KV
+        cache: learned positions, pre-LN, fused qkv, GELU
+        (``GPT_LAYER``)."""
+        from .decoder_stack import GPT_LAYER
+
+        cfg = self.config
+        layers = []
+        for layer in self.gpt.layers:
+            a = layer.attn
+            layers.append(dict(
+                ln1_w=layer.norm1.weight._value,
+                ln1_b=layer.norm1.bias._value,
+                wqkv=a.qkv_proj.weight._value, bqkv=a.qkv_proj.bias._value,
+                wo=a.out_proj.weight._value, bo=a.out_proj.bias._value,
+                ln2_w=layer.norm2.weight._value,
+                ln2_b=layer.norm2.bias._value,
+                w1=layer.linear1.weight._value, b1=layer.linear1.bias._value,
+                w2=layer.linear2.weight._value, b2=layer.linear2.bias._value,
+            ))
+        out = dict(
+            embed=self.gpt.wte.weight._value,
+            wpe=self.gpt.wpe.weight._value,
+            normf_w=self.gpt.norm_f.weight._value,
+            normf_b=self.gpt.norm_f.bias._value,
+            layers=layers,
+            nh=cfg.num_attention_heads, nkv=cfg.num_attention_heads,
+            dh=cfg.hidden_size // cfg.num_attention_heads,
+            eps=cfg.layer_norm_eps,
+            # tied head: logits = hidden @ embed.T computed in-graph (a
+            # materialized transpose would duplicate [V, H] on device)
+            tied_head=bool(cfg.tie_word_embeddings),
+            max_positions=int(cfg.max_position_embeddings),
+            specs=(GPT_LAYER,) * len(layers),
+        )
+        if not cfg.tie_word_embeddings:
+            out["head"] = self.lm_head.weight._value
+        return out
+
     def generate(self, input_ids, max_new_tokens: int = 32,
                  do_sample: bool = False, temperature: float = 1.0,
                  top_k: int = 0, top_p: float = 1.0,
                  eos_token_id=None, seed: int = 0, pad_token_id=None,
-                 paged: bool = False, block_size: int = 64,
-                 num_blocks=None,
                  num_beams: int = 1, length_penalty: float = 0.0,
                  repetition_penalty: float = 1.0, min_length: int = 0):
         """KV-cache incremental decoding — one jitted lax.scan over a
         dense cache (models/generation.py, same driver as Llama);
-        ``pad_token_id`` enables left-padded ragged prompts;
-        ``paged=True``/``num_blocks`` as in the Llama family."""
+        ``pad_token_id`` enables left-padded ragged prompts."""
         from .generation import generate as _generate
 
         return _generate(self, input_ids, max_new_tokens=max_new_tokens,
                          do_sample=do_sample, temperature=temperature,
                          top_k=top_k, top_p=top_p,
                          eos_token_id=eos_token_id, seed=seed,
-                         pad_token_id=pad_token_id, paged=paged,
-                         block_size=block_size, num_blocks=num_blocks,
+                         pad_token_id=pad_token_id,
                          num_beams=num_beams,
                          length_penalty=length_penalty,
                          repetition_penalty=repetition_penalty,

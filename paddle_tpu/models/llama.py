@@ -298,29 +298,54 @@ class LlamaForCausalLM(nn.Layer):
     def num_parameters(self) -> int:
         return sum(p.size for p in self.parameters())
 
+    def decode_view(self):
+        """The parameter view ``models/decoder_stack.py`` runs over a KV
+        cache: the arrays by the stack's names, the statics, and one
+        ``LayerSpec`` a layer (the defaults are this family's)."""
+        from .decoder_stack import LayerSpec
+
+        cfg = self.config
+        layers = []
+        for layer in self.llama.layers:
+            a, m = layer.self_attn, layer.mlp
+            layers.append(dict(
+                ln1=layer.input_layernorm.weight._value,
+                wq=a.q_proj.weight._value, wk=a.k_proj.weight._value,
+                wv=a.v_proj.weight._value, wo=a.o_proj.weight._value,
+                ln2=layer.post_attention_layernorm.weight._value,
+                wg=m.gate_proj.weight._value, wu=m.up_proj.weight._value,
+                wd=m.down_proj.weight._value,
+            ))
+        return dict(
+            embed=self.llama.embed_tokens.weight._value,
+            norm=self.llama.norm.weight._value,
+            head=self.lm_head.weight._value,
+            layers=layers,
+            nh=cfg.num_attention_heads, nkv=cfg.num_key_value_heads,
+            dh=cfg.hidden_size // cfg.num_attention_heads,
+            eps=cfg.rms_norm_eps, theta=cfg.rope_theta,
+            specs=(LayerSpec(),) * len(layers),
+        )
+
     def generate(self, input_ids, max_new_tokens: int = 32,
                  do_sample: bool = False, temperature: float = 1.0,
                  top_k: int = 0, top_p: float = 1.0,
                  eos_token_id=None, seed: int = 0, pad_token_id=None,
-                 paged: bool = False, block_size: int = 64,
-                 num_blocks=None,
                  num_beams: int = 1, length_penalty: float = 0.0,
                  repetition_penalty: float = 1.0, min_length: int = 0):
         """KV-cache incremental decoding: the whole loop is one jitted
         lax.scan (models/generation.py). Greedy by default; sampling
         via do_sample + temperature/top_k/top_p; ``pad_token_id``
-        enables left-padded ragged prompts; ``paged=True`` decodes over
-        the serving block/paged KV cache (``num_blocks`` caps the pool
-        and fails loudly on exhaustion). Returns
-        [B, prompt + max_new_tokens] including the prompt."""
+        enables left-padded ragged prompts. Returns
+        [B, prompt + max_new_tokens] including the prompt. (Paged
+        decoding is ``serve.ServeEngine``'s.)"""
         from .generation import generate as _generate
 
         return _generate(self, input_ids, max_new_tokens=max_new_tokens,
                          do_sample=do_sample, temperature=temperature,
                          top_k=top_k, top_p=top_p,
                          eos_token_id=eos_token_id, seed=seed,
-                         pad_token_id=pad_token_id, paged=paged,
-                         block_size=block_size, num_blocks=num_blocks,
+                         pad_token_id=pad_token_id,
                          num_beams=num_beams,
                          length_penalty=length_penalty,
                          repetition_penalty=repetition_penalty,

@@ -35,11 +35,16 @@ Scheduling policy (the contract the tests pin):
   the engine (the e2e test asserts exactly that). Prefill compiles once
   per power-of-two length bucket.
 
-Attention reads the pool through
+What a decoder layer IS belongs to the model families: every compiled
+step here runs ``models/decoder_stack.stack_layers`` over the view the
+model hands over (``decode_view()``: arrays and one ``LayerSpec`` a
+layer), the function ``generate()``'s dense-cache forward runs too, so
+engine streams and ``generate()`` cannot drift. This module keeps the
+CACHE and the SCHEDULE: it hands the stack ``write_kv`` (new K/V rows
+into the paged pool, ``ops/pallas/kv_write``) and ``attn`` (through
 ``ops/pallas/paged_attention.paged_attention_decode`` — the decode-
-specialized Pallas kernel on TPU, its jnp gather reference on CPU — and
-the per-layer norm/FFN math is imported from ``models/generation.py``'s
-shared helpers, so engine streams and ``generate()`` cannot drift.
+specialized Pallas kernel on TPU, its jnp gather reference on CPU — or,
+in a cold prefill, causal attention within the prompt).
 
 Telemetry: the ``serve.`` metric subsystem (claimed in
 ``observability.metrics.CLAIMED_SUBSYSTEMS``, label discipline audited
@@ -76,6 +81,7 @@ from __future__ import annotations
 
 import collections
 import os
+from functools import partial
 import time
 from dataclasses import dataclass, field
 from typing import Deque, List, Optional
@@ -84,6 +90,7 @@ import numpy as np
 
 from .. import observability as obs
 from ..core.tensor import Tensor
+from ..models import decoder_stack as _stack
 from ..models import generation as _gen
 from .pool import BlockPool, PoolExhaustedError
 from .prefix import PrefixCache
@@ -243,9 +250,10 @@ class Request:
 
 class ServeEngine:
     """Continuous-batching server over a paged KV pool (module docstring
-    has the admission/eviction contract). Llama, GPT and EXAONE-MoE
-    families: ``_stack_layers`` reads one ``LayerSpec`` a layer (norm and
-    its placement, projections, RoPE, q/k norm, window, FFN kind).
+    has the admission/eviction contract). Any family whose
+    ``decode_view()`` gives one ``LayerSpec`` a layer with FFN kinds that
+    work row by row (Llama, GPT, EXAONE-MoE): the layers themselves are
+    ``models/decoder_stack.py``'s.
 
     Two kinds of cache: a full-attention layer's pool is the block table
     the docstring describes (``num_blocks`` counts its blocks); a
@@ -284,16 +292,16 @@ class ServeEngine:
         the PR-14 one-roundtrip-per-token loop)."""
         import jax
 
-        if not any(hasattr(model, f) for f in ("llama", "gpt", "exaone")):
+        p = _gen._decode_family(model)
+        self._specs = p["specs"]
+        if not all(_stack.FFN_KINDS[s.ffn].per_row for s in self._specs):
+            # (a capacity computed over the call's tokens makes a stream
+            # depend on what it is batched with)
             raise NotImplementedError(
                 "ServeEngine supports the Llama and GPT families and "
                 "EXAONE-MoE (the paged-decode surface); capacity-padded "
                 "MoE models decode on the dense path — got "
                 f"{type(model).__name__}")
-        p, _fwd = _gen._decode_family(model)
-        self._specs = p.get("specs") or (
-            (_gen.LayerSpec() if hasattr(model, "llama")
-             else _gen.GPT_LAYER,) * len(p["layers"]))
         #: the layers whose FFN is sparse (their group sizes come back
         #: from a decode step in this order)
         self._sparse = [i for i, s in enumerate(self._specs)
@@ -337,8 +345,7 @@ class ServeEngine:
                         if not hasattr(v, "dtype")
                         and not isinstance(v, list)}
         self._arrays = {k: v for k, v in p.items() if k not in self._static}
-        self._nh, self._nkv = p["nh"], p["nkv"]
-        self._dh, self._L = p["dh"], len(p["layers"])
+        self._nh, self._nkv, self._dh = p["nh"], p["nkv"], p["dh"]
         self._dtype = p["embed"].dtype
         import jax.numpy as jnp
 
@@ -1151,15 +1158,17 @@ class ServeEngine:
                 self._caches, jnp.int32(0), jnp.int32(0)))
         return out
 
-    def _scatter_kv(self, spec, kc, vc, k_new, v_new, slots, fresh):
+    def _scatter_kv(self, slots, fresh, _i, spec, kc, vc, k_new, v_new):
         """Write per-row K/V ([rows, kvh, dh]) into the pool at flat
         slot ids, in place and in the pool's own layout (out-of-range
         ids drop — that is how inactive slots and pad rows are fenced
-        off the pool). ``slots`` gives the ids by the layer's kind of
-        cache (``_full_slots`` / ``_ring_slots``). ``fresh``: the rows
-        start their stream (a cold prefill), so they go in whole blocks;
-        of a window layer's only the ring's last blocks are written,
-        ``slots["window"]`` then being (first row, rows, their ids)."""
+        off the pool). With ``slots`` and ``fresh`` bound it is the
+        ``write_kv`` the decoder stack is handed. ``slots`` gives the
+        ids by the layer's kind of cache (``_full_slots`` /
+        ``_ring_slots``). ``fresh``: the rows start their stream (a cold
+        prefill), so they go in whole blocks; of a window layer's only
+        the ring's last blocks are written, ``slots["window"]`` then
+        being (first row, rows, their ids)."""
         from jax import lax
 
         from ..ops.pallas.kv_write import kv_write
@@ -1201,140 +1210,14 @@ class ServeEngine:
         return jnp.where(written, phys * bs + positions % bs,
                          self.window_pool.num_blocks * bs)
 
-    def _rope_rows(self, pos):
-        """cos/sin rows at per-row positions ``pos`` — computed ONCE
-        per compiled call and reused by every layer (the tables are
-        position-only; rebuilding them per layer would stage L
-        identical table subgraphs per trace)."""
-        import jax.numpy as jnp
-
-        from ..incubate.nn.functional import _rope_tables
-
-        cos_full, sin_full = _rope_tables(
-            self.max_seq_len, self._dh, self._static["theta"], True,
-            jnp.float32)
-        return (jnp.take(cos_full, pos, axis=0)[:, None, :],
-                jnp.take(sin_full, pos, axis=0)[:, None, :])
-
-    def _rope(self, q, k, cos, sin):
-        """Rotate q/k ([rows, heads, dh]) by precomputed cos/sin rows
-        (the layers whose spec says ``rope``)."""
-        import jax.numpy as jnp
-
-        from ..incubate.nn.functional._rope_common import rotate_half
-
-        q = (q.astype(jnp.float32) * cos
-             + rotate_half(q.astype(jnp.float32), True) * sin)
-        k = (k.astype(jnp.float32) * cos
-             + rotate_half(k.astype(jnp.float32), True) * sin)
-        return q.astype(self._dtype), k.astype(self._dtype)
-
-    def _embed(self, p, tokens, positions):
-        """Token rows and what the family adds to them of position:
-        (x, the rope rows or None)."""
-        import jax.numpy as jnp
-
-        x = jnp.take(p["embed"], tokens, axis=0)
-        if tokens.ndim == 2:            # a prefill's [1, bucket] ids
-            x = x[0]
-        rope = None
-        if any(s.rope for s in self._specs):
-            rope = self._rope_rows(positions)
-        if "wpe" in p:
-            x = x + jnp.take(p["wpe"], positions, axis=0)
-        return x, rope
-
-    def _stack_layers(self, p, x, rope, caches, slots, attn,
-                      fresh=False, valid=None):
-        """ONE transformer stack for BOTH compiled steps, read off each
-        layer's ``LayerSpec``: norm and projection, q/k norm, rope, K/V
-        scatter into the layer's kind of pool, attention via the
-        provided closure, residual + FFN (dense or expert), final norm.
-        ``x`` is [rows, H]; ``attn(spec, q, k, v, kc, vc) -> [rows,
-        nh*dh]`` is the only thing decode and prefill legitimately
-        differ in (paged pool attention vs in-prompt causal attention),
-        so it is the only thing they provide, but for ``fresh``: whether
-        row ``i`` is position ``i`` of its stream (see ``_scatter_kv``).
-        ``valid`` marks the rows that are tokens (a sparse layer routes
-        the others nowhere). Returns (normed hidden [rows, H], new
-        caches, the held experts' group sizes of each sparse layer)."""
-        import jax
-
-        from ..models.exaone_moe import moe_ffn
+    def _count_kv_write(self, rows: int, fresh: bool = False):
+        """One count a traced program, by the path its K/V rows take
+        (executes at TRACE time only)."""
         from ..ops.pallas.kv_write import kv_write_path
 
-        rows = x.shape[0]
-        # executes at TRACE time only, once a compiled program
         _M_KV_WRITE_TRACES.inc(engine=self.name, path=kv_write_path(
             rows, self.block_size, rows_start_blocks=fresh,
             backend=self.attention_backend))
-        nh, kvh, dh = self._nh, self._nkv, self._dh
-        dtype = self._dtype
-        eps = p["eps"]
-
-        def norm(spec, x, lp, which):
-            if spec.norm == "rms":
-                return _gen._rms(x, lp[which], eps, dtype)
-            return _gen._ln(x, lp[which + "_w"], lp[which + "_b"], eps,
-                            dtype)
-
-        # scopes by hand, as nn.Layer.__call__ gives them to the eager
-        # stack: they are what the op metadata of the compiled steps, and
-        # with it XProf and profiler.scope_seconds, name device time by
-        scope = jax.named_scope
-        new_caches, moe_sizes = [], []
-        for i, (lp, spec, (kc, vc)) in enumerate(
-                zip(p["layers"], self._specs, caches)):
-            pre = spec.placement == "pre"
-            with scope(f"layer{i}/qkv"):
-                h = norm(spec, x, lp, "ln1") if pre else x
-                if spec.proj == "split":
-                    q = (h @ lp["wq"]).reshape(rows, nh, dh)
-                    k = (h @ lp["wk"]).reshape(rows, kvh, dh)
-                    v = (h @ lp["wv"]).reshape(rows, kvh, dh)
-                else:
-                    qkv = (h @ lp["wqkv"] + lp["bqkv"]).reshape(
-                        rows, 3, nh, dh)
-                    q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]
-                if spec.qk_norm:
-                    q = _gen._rms(q, lp["qn"], eps, dtype)
-                    k = _gen._rms(k, lp["kn"], eps, dtype)
-                if spec.rope:
-                    q, k = self._rope(q, k, *rope)
-            with scope(f"layer{i}/scatter_kv"):
-                kc, vc = self._scatter_kv(spec, kc, vc, k, v, slots, fresh)
-            new_caches.append((kc, vc))
-            with scope(f"layer{i}/attn"):
-                ctx = attn(spec, q, k, v, kc, vc)
-            with scope(f"layer{i}/out"):
-                if spec.proj != "split":
-                    x = x + ctx.astype(dtype) @ lp["wo"] + lp["bo"]
-                elif pre:
-                    x = x + ctx.astype(dtype) @ lp["wo"]
-                else:
-                    x = x + norm(spec, ctx.astype(dtype) @ lp["wo"], lp,
-                                 "ln1")
-            if spec.ffn == "moe":
-                f, sizes = moe_ffn(
-                    x, lp, p["moe"], dtype, valid=valid,
-                    backend=self.attention_backend, scope=f"layer{i}/moe")
-                moe_sizes.append(sizes)
-                with scope(f"layer{i}/moe/combine"):
-                    x = x + norm(spec, f, lp, "ln2")
-                continue
-            with scope(f"layer{i}/ffn"):
-                ffn = _gen._llama_ffn if spec.ffn == "swiglu" \
-                    else _gen._gpt_ffn
-                if pre:
-                    x = x + ffn(norm(spec, x, lp, "ln2"), lp, dtype)
-                else:
-                    x = x + norm(spec, ffn(x, lp, dtype), lp, "ln2")
-        with scope("final_norm"):
-            if self._specs[-1].norm == "rms":
-                out = _gen._rms(x, p["norm"], eps, dtype)
-            else:
-                out = _gen._ln(x, p["normf_w"], p["normf_b"], eps, dtype)
-        return out, new_caches, moe_sizes
 
     def _decode_impl(self, arrays, caches, tokens, lens, active, tables,
                      temps, key):
@@ -1378,14 +1261,14 @@ class ServeEngine:
 
         with jax.named_scope("embed"):
             pos = lens.astype(jnp.int32)
-            x, rope = self._embed(p, tokens, pos)         # [B, H]
+            x, rope = _stack.embed(p, tokens, pos, self.max_seq_len)
         lengths = jnp.where(active, pos + 1, 0)
         slots = {"full": self._full_slots(table, pos, active)}  # OOB drops
         if ring:
             slots["window"] = self._ring_slots(ring[0], pos, active)
             starts = jnp.maximum(lengths - self.window, 0)
 
-        def attn(spec, q, _k, _v, kc, vc):
+        def attn(_i, spec, q, _k, _v, kc, vc):
             if spec.window is None:
                 return paged_attention_decode(
                     q, kc, vc, lengths, table,
@@ -1395,10 +1278,12 @@ class ServeEngine:
                 q, kc, vc, lengths, ring[0], starts=starts, ring=True,
                 backend=self.attention_backend).reshape(b, nh * self._dh)
 
-        out, new_caches, moe_sizes = self._stack_layers(
-            p, x, rope, caches, slots, attn, valid=active)
+        self._count_kv_write(b)
+        out, new_caches, moe_sizes = _stack.stack_layers(
+            p, x, rope, caches, partial(self._scatter_kv, slots, False),
+            attn, valid=active, backend=self.attention_backend)
         with jax.named_scope("head"):
-            logits = _gen._head_logits(p, out).astype(jnp.float32)  # [B, V]
+            logits = _stack.head_logits(p, out).astype(jnp.float32)  # [B, V]
         with jax.named_scope("sample"):
             nxt = _gen._sample_slot_tokens(logits, temps, key)
         return nxt, new_caches, moe_sizes
@@ -1470,7 +1355,7 @@ class ServeEngine:
         positions = jnp.arange(tp, dtype=jnp.int32)
         valid = positions < n                              # [Tp]
         with jax.named_scope("embed"):
-            x, rope = self._embed(p, ids, positions)    # [Tp, H]
+            x, rope = _stack.embed(p, ids, positions, self.max_seq_len)
         # causal within the prompt; pad rows see themselves only (their
         # K/V never reach the pool and their logits are never read)
         causal = (positions[None, :] <= positions[:, None]) \
@@ -1489,7 +1374,7 @@ class ServeEngine:
         flash = (p.get("prefill") == "flash"
                  and self.attention_backend != "reference")
 
-        def attn(spec, q, k, v, _kc, _vc):
+        def attn(_i, spec, q, k, v, _kc, _vc):
             if flash:
                 from ..ops.pallas.flash_attention import _flash_fwd_bhsd
 
@@ -1517,11 +1402,13 @@ class ServeEngine:
                 "hqk,khd->qhd", probs,
                 v_rep.astype(jnp.float32)).reshape(tp, nh * dh)
 
-        out, new_caches, _ = self._stack_layers(
-            p, x, rope, caches, slots, attn, fresh=True, valid=valid)
+        self._count_kv_write(tp, fresh=True)
+        out, new_caches, _ = _stack.stack_layers(
+            p, x, rope, caches, partial(self._scatter_kv, slots, True),
+            attn, valid=valid, backend=self.attention_backend)
         with jax.named_scope("head"):
             h_last = jnp.take(out, n - 1, axis=0)          # [H]
-            logits = _gen._head_logits(p, h_last[None, :])[0]
+            logits = _stack.head_logits(p, h_last[None, :])[0]
             return new_caches, logits.astype(jnp.float32)
 
     def _suffix_prefill_impl(self, arrays, caches, ids, n, start,
@@ -1552,23 +1439,25 @@ class ServeEngine:
         positions = start + offs                           # absolute
         valid = offs < n
         with jax.named_scope("embed"):
-            x, rope = self._embed(p, ids, positions)    # [Tp, H]
+            x, rope = _stack.embed(p, ids, positions, self.max_seq_len)
 
         slots = {"full": self._full_slots(table_row, positions, valid)}
         lengths = jnp.where(valid, positions + 1, 0)       # causal
         tables_rep = jnp.broadcast_to(
             table_row[None, :], (tp, table_row.shape[0]))
 
-        def attn(_spec, q, _k, _v, kc, vc):
+        def attn(_i, _spec, q, _k, _v, kc, vc):
             return paged_attention_decode(
                 q, kc, vc, lengths, tables_rep,
                 backend=self.attention_backend).reshape(tp, nh * dh)
 
-        out, new_caches, _ = self._stack_layers(
-            p, x, rope, caches, slots, attn, valid=valid)
+        self._count_kv_write(tp)
+        out, new_caches, _ = _stack.stack_layers(
+            p, x, rope, caches, partial(self._scatter_kv, slots, False),
+            attn, valid=valid, backend=self.attention_backend)
         with jax.named_scope("head"):
             h_last = jnp.take(out, n - 1, axis=0)          # [H]
-            logits = _gen._head_logits(p, h_last[None, :])[0]
+            logits = _stack.head_logits(p, h_last[None, :])[0]
             return new_caches, logits.astype(jnp.float32)
 
     def _cow_impl(self, caches, src, dst):

@@ -39,9 +39,8 @@ class PoolExhaustedError(RuntimeError):
 
     Raised by :meth:`BlockPool.alloc`; the serving engine reacts by
     evicting prefix-cached (refcount-0) blocks, then queueing the
-    admission or preempting the youngest stream; a bare
-    ``generate(paged=True)`` caller fails loudly instead of gathering
-    out of bounds.
+    admission or preempting the youngest stream (a request that could
+    never fit is refused at ``submit()``).
     """
 
 

@@ -1,0 +1,170 @@
+"""models/decoder_stack.py: the one decoder stack, under two kinds of
+cache.
+
+``stack_layers`` is a pure function of a family's decode view and of two
+closures, ``write_kv`` and ``attn``. Here it runs a prompt under plain
+dense closures (the cache is the prompt's own rows, attention a masked
+softmax with a band where the layer has a window) and under
+``ServeEngine``'s paged closures (pool, block table, ring; ``reference``
+backend), for a GPT, a Llama and a two-layer EXAONE-MoE view: the last
+row's logits agree. Where ``generate()`` serves the family, its
+``_cached_forward`` is a third caller held to the same logits.
+"""
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as paddle
+from paddle_tpu.models import (GPTConfig, GPTForCausalLM, LlamaConfig,
+                               LlamaForCausalLM)
+from paddle_tpu.models import decoder_stack as ds
+from paddle_tpu.models.exaone_moe import (ExaoneMoeConfig,
+                                          ExaoneMoeForCausalLM)
+from paddle_tpu.models.generation import _cached_forward, _decode_family
+from paddle_tpu.serve import ServeEngine
+
+T = 13          # longer than EXAONE's window of 8, not a block multiple
+BLOCK = 4
+
+
+def _gpt():
+    return GPTForCausalLM(GPTConfig.tiny(
+        vocab_size=89, hidden_size=32, intermediate_size=64,
+        num_hidden_layers=2, num_attention_heads=4,
+        max_position_embeddings=32, hidden_dropout_prob=0.0,
+        attention_probs_dropout_prob=0.0))
+
+
+def _llama():
+    return LlamaForCausalLM(LlamaConfig.tiny(
+        vocab_size=97, hidden_size=32, intermediate_size=64,
+        num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=2,
+        max_position_embeddings=64))
+
+
+def _exaone():
+    return ExaoneMoeForCausalLM(ExaoneMoeConfig.tiny(
+        num_hidden_layers=2, sliding_window=8,
+        layer_types=("sliding_attention", "full_attention")))
+
+
+FAMILIES = {"gpt": _gpt, "llama": _llama, "exaone": _exaone}
+
+
+def _build(family):
+    paddle.seed(5)
+    model = FAMILIES[family]()
+    model.eval()
+    vocab = model.config.vocab_size
+    ids = np.random.RandomState(17).randint(1, vocab, T).astype(np.int32)
+    return model, ids
+
+
+def _dense_last_logits(p, ids):
+    """The stack under the plainest closures there are."""
+    t = ids.shape[0]
+    nh, kvh, dh = p["nh"], p["nkv"], p["dh"]
+    pos = jnp.arange(t)
+    x, rope = ds.embed(p, jnp.asarray(ids), pos, t)
+
+    def write_kv(_i, _spec, _kc, _vc, k, v):
+        return k, v                       # the cache is the rows
+
+    def attn(_i, spec, q, _k, _v, kc, vc):
+        seen = pos[None, :] <= pos[:, None]
+        if spec.window is not None:
+            seen &= pos[:, None] - pos[None, :] < spec.window
+        kk, vv = (jnp.repeat(a, nh // kvh, axis=1) for a in (kc, vc))
+        s = jnp.einsum("qhd,khd->hqk", q, kk) * dh ** -0.5
+        w = jnp.exp(s - s.max(-1, keepdims=True)) * seen[None]
+        w = w / w.sum(-1, keepdims=True)
+        return jnp.einsum("hqk,khd->qhd", w, vv).reshape(t, nh * dh)
+
+    out, caches, _ = ds.stack_layers(
+        p, x, rope, [(None, None)] * len(p["layers"]), write_kv, attn,
+        backend="reference")
+    assert caches[0][0].shape == (t, kvh, dh)
+    return np.asarray(ds.head_logits(p, out[-1]), np.float32)
+
+
+def _paged_last_logits(model, ids, name):
+    """The same prompt through the engine's cold prefill program: the
+    stack under ``_scatter_kv`` and in-prompt causal attention, K/V in
+    the slot's blocks (and ring)."""
+    eng = ServeEngine(model, max_slots=2, block_size=BLOCK, num_blocks=16,
+                      max_seq_len=32, attention_backend="reference",
+                      name=name)
+    eng._tables[1, :4] = [9, 3, 12, 5]
+    if eng.window is not None:
+        eng._rings[1] = np.arange(eng.ring_blocks)[::-1]
+    padded = np.zeros((1, 16), np.int32)
+    padded[0, :T] = ids
+    _, logits = eng._prefill_fn(eng._arrays, eng._caches,
+                                jnp.asarray(padded), jnp.int32(T),
+                                eng._table_args(1))
+    return np.asarray(logits)
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_one_stack_under_dense_and_paged_closures(family):
+    model, ids = _build(family)
+    p = _decode_family(model)
+    assert len(ds.specs_of(p)) == 2
+    dense = _dense_last_logits(p, ids)
+    paged = _paged_last_logits(model, ids, f"stack-{family}")
+    np.testing.assert_allclose(paged, dense, atol=2e-4, rtol=1e-4)
+    assert int(paged.argmax()) == int(dense.argmax())
+    caches = [(jnp.zeros((1, T, p["nkv"], p["dh"]), jnp.float32),) * 2
+              for _ in p["layers"]]
+    if family == "exaone":
+        # generate() keeps no band in its dense mask
+        with pytest.raises(NotImplementedError, match="sliding-window"):
+            _cached_forward(p, jnp.asarray(ids)[None], caches, 0, T)
+        return
+    hidden, _ = _cached_forward(p, jnp.asarray(ids)[None], caches, 0, T)
+    np.testing.assert_allclose(
+        np.asarray(ds.head_logits(p, hidden[0])), dense, atol=2e-4,
+        rtol=1e-4)
+
+
+def test_view_without_specs_is_refused():
+    model, _ = _build("llama")
+    view = model.decode_view()
+    bare = {k: v for k, v in view.items() if k != "specs"}
+    with pytest.raises(TypeError, match="one LayerSpec a layer"):
+        ds.specs_of(bare)
+    with pytest.raises(TypeError, match="one LayerSpec a layer"):
+        ds.specs_of({**bare, "specs": view["specs"][:1]})
+
+    class NoSpecs:
+        def decode_view(self):
+            return bare
+
+    with pytest.raises(TypeError, match="one LayerSpec a layer"):
+        _decode_family(NoSpecs())
+    with pytest.raises(TypeError, match="one LayerSpec a layer"):
+        ServeEngine(NoSpecs(), name="stack-nospecs")
+    # and a model that hands over no view at all
+    with pytest.raises(TypeError, match="Llama, GPT and ERNIE-MoE"):
+        _decode_family(model.llama)
+
+
+def test_ffn_kinds_are_looked_up():
+    """Every family's specs name kinds of the table; the engine serves
+    the kinds that work row by row and no other."""
+    from paddle_tpu.models import ErnieMoeConfig, ErnieMoeForCausalLM
+
+    kinds = {}
+    for family in FAMILIES:
+        model, _ = _build(family)
+        kinds[family] = {s.ffn for s in model.decode_view()["specs"]}
+    paddle.seed(5)
+    ernie = ErnieMoeForCausalLM(ErnieMoeConfig.tiny(moe_layer_interval=2))
+    ernie.eval()
+    kinds["ernie"] = {s.ffn for s in ernie.decode_view()["specs"]}
+    assert kinds == {"gpt": {"gelu"}, "llama": {"swiglu"},
+                     "exaone": {"swiglu", "moe"},
+                     "ernie": {"swiglu", "capacity_moe"}}
+    assert set().union(*kinds.values()) == set(ds.FFN_KINDS)
+    assert [k for k, v in ds.FFN_KINDS.items() if not v.per_row] == [
+        "capacity_moe"]

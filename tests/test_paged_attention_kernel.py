@@ -5,7 +5,7 @@ This is tools/paged_kernel_probe.py's kernel-vs-masked-softmax
 equivalence check promoted to pytest (ISSUE 14 satellite): the
 CPU-runnable tier-1 gates pin the jnp reference against an independent
 numpy oracle AND against the existing ``block_mha_p`` gather path (the
-serving op `generate(paged=True)` decodes through), so the kernel's
+reference's public serving op), so the kernel's
 semantics oracle is itself oracle-pinned; the Pallas kernel comparison
 runs the real kernel body under the interpreter at the probe's bf16
 serving shapes and is marked ``slow`` (tier-1 runs ``-m 'not slow'``;
@@ -84,8 +84,8 @@ class TestReference:
 
     def test_matches_block_mha_gather_path(self):
         """Bit-compatibility with the EXISTING paged gather path: one
-        decode step through ``_bmha_fwd`` (the block_mha_p program
-        `generate(paged=True)` drives) equals the new decode attention
+        decode step through ``_bmha_fwd`` (the block_mha_p program of
+        the public serving op) equals the new decode attention
         on the same pool state."""
         from paddle_tpu.incubate.nn.functional.inference_attention import \
             _bmha_fwd
