@@ -412,11 +412,15 @@ class ServeTracer:
         """One batched decode dispatch on the engine lane — a single
         step, or a fused burst of ``tokens`` in-scan steps when the
         engine runs with ``decode_burst > 1`` (one host round-trip
-        either way, which is exactly the point). The gap between the
-        previous dispatch's end and this start, while the previous one
-        left runnable slots behind, is host-side scheduler time the
-        chip sat idle — the fused-decode opportunity PTL404 lints;
-        bursts shrink the number of such gaps ~N x."""
+        either way, which is exactly the point). ``start`` is when the
+        program's dispatch began and ``end`` when its tokens were on
+        the host. The gap between the previous program's end and this
+        start, while the previous one left runnable slots behind, is
+        host-side scheduler time the chip sat idle — the fused-decode
+        opportunity PTL404 lints; bursts shrink the number of such gaps
+        ~N x. A program that the engine dispatched BEFORE it read the
+        one before (its steady order) starts before that one's end: the
+        chip had it queued, the gap is negative and is no stall."""
         if self._last_step_end is not None and self._last_step_active > 0:
             gap = start - self._last_step_end
             if gap > 0:

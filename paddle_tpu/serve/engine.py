@@ -34,6 +34,26 @@ Scheduling policy (the contract the tests pin):
   never retraces: ``serve.decode_traces`` stays at 1 for the life of
   the engine (the e2e test asserts exactly that). Prefill compiles once
   per power-of-two length bucket.
+- **One decode program in flight ahead of the host.** A steady
+  ``step()`` dispatches program N+1 BEFORE it reads program N's tokens:
+  N+1 takes its input tokens from N's output as it lies on the device,
+  and everything else it needs is known without them (each active
+  stream's length grows by one; a stream that reaches
+  ``max_new_tokens`` at N is left out of N+1; the block N+1 writes into
+  is allocated a token ahead). The host then blocks on N, emits its
+  tokens, and the next ``step()`` admits and dispatches N+2 while N+1
+  runs. Only an ``eos`` hit is not known ahead: a stream that hits it
+  at N still has ONE row in N+1, whose token the host throws away (the
+  row is written into a block the stream held when N+1 was dispatched;
+  every program is chained to the one before through the donated pool,
+  so whoever is handed that block later writes and reads it after N+1).
+  A prefill's first token is sampled on the host as ever, but its
+  logits are read only after the step's decode program is out, so the
+  new stream joins the program after. What must see the tokens first
+  reads the program in flight and then runs as before
+  (``serve.pipeline_drains``): a preemption, a fused burst, a step with
+  nothing to decode. There is no other order: with nothing in flight
+  the same code is the sequential loop.
 
 What a decoder layer IS belongs to the model families: every compiled
 step here runs ``models/decoder_stack.stack_layers`` over the view the
@@ -131,7 +151,16 @@ _M_STALLS = obs.counter(
 _M_TOKENS = obs.counter(
     "serve.tokens_generated", "tokens emitted across all streams")
 _M_DECODE_STEPS = obs.counter(
-    "serve.decode_steps", "batched decode steps executed")
+    "serve.decode_steps", "batched decode steps dispatched")
+_M_DECODE_OVERLAPPED = obs.counter(
+    "serve.decode_overlapped", "decode programs dispatched while the one "
+    "before was still unread: the host's work of that step ran behind "
+    "the device (over serve.decode_steps: how often the pipeline holds)")
+_M_PIPELINE_DRAINS = obs.counter(
+    "serve.pipeline_drains", "times the decode program in flight was "
+    "read before the next could be dispatched, by reason (preempt: the "
+    "pool ran dry; burst: a fused burst follows; idle: no stream had a "
+    "token to decode)")
 _M_DECODE_TRACES = obs.counter(
     "serve.decode_traces", "times the persistent decode step was "
     "traced — slot churn must keep this at 1 per engine")
@@ -246,6 +275,16 @@ class Request:
         if self.first_token_time is None:
             return None
         return self.first_token_time - self.submit_time
+
+
+@dataclass
+class _Program:
+    """One dispatched decode program whose tokens the host has not read."""
+
+    nxt: object                  # [max_slots] tokens, on the device
+    sizes: object                # the sparse layers' group sizes, flat
+    reqs: List[Optional[Request]]   # by slot: whose row it computes
+    start: float                 # when its dispatch began
 
 
 class ServeEngine:
@@ -411,6 +450,13 @@ class ServeEngine:
         self._secs = dict.fromkeys(STEP_PHASES, 0.0)
         self._key = jax.random.PRNGKey(seed)
         self._rng = np.random.default_rng(seed)
+        #: the decode program dispatched and not yet read, if any
+        self._inflight: Optional[_Program] = None
+        #: prefills whose first token is still to be read: (request,
+        #: logits on the device, when the prefill's dispatch began)
+        self._first_tokens: List[tuple] = []
+        # what a program takes for "the tokens before" with none in flight
+        self._no_tokens = jnp.zeros(self.max_slots, jnp.int32)
         # the caches are DONATED (argument 1 after the bound self):
         # the engine replaces self._caches with the returned pool every
         # call, so in-place aliasing is safe — and without it every
@@ -527,14 +573,18 @@ class ServeEngine:
 
     @property
     def has_work(self) -> bool:
-        """True while anything is queued or decoding."""
-        return bool(self.queue) or any(r is not None for r in self._slots)
+        """True while anything is queued or decoding, or a dispatched
+        program's tokens are still to be read."""
+        return (bool(self.queue) or self._inflight is not None
+                or any(r is not None for r in self._slots))
 
     def step(self) -> int:
         """One scheduler iteration: admit from the queue into free
-        slots (prefill), then run ONE batched decode step for every
-        active stream, retiring the ones that finish. Returns the
-        number of streams that were active this step."""
+        slots (their prefills are dispatched), dispatch ONE batched
+        decode step for every stream that has a token to decode, read
+        the step dispatched before it and retire the streams that
+        finish, then read the new streams' first tokens. Returns the
+        number of streams that held a slot this step."""
         serving_real_work = self.slo is not None and any(
             not r.warmup for r in self._live_requests())
         tok0, pre0 = self._n_tokens, self._n_preempts
@@ -545,11 +595,14 @@ class ServeEngine:
                 sp.note(admitted=self._admit())
             secs["admit"] = sp.seconds - secs["prefill"]
             n_active = self.n_active
-            if n_active:
-                if self.decode_burst > 1:
+            if self.decode_burst > 1:
+                # a burst carries its streams' tokens on from the host's
+                self._sync("burst")
+                if self.n_active:
                     self._decode_burst_once()
-                else:
-                    self._decode_once()
+            else:
+                self._decode_once()
+            self._read_first_tokens()
             whole.note(n_active=n_active)
             _M_QUEUE_DEPTH.set(len(self.queue), engine=self.name)
             _M_POOL_OCCUPANCY.set(round(self.pool.occupancy, 4),
@@ -724,9 +777,9 @@ class ServeEngine:
             start = (n_pre - 1) if cow else len(read_only) * bs
             self._prefill(req, prefill_ids, start=start)
             _M_ADMITTED.inc(engine=self.name)
-            if req.state is FINISHED:
-                continue        # eos / max_new hit on the first token
             self._lens[slot] = n_pre
+            # a resumed stream's pending token; a fresh stream's comes
+            # with its first token (_read_first_tokens)
             self._tokens[slot] = req.ids[-1]
             self._temps[slot] = req.temperature
             self._eos[slot] = (-1 if req.eos_token_id is None
@@ -745,12 +798,14 @@ class ServeEngine:
 
     def _prefill(self, req: Request, prefill_ids: List[int],
                  start: int = 0):
-        """Prefill this stream's KV. ``start`` tokens are already
-        resident (mounted from the prefix cache), so only the suffix
-        ``prefill_ids[start:]`` is computed — through the block table,
-        where each suffix row attends to the shared prefix it never
-        recomputed. ``start == 0`` is the cold path (in-prompt causal
-        attention, the PR-14 kernel)."""
+        """Dispatch the prefill of this stream's KV. ``start`` tokens are
+        already resident (mounted from the prefix cache), so only the
+        suffix ``prefill_ids[start:]`` is computed — through the block
+        table, where each suffix row attends to the shared prefix it
+        never recomputed. ``start == 0`` is the cold path (in-prompt
+        causal attention, the PR-14 kernel). A fresh stream's logits
+        stay on the device until ``_read_first_tokens``: the step's
+        decode program goes out before the host blocks on them."""
         import jax.numpy as jnp
 
         suffix = prefill_ids[start:]
@@ -772,11 +827,30 @@ class ServeEngine:
                     self._arrays, self._caches, jnp.asarray(padded),
                     jnp.int32(n), jnp.int32(start),
                     jnp.asarray(self._tables[req.slot]))
-            if req.n_generated == 0:
-                # fresh stream: its FIRST token comes from the prefill
-                # logits (this is the TTFT moment); resumed streams
-                # already hold their pending token, the logits are
-                # discarded
+            # the blocks this prefill fills are matchable from here on:
+            # whoever mounts them runs after it (a later request of this
+            # very admission pass shares them, as it always could)
+            self._register_full_blocks(req, written=len(prefill_ids))
+        self._secs["prefill"] += sp.seconds
+        if req.n_generated == 0:
+            # fresh stream: its FIRST token comes from these logits
+            self._first_tokens.append((req, logits, sp.start))
+            return
+        # resumed streams already hold their pending token, the logits
+        # are discarded
+        _M_PREFILL_SECONDS.observe(sp.seconds, engine=self.name)
+        if self.tracer is not None:
+            self.tracer.on_decode_begin(req)
+
+    def _read_first_tokens(self):
+        """Block on the logits of the prefills dispatched since the last
+        call, in admission order, and sample each fresh stream's first
+        token on the host (this is the TTFT moment). A second
+        ``serve.prefill`` span a prompt: the wait for its program."""
+        pending, self._first_tokens = self._first_tokens, []
+        for req, logits, start in pending:
+            with self._span("serve.prefill", request=req.id,
+                            first_token=True) as sp:
                 tok = self._sample_host(np.asarray(logits),
                                         req.temperature)
                 now = self._clock()
@@ -789,15 +863,14 @@ class ServeEngine:
                                               now=now)
                 if self.tracer is not None:
                     self.tracer.on_first_token(req, now)
+                slot = req.slot
                 self._append_token(req, tok)
-            else:
-                # resumed streams append nothing here; their
-                # just-refilled full blocks still need trie registration
-                self._register_full_blocks(req)
-        _M_PREFILL_SECONDS.observe(sp.seconds, engine=self.name)
-        self._secs["prefill"] += sp.seconds
-        if self.tracer is not None and req.state is not FINISHED:
-            self.tracer.on_decode_begin(req)
+                if req.state is not FINISHED:
+                    self._tokens[slot] = tok
+            _M_PREFILL_SECONDS.observe(sp.end - start, engine=self.name)
+            self._secs["prefill"] += sp.seconds
+            if self.tracer is not None and req.state is not FINISHED:
+                self.tracer.on_decode_begin(req)
 
     def _sample_host(self, logits: np.ndarray, temperature: float) -> int:
         """First-token sampling (host-side; decode steps sample on
@@ -810,16 +883,19 @@ class ServeEngine:
         prob /= prob.sum()
         return int(self._rng.choice(logits.shape[0], p=prob))
 
-    def _register_full_blocks(self, req: Request):
+    def _register_full_blocks(self, req: Request,
+                              written: Optional[int] = None):
         """Register every newly-FULL block of this stream in the prefix
-        trie so later prompts can share it. Written positions are
-        ``len(ids) - 1`` (the pending last token is emitted but not yet
-        written); a chunk another stream registered first wins and this
-        stream's block simply stays private."""
+        trie so later prompts can share it. ``written`` positions hold
+        their K/V once the programs dispatched so far have run:
+        ``len(ids) - 1`` for a decoding stream (the pending last token
+        is emitted but not yet written), the prefilled ids for a prompt
+        just dispatched. A chunk another stream registered first wins
+        and this stream's block simply stays private."""
         if self._prefix is None or req.prefix_node is None:
             return
         bs = self.block_size
-        full = (len(req.ids) - 1) // bs
+        full = (len(req.ids) - 1 if written is None else written) // bs
         while req.registered_upto < full:
             b = req.registered_upto
             req.prefix_node = self._prefix.register(
@@ -905,13 +981,34 @@ class ServeEngine:
             self.tracer.on_preempt(victim)
         return victim
 
+    def _decodable(self) -> List[Optional[Request]]:
+        """By slot, the stream whose row the next decode program
+        computes, or None: it has its first token, and the tokens it has
+        and the one a program in flight is making leave it one more to
+        make (an ``eos`` among them is not known yet: that row is
+        wasted)."""
+        flying = self._inflight.reqs if self._inflight is not None else None
+        rows: List[Optional[Request]] = []
+        for slot, r in enumerate(self._slots):
+            if r is not None and r.n_generated > 0:
+                unread = flying is not None and flying[slot] is r
+                if r.n_generated + unread < r.max_new_tokens:
+                    rows.append(r)
+                    continue
+            rows.append(None)
+        return rows
+
     def _ensure_blocks(self, lookahead: int = 1):
-        """Every active stream needs the block its next token writes
-        into; allocate at block boundaries, evicting youngest-first
-        when the pool runs dry (a stream that is ITSELF the youngest
-        self-preempts back to the queue rather than evicting an older
-        one). This is the full layers' table alone: a slot's ring of
-        window blocks came with the slot and never grows.
+        """Every stream of the next decode program needs the block its
+        next token writes into; allocate at block boundaries, evicting
+        youngest-first when the pool runs dry (a stream that is ITSELF
+        the youngest self-preempts back to the queue rather than
+        evicting an older one). This is the full layers' table alone: a
+        slot's ring of window blocks came with the slot and never grows.
+        ``_lens`` counts the rows of programs dispatched, read or not,
+        so with a program in flight this is one token ahead of what the
+        host has seen. A preemption needs the victim's tokens whole:
+        whatever is unread is read first, and the pass starts again.
 
         ``lookahead > 1`` (the fused-burst path) pre-allocates enough
         blocks for the next ``lookahead`` tokens so a stream one token
@@ -920,7 +1017,7 @@ class ServeEngine:
         writes into) is worth preempting for — when the pool can't fund
         the extra lookahead blocks the burst just shrinks via
         ``_pick_burst_len``'s capacity term."""
-        for req in sorted((r for r in self._slots if r is not None),
+        for req in sorted((r for r in self._decodable() if r is not None),
                           key=lambda r: r.admit_seq):
             if req.slot is None:
                 continue          # evicted by an older stream this pass
@@ -934,44 +1031,99 @@ class ServeEngine:
                 except PoolExhaustedError:
                     if len(req.blocks) > bi:
                         break     # next token covered; burst shrinks
+                    if self._inflight is not None or self._first_tokens:
+                        self._sync("preempt")
+                        return self._ensure_blocks(lookahead)
                     if self._preempt_youngest() is req:
                         break     # req went back to the queue itself
                     continue
                 req.blocks.extend(new)
                 self._tables[req.slot, len(req.blocks) - 1] = new[0]
 
+    def _sync(self, reason: str):
+        """Read whatever is dispatched and unread, the decode program in
+        flight and the new streams' first tokens: after it the host
+        holds every token, as the sequential loop did at this point."""
+        prog, self._inflight = self._inflight, None
+        if prog is not None:
+            _M_PIPELINE_DRAINS.inc(engine=self.name, reason=reason)
+            self._read_decode(prog)
+        self._read_first_tokens()
+
     def _decode_once(self):
+        """Dispatch the next decode program, THEN read the one before
+        it: the host's work of a step runs while the device runs the
+        program dispatched a step ago."""
+        rows = self._ensure_blocks_timed()
+        prev = self._inflight
+        if any(r is not None for r in rows):
+            self._inflight = self._dispatch_decode(rows, prev)
+        elif prev is not None:    # nothing to queue behind it
+            self._inflight = None
+            _M_PIPELINE_DRAINS.inc(engine=self.name, reason="idle")
+        if prev is not None:
+            self._read_decode(prev)
+
+    def _dispatch_decode(self, rows, prev: Optional[_Program]) -> _Program:
+        """One decode program for ``rows`` (by slot, the stream or
+        None). A row whose last token ``prev`` is still making takes it
+        from ``prev``'s output on the device; the others take the
+        host's. The slot state goes up as fresh arrays: the host writes
+        its own on while the program runs."""
         import jax
         import jax.numpy as jnp
 
-        active_np = self._ensure_blocks_timed()
-        if not active_np.any():
-            return                # everyone was preempted away
         with self._span("serve.decode.dispatch", burst=1) as dispatch:
+            active = np.array([r is not None for r in rows], bool)
+            fed = np.array([r is not None and prev is not None
+                            and prev.reqs[slot] is r
+                            for slot, r in enumerate(rows)], bool)
+            state = np.stack([self._tokens, self._lens, active, fed],
+                             dtype=np.int32)
             self._key, sub = jax.random.split(self._key)
-            nxt, self._caches = self._decode_fn(
-                self._arrays, self._caches, jnp.asarray(self._tokens),
-                jnp.asarray(self._lens), jnp.asarray(active_np),
-                self._table_args(), jnp.asarray(self._temps), sub)
+            nxt, sizes, self._caches = self._decode_fn(
+                self._arrays, self._caches,
+                self._no_tokens if prev is None else prev.nxt,
+                jnp.asarray(state), self._table_args(),
+                jnp.array(self._temps), sub)
+            for out in (nxt, sizes):
+                out.copy_to_host_async()
+            self._lens[active] += 1
+        self._dispatched(dispatch)
+        if prev is not None:
+            _M_DECODE_OVERLAPPED.inc(engine=self.name)
+        return _Program(nxt, sizes, list(rows), dispatch.start)
+
+    def _dispatched(self, dispatch, n: int = 1):
+        """Count one dispatch of ``n`` decode ticks."""
+        self._secs["dispatch"] += dispatch.seconds
+        _M_DECODE_STEPS.inc(n, engine=self.name)
+        _M_HOST_RT.inc(engine=self.name)
+
+    def _read_decode(self, prog: _Program):
+        """Block on a dispatched program's tokens and emit them. A row
+        whose stream has left its slot since the dispatch (``eos`` at
+        the program before) is thrown away."""
         with self._span("serve.decode.wait") as wait:
-            nxt = np.asarray(nxt)
+            nxt = np.asarray(prog.nxt)
         with self._span("serve.decode.emit") as emit:
-            self._count_moe(nxt[self.max_slots:], int(active_np.sum()))
+            self._count_moe(np.asarray(prog.sizes),
+                            sum(r is not None for r in prog.reqs))
             tok0 = self._n_tokens
-            for slot, req in enumerate(self._slots):
-                if req is None:
+            for slot, req in enumerate(prog.reqs):
+                if req is None or self._slots[slot] is not req:
                     continue
-                self._lens[slot] += 1
                 self._append_token(req, int(nxt[slot]))
                 if req.state is not FINISHED:
                     self._tokens[slot] = req.ids[-1]
             emit.note(tokens=self._n_tokens - tok0)
-        self._decode_done(1, dispatch, wait, emit)
+        self._decode_done(prog.start, wait, emit)
 
     def _count_moe(self, sizes: np.ndarray, n_tokens: int):
         """Feed the ``serve.moe_*`` counters from the held experts' group
         sizes of each sparse layer, which a decode program hands back
-        behind its tokens (nothing there for a dense model)."""
+        with its tokens, so a program late (nothing there for a dense
+        model)."""
         if not sizes.size:
             return
         sizes = sizes.reshape(len(self._sparse), -1)
@@ -981,38 +1133,39 @@ class ServeEngine:
             _M_MOE_MAX.inc(int(row.max()), engine=self.name, layer=layer)
             _M_MOE_SUM.inc(int(row.sum()), engine=self.name, layer=layer)
 
-    def _ensure_blocks_timed(self, lookahead: int = 1) -> np.ndarray:
+    def _ensure_blocks_timed(self, lookahead: int = 1) -> list:
         """``_ensure_blocks`` with any preemption it causes, as one
-        phase of the step; returns the active mask it leaves."""
+        phase of the step; returns the rows it leaves to decode (by
+        slot, the stream or None)."""
         pre0 = self._n_preempts
         with self._span("serve.ensure_blocks") as sp:
             self._ensure_blocks(lookahead)
             sp.note(preemptions=self._n_preempts - pre0)
         self._secs["ensure_blocks"] += sp.seconds
-        active = np.array([r is not None for r in self._slots], bool)
+        rows = self._decodable()
+        active = np.array([r is not None for r in rows], bool)
         if active.any():          # a decode step follows
             _M_PAGES_LIVE.inc(int((self._lens[active] // self.block_size
                                    + 1).sum()), engine=self.name)
             _M_PAGES_TABLE.inc(self._tables.size, engine=self.name)
-        return active
+        return rows
 
-    def _decode_done(self, n: int, dispatch, wait, emit):
-        """Feed everything that reads one decode program's times from
-        the three spans round it: the step record, the histogram, the
-        counters and the tracer's engine lane."""
+    def _decode_done(self, start: float, wait, emit, n: int = 1):
+        """Feed everything that reads one decode program's times, from
+        the start of its dispatch to its tokens on the host (the step
+        after, for a program that another was dispatched behind): the
+        step record, the histogram and the tracer's engine lane."""
         secs = self._secs
-        secs["dispatch"] += dispatch.seconds
         secs["wait"] += wait.seconds
         secs["emit"] += emit.seconds
-        _M_DECODE_SECONDS.observe(wait.end - dispatch.start,
-                                  engine=self.name)
-        _M_DECODE_STEPS.inc(n, engine=self.name)
-        _M_HOST_RT.inc(engine=self.name)
+        _M_DECODE_SECONDS.observe(wait.end - start, engine=self.name)
         if self.tracer is not None:
             # active_after = runnable slots LEFT BEHIND by this step —
-            # the gap to the next step only counts as host-side stall
-            # (PTL404) when someone was still waiting to decode
-            self.tracer.on_decode_step(dispatch.start, wait.end,
+            # the gap to the next program only counts as host-side stall
+            # (PTL404) when someone was still waiting to decode, and it
+            # is a gap only if that program's dispatch began after this
+            # one's tokens were read (one queued behind starts before)
+            self.tracer.on_decode_step(start, wait.end,
                                        active_after=self.n_active,
                                        queued=len(self.queue), tokens=n)
 
@@ -1046,7 +1199,8 @@ class ServeEngine:
         import jax
         import jax.numpy as jnp
 
-        active_np = self._ensure_blocks_timed(lookahead=self.decode_burst)
+        active_np = np.array([r is not None for r in
+                              self._ensure_blocks_timed(self.decode_burst)])
         if not active_np.any():
             return                # everyone was preempted away
         n = self._pick_burst_len()
@@ -1085,7 +1239,8 @@ class ServeEngine:
                     self._tokens[slot] = req.ids[-1]
             emit.note(tokens=n_emitted)
         _M_BURST_TOKENS.inc(n_emitted, engine=self.name)
-        self._decode_done(n, dispatch, wait, emit)
+        self._dispatched(dispatch, n)
+        self._decode_done(dispatch.start, wait, emit, n)
 
     def warm_burst(self, n: int):
         """Compile the ``n``-step fused burst against idle slot state
@@ -1139,8 +1294,10 @@ class ServeEngine:
                  jnp.zeros(self.max_slots, bool),
                  self._table_args(), jnp.asarray(self._temps))
         row = jnp.asarray(self._tables[0])
-        out = {"decode": self._decode_fn.lower(
-            *avals(*state, *slots, self._key))}
+        out = {"decode": self._decode_fn.lower(*avals(
+            *state, self._no_tokens,
+            jnp.zeros((4, self.max_slots), jnp.int32), *slots[3:],
+            self._key))}
         for b in sorted({self._bucket(int(n)) for n in prompt_lens}):
             out[f"prefill.{b}"] = self._prefill_fn.lower(*avals(
                 *state, jnp.zeros((1, b), jnp.int32), jnp.int32(1),
@@ -1219,29 +1376,34 @@ class ServeEngine:
             rows, self.block_size, rows_start_blocks=fresh,
             backend=self.attention_backend))
 
-    def _decode_impl(self, arrays, caches, tokens, lens, active, tables,
-                     temps, key):
+    def _decode_impl(self, arrays, caches, prev, state, tables, temps,
+                     key):
         """ONE batched decode tick over every slot: write each active
         stream's pending token into its KV block, attend through the
         block tables (decode-specialized paged attention), project,
         sample. Shapes are fixed at [max_slots, ...]; slot churn is
         data, so this traces exactly once per engine (asserted via
         ``serve.decode_traces``). The caches are DONATED: the pool
-        updates in place instead of being copied per token. A model
-        with sparse layers hands their held experts' group sizes back
-        behind the tokens, in the same array (one transfer)."""
+        updates in place instead of being copied per token. ``state``
+        is the slots' tokens, lengths, active mask and ``fed`` mask in
+        one int32 array (one upload); a ``fed`` row's token is
+        ``prev``'s, the output of the program before as it lies on the
+        device, which the host may not have read yet. A model with
+        sparse layers hands their held experts' group sizes back beside
+        the tokens (none for a dense model)."""
         import jax.numpy as jnp
 
         # executes at TRACE time only — the flatness counter the e2e
         # continuous-batching test pins at 1
         self.decode_traces += 1
         _M_DECODE_TRACES.inc(engine=self.name)
+        tokens, lens, active, fed = state
         nxt, new_caches, moe_sizes = self._decode_core(
-            caches, tokens, lens, active, tables, temps, key, arrays=arrays)
-        if moe_sizes:
-            nxt = jnp.concatenate(
-                [nxt, jnp.stack(moe_sizes).reshape(-1).astype(nxt.dtype)])
-        return nxt, new_caches
+            caches, jnp.where(fed != 0, prev, tokens), lens, active != 0,
+            tables, temps, key, arrays=arrays)
+        sizes = (jnp.stack(moe_sizes).reshape(-1).astype(jnp.int32)
+                 if moe_sizes else jnp.zeros(0, jnp.int32))
+        return nxt, sizes, new_caches
 
     def _decode_core(self, caches, tokens, lens, active, tables, temps,
                      key, *, arrays=None, p=None):

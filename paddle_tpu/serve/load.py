@@ -84,9 +84,10 @@ def warm_engine(engine: ServeEngine, max_prompt_len=None):
     for i, n in enumerate(n for n in dict.fromkeys(lens) if n >= 1):
         # the first token comes out of the prefill, so a one-token
         # request never runs a decode step: the first (shortest) warm
-        # request asks for two, which compiles the decode step too
+        # request asks for three, which runs the decode step both ways
+        # (its tokens from the host, and from the program before it)
         req = engine.submit(np.arange(n) % (vocab - 1) + 1,
-                            max_new_tokens=2 if i == 0 else 1, warmup=True)
+                            max_new_tokens=3 if i == 0 else 1, warmup=True)
         engine.run()
         if req.state != "FINISHED":   # pragma: no cover — engine contract
             raise RuntimeError("warm-up request did not finish")

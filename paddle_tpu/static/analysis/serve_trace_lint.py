@@ -12,7 +12,12 @@ continuous-batching engine can hide inside healthy-looking aggregates:
   slots behind. The chip sits idle while the host runs admission,
   sampling and bookkeeping — exactly the signal that motivates the
   ROADMAP's fused multi-token decode item (``lax.scan`` bursts between
-  scheduler passes).
+  scheduler passes). A step runs from its dispatch to its tokens on the
+  host; the engine dispatches a step before it reads the one before, so
+  in its steady order a step starts before its predecessor ends and the
+  gap is negative: a program was queued behind, which is no stall. Gaps
+  are left after a drain of that pipeline (a preemption) and wherever
+  the host, not the chip, was the last to finish.
 - **PTL405 — preemption thrash**: one request preempted >= K times. Each
   preemption throws away that stream's KV blocks and bills a full
   recompute prefill on resume; a request evicted over and over is paying

@@ -102,7 +102,9 @@ def test_program_spans_land_in_a_trace_started_from_outside(tmp_path):
     # a plain jax.profiler trace: no profiler.Profiler anywhere
     jax.profiler.start_trace(str(tmp_path))
     try:
-        for _ in range(3):
+        # admit and prefill; the first decode program goes out; from the
+        # third on a step dispatches a program and reads the one before
+        for _ in range(5):
             eng.step()
         float(step(ids, ids))
     finally:
@@ -112,8 +114,8 @@ def test_program_spans_land_in_a_trace_started_from_outside(tmp_path):
     for name, a, b, stats in events:
         by_name.setdefault(name, []).append((a, b, stats))
     steps = sorted(by_name["serve.step"])
-    assert len(steps) == 3
-    assert [s[2]["step"] for s in steps] == [0, 1, 2]
+    assert len(steps) == 5
+    assert [s[2]["step"] for s in steps] == [0, 1, 2, 3, 4]
     assert steps[0][2]["n_active"] == 1
 
     def inside_a_step(span):
@@ -124,9 +126,16 @@ def test_program_spans_land_in_a_trace_started_from_outside(tmp_path):
                  "serve.decode.emit"):
         assert by_name.get(name), f"{name} is not in the host plane"
         assert all(inside_a_step(s) for s in by_name[name]), name
-    prefill = by_name["serve.prefill"][0][2]
+    # the prompt's dispatch, and the wait for its first token
+    prefill, first = (s[2] for s in sorted(by_name["serve.prefill"]))
     assert prefill["bucket"] == 8 and prefill["tokens"] == 7
+    assert first["first_token"] and "bucket" not in first
+    assert len(by_name["serve.decode.dispatch"]) == 4
     assert len(by_name["serve.decode.wait"]) == 3
+    # a program's dispatch lies before the read of the program before it
+    for d, w in zip(sorted(by_name["serve.decode.dispatch"])[1:],
+                    sorted(by_name["serve.decode.wait"])):
+        assert d[1] <= w[0]
     (call,) = by_name["jit.call"]
     assert call[2]["fn"] == "tiny_train_step"
     for name in ("jit.lookup", "jit.state", "jit.dispatch",
@@ -140,7 +149,7 @@ def test_program_spans_land_in_a_trace_started_from_outside(tmp_path):
 # --------------------------------------------------------------------------
 def test_step_record_phases_sum_to_the_step_on_a_fake_clock():
     clock = obs.FakeClock(start=100.0, tick=0.001)
-    eng = _engine("spans-fake", clock=clock)
+    eng = _engine("spans-fake", clock=clock, trace=True)
     reqs = [eng.submit(np.arange(1, n), max_new_tokens=k)
             for n, k in [(8, 5), (4, 7), (6, 3)]]
     eng.run(max_steps=200)
@@ -157,10 +166,11 @@ def test_step_record_phases_sum_to_the_step_on_a_fake_clock():
                                                    abs=1e-9)
         # one clock pair round a decode's wait: one tick on this clock
         assert secs["wait"] in (0.0, pytest.approx(0.001))
-    # one clock pair round a prefill, and the first token's read between
-    # them: two ticks on this clock, for each of the three prompts
+    # one clock pair round a prefill's dispatch, another round the wait
+    # for its logits, and the first token's read inside that: three ticks
+    # on this clock, for each of the three prompts
     assert sum(r["seconds"]["prefill"] for r in records) == pytest.approx(
-        3 * 0.002)
+        3 * 0.003)
     assert all(q.finish_time is not None for q in reqs)
     # the registry's series are fed from the same measurements
     hist = obs.registry.get("serve.decode_step_seconds")
@@ -168,9 +178,17 @@ def test_step_record_phases_sum_to_the_step_on_a_fake_clock():
     assert hist.stats(engine="spans-fake")["count"] == n_decodes
     assert obs.registry.get("serve.host_roundtrips").value(
         engine="spans-fake") == n_decodes
+    assert sum(r["seconds"]["dispatch"] > 0 for r in records) == n_decodes
+    # a program's time runs from its dispatch to its tokens, which are
+    # read a step later wherever another program was dispatched behind
+    # it: the same pairs that the tracer's engine lane gets
+    lane = list(eng.tracer.decode_steps)
+    assert len(lane) == n_decodes
     assert hist.stats(engine="spans-fake")["sum"] == pytest.approx(
-        sum(r["seconds"]["dispatch"] + r["seconds"]["wait"] + 0.001
-            for r in records if r["seconds"]["wait"] > 0), abs=1e-9)
+        sum(s["end"] - s["start"] for s in lane), abs=1e-6)
+    assert hist.stats(engine="spans-fake")["sum"] > sum(
+        r["seconds"]["dispatch"] + r["seconds"]["wait"] for r in records)
+    assert any(b["start"] < a["end"] for a, b in zip(lane, lane[1:]))
     assert obs.registry.get("serve.prefill_seconds").stats(
         engine="spans-fake")["count"] == 3
 
@@ -228,6 +246,7 @@ def test_a_step_reads_its_clock_a_dozen_times():
     eng = _engine("spans-count", clock=clock)
     eng.submit(np.arange(1, 8), max_new_tokens=12)
     eng.step()                       # admits and prefills
+    eng.step()                       # the first decode program goes out
     for _ in range(3):
         before = clock.reads
         eng.step()                   # decodes only: nothing admitted or done
@@ -237,8 +256,9 @@ def test_a_step_reads_its_clock_a_dozen_times():
     before = clock.reads
     eng.submit(np.arange(1, 5), max_new_tokens=4)    # submit time: 1
     eng.step()
-    # the admission: admit time, the prefill's pair, the first token: 4
-    assert clock.reads - before == 1 + 13 + 4
+    # the admission: admit time, the prefill's pair, the pair round the
+    # wait for its logits, the first token: 6
+    assert clock.reads - before == 1 + 13 + 6
 
 
 def test_to_static_keeps_a_step_record_a_call():

@@ -189,6 +189,19 @@ class TestServeTracerHooks:
         assert obs.registry.get("trace.decode_gap_seconds").value(
             engine="t2") == pytest.approx(0.04)
 
+    def test_a_step_queued_behind_the_last_is_no_gap(self):
+        # the engine's steady order: a program is dispatched before the
+        # one before it is read, so it starts before that one ends
+        clk = obs.FakeClock()
+        tr = ServeTracer("t2b", clk, max_slots=1)
+        tr.on_decode_step(0.000, 0.020, active_after=1, queued=0)
+        tr.on_decode_step(0.004, 0.040, active_after=1, queued=0)  # queued
+        tr.on_decode_step(0.024, 0.060, active_after=1, queued=0)  # queued
+        assert tr.total_decode_gap == 0.0
+        # after a drain the next program starts late: that is a stall
+        tr.on_decode_step(0.065, 0.085, active_after=1, queued=0)
+        assert tr.total_decode_gap == pytest.approx(0.005)
+
     def test_chrome_export_lanes_and_merge(self, tmp_path):
         clk = obs.FakeClock(tick=0.001)
         tr = ServeTracer("t3", clk, max_slots=2)
@@ -388,6 +401,18 @@ class TestServeTraceLint:
         assert _codes(report) == ["PTL404"]
         (d,) = list(report)
         assert d.suggestion["gap_seconds"] == pytest.approx(0.05, rel=0.1)
+
+    def test_overlapped_steps_are_not_flagged(self):
+        # each step dispatched 4 ms into the one before, read 20 ms on:
+        # negative gaps, however long the steps
+        steps = [{"start": 0.020 * i, "end": 0.020 * i + 0.036,
+                  "active": 2, "queued": 0} for i in range(12)]
+        assert not lint_serve_trace(self._dump(steps=steps)).diagnostics
+        # ... and a hole of most of a second after a drain still is
+        late = [dict(s, start=s["start"] + 1.0, end=s["end"] + 1.0)
+                for s in steps[:3]]
+        report = lint_serve_trace(self._dump(steps=steps + late))
+        assert _codes(report) == ["PTL404"]
 
     def test_gap_while_drained_is_not_flagged(self):
         steps = self._steps(5)

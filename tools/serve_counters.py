@@ -1,0 +1,90 @@
+#!/usr/bin/env python3
+"""One benchmark cell as `benchmark/run.py` runs it, then the serving
+engine's own counters of that run, on the chip:
+
+    chiprun -- python3 tools/serve_counters.py --workload <cell> --seed <n> \
+        --seconds 50 --trace 0
+
+The arguments are `benchmark/run.py`'s and go to its `main` untouched: the
+result line is still the last line of standard output. The counters (the
+registry's series under the cell's name, which the harness gives its
+engine: warm-up, ramp, window and drain together) go to standard error as
+one JSON object: decode programs dispatched, how many of them went out
+while the one before was unread, the pipeline's drains by reason,
+preemptions. A checkout from before a counter has it prints null there.
+Then, from the engine's step ring (its last 4,096 steps), the five longest
+steps with their seconds by phase and the five longest gaps between two
+steps (the caller's time): where a one-off stall of seconds lies (ROADMAP
+S8), if the run held one. Judges nothing.
+"""
+import json
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+
+def counters(engine: str) -> dict:
+    from paddle_tpu import observability as obs
+
+    def value(name, **labels):
+        m = obs.registry.get(name)
+        return None if m is None else m.value(engine=engine, **labels)
+
+    steps = value("serve.decode_steps")
+    overlapped = value("serve.decode_overlapped")
+    return {
+        "engine": engine, "decode_steps": steps,
+        "decode_overlapped": overlapped,
+        "overlapped_share": (overlapped / steps
+                             if steps and overlapped is not None else None),
+        "pipeline_drains": {r: value("serve.pipeline_drains", reason=r)
+                            for r in ("preempt", "burst", "idle")},
+        "preemptions": value("serve.preemptions", reason="pool_exhausted"),
+        "requests_finished": value("serve.requests_finished",
+                                   reason="max_new_tokens"),
+    }
+
+
+def slowest(engine: str, k: int = 5) -> dict:
+    """The ring's ``k`` longest steps and longest gaps between steps, in
+    milliseconds, each with when it began (seconds before the ring's end)."""
+    from paddle_tpu.observability import tracing
+
+    steps = list(tracing.ring(engine, "steps"))
+    if not steps:
+        return {}
+    t1 = steps[-1]["end"]
+    by_len = sorted(steps, key=lambda r: r["end"] - r["begin"])[-k:]
+    gaps = sorted(zip(steps, steps[1:]),
+                  key=lambda ab: ab[1]["begin"] - ab[0]["end"])[-k:]
+    return {
+        "steps_in_ring": len(steps),
+        "longest_steps": [
+            {"before_end_s": round(t1 - r["begin"], 3),
+             "ms": round((r["end"] - r["begin"]) * 1e3, 2),
+             "phases_ms": {p: round(v * 1e3, 2)
+                           for p, v in r["seconds"].items() if v > 5e-4}}
+            for r in reversed(by_len)],
+        "longest_gaps": [
+            {"before_end_s": round(t1 - a["end"], 3),
+             "ms": round((b["begin"] - a["end"]) * 1e3, 2)}
+            for a, b in reversed(gaps)]}
+
+
+def main(argv=None):
+    from benchmark import run
+
+    argv = sys.argv[1:] if argv is None else argv
+    rc = run.main(argv)
+    cell = argv[argv.index("--workload") + 1]
+    print("serve counters: " + json.dumps(counters(cell)), file=sys.stderr,
+          flush=True)
+    print("serve slowest: " + json.dumps(slowest(cell)), file=sys.stderr,
+          flush=True)
+    return rc
+
+
+if __name__ == "__main__":
+    sys.exit(main())
