@@ -5,17 +5,23 @@ A family hands over a *view* (``<Family>ForCausalLM.decode_view()``): its
 parameter arrays under the names below, the statics ``nh`` / ``nkv`` /
 ``dh`` / ``eps`` (and ``theta`` where a layer rotates), and ``specs``,
 one :class:`LayerSpec` a layer. :func:`stack_layers` reads the layer off
-its spec (norm and its placement, projections, q/k norm, RoPE, FFN kind)
-and is handed what legitimately differs between its callers, and nothing
-else: ``write_kv`` (how new K/V rows enter a layer's cache) and ``attn``
-(how a row attends). The callers are ``serve.ServeEngine``'s compiled
+its spec (the mixer's kind, norm and its placement, projections, q/k norm,
+RoPE, FFN kind) and is handed what legitimately differs between its
+callers, and nothing else: ``write_kv`` (how new K/V rows enter a layer's
+cache), ``attn`` (how a row attends) and, for a state-space layer,
+``ssm`` (the convolution and the recurrence over the caller's cached
+state). A view may also carry four scalings, each 1 (or ``dh ** -0.5``)
+where it is absent and then not applied: ``embedding_multiplier``,
+``residual_multiplier``, ``logits_scaling`` and ``attn_scale``. The
+callers are ``serve.ServeEngine``'s compiled
 steps (a paged pool, ``ops/pallas`` kernels) and
 ``models/generation._cached_forward`` (a dense cache, a masked softmax);
 a new kind of *cache* is theirs, a new kind of *sub-layer* is this
 module's, a new family's leaves are its own ``decode_view``.
 
 The ``jax.named_scope`` names here (``layer<i>/qkv|scatter_kv|attn|out|
-ffn``, ``layer<i>/moe/...``, ``final_norm``) are what the compiled
+ffn``, ``layer<i>/moe/...``, ``layer<i>/ssm/in_proj|conv|scan|gate_norm|
+out``, ``final_norm``) are what the compiled
 steps' op metadata, XProf, ``profiler.scope_seconds`` and
 ``tools/scope_breakdown.py`` name device time by.
 """
@@ -38,6 +44,10 @@ class LayerSpec(NamedTuple):
     qk_norm: bool = False
     window: Optional[int] = None   # None: full attention
     ffn: str = "swiglu"            # a key of FFN_KINDS
+    #: what mixes the tokens: "attention" (everything above ``ffn``
+    #: describes it) | "mamba2" (a state-space layer: pre-norm, its
+    #: sizes in the view's ``ssm`` statics)
+    mixer: str = "attention"
 
 
 #: a GPT-2 layer
@@ -86,6 +96,18 @@ def swiglu_ffn(h, lp, dtype):
             * (h @ lp["wu"])) @ lp["wd"]
 
 
+def swiglu_fused_ffn(h, lp, dtype):
+    """SwiGLU MLP whose gate and up matrices are one leaf, side by side
+    (``w_in`` ``[H, 2 I]``, ``wd``)."""
+    import jax
+    import jax.numpy as jnp
+
+    gate_up = h @ lp["w_in"]
+    i = gate_up.shape[-1] // 2
+    return (jax.nn.silu(gate_up[:, :i].astype(jnp.float32)).astype(dtype)
+            * gate_up[:, i:]) @ lp["wd"]
+
+
 def gelu_ffn(h, lp, dtype):
     """GELU MLP with biases (``w1``, ``b1``, ``w2``, ``b2``)."""
     import jax
@@ -131,6 +153,7 @@ def _dense(ffn):
 
 FFN_KINDS = {
     "swiglu": FfnKind(_dense(swiglu_ffn), "ffn", "ffn"),
+    "swiglu_fused": FfnKind(_dense(swiglu_fused_ffn), "ffn", "ffn"),
     "gelu": FfnKind(_dense(gelu_ffn), "ffn", "ffn"),
     # dropless, one chip's held experts (models/exaone_moe.py)
     "moe": FfnKind(_moe, None, "moe/combine"),
@@ -140,10 +163,11 @@ FFN_KINDS = {
 
 
 def head_logits(p, hidden):
-    """LM-head logits; tied heads reuse the embedding in-graph."""
-    if p.get("tied_head"):
-        return hidden @ p["embed"].T
-    return hidden @ p["head"]
+    """LM-head logits; tied heads reuse the embedding in-graph. A view's
+    ``logits_scaling`` divides them."""
+    logits = hidden @ (p["embed"].T if p.get("tied_head") else p["head"])
+    scaling = p.get("logits_scaling", 1)
+    return logits if scaling == 1 else logits / scaling
 
 
 def rope_rows(p, pos, s_max):
@@ -185,6 +209,8 @@ def embed(p, tokens, positions, s_max):
     x = jnp.take(p["embed"], tokens, axis=0)
     if tokens.ndim == 2:            # a prefill's [1, bucket] ids
         x = x[0]
+    if p.get("embedding_multiplier", 1) != 1:
+        x = x * p["embedding_multiplier"]
     rope = None
     if any(s.rope for s in specs_of(p)):
         rope = rope_rows(p, positions, s_max)
@@ -193,17 +219,101 @@ def embed(p, tokens, positions, s_max):
     return x, rope
 
 
-def stack_layers(p, x, rope, caches, write_kv, attn, *, valid=None,
-                 backend="auto"):
-    """ONE transformer stack for every cached decode path, read off each
+def _attention_mixer(i, spec, p, lp, x, cache, rope, write_kv, attn,
+                     norm, scaled):
+    """Layer ``i``'s attention sub-layer on ``x`` [rows, H]: (x with the
+    sub-layer's output added, the layer's cache as written)."""
+    import jax
+
+    rows = x.shape[0]
+    nh, kvh, dh = p["nh"], p["nkv"], p["dh"]
+    dtype, eps = p["embed"].dtype, p["eps"]
+    scope = jax.named_scope
+    kc, vc = cache
+    pre = spec.placement == "pre"
+    with scope(f"layer{i}/qkv"):
+        h = norm(spec, x, lp, "ln1") if pre else x
+        if spec.proj == "split":
+            q = (h @ lp["wq"]).reshape(rows, nh, dh)
+            k = (h @ lp["wk"]).reshape(rows, kvh, dh)
+            v = (h @ lp["wv"]).reshape(rows, kvh, dh)
+        else:
+            qkv = (h @ lp["wqkv"] + lp["bqkv"]).reshape(
+                rows, 3, nh, dh)
+            q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]
+        if spec.qk_norm:
+            q = rms(q, lp["qn"], eps, dtype)
+            k = rms(k, lp["kn"], eps, dtype)
+        if spec.rope:
+            q, k = rotate(q, k, *rope, dtype)
+    with scope(f"layer{i}/scatter_kv"):
+        kc, vc = write_kv(i, spec, kc, vc, k, v)
+    with scope(f"layer{i}/attn"):
+        ctx = attn(i, spec, q, k, v, kc, vc)
+    with scope(f"layer{i}/out"):
+        if spec.proj != "split":
+            x = x + scaled(ctx.astype(dtype) @ lp["wo"]) + scaled(lp["bo"])
+        elif pre:
+            x = x + scaled(ctx.astype(dtype) @ lp["wo"])
+        else:
+            x = x + scaled(norm(spec, ctx.astype(dtype) @ lp["wo"], lp,
+                                "ln1"))
+    return x, (kc, vc)
+
+
+def _mamba2_mixer(i, spec, p, lp, x, cache, ssm, norm, scaled):
+    """Layer ``i``'s state-space sub-layer (Mamba-2; ``ops/ssm.py`` has
+    the equations): norm, ``[z | xBC | dt] = h W_in``, the caller's
+    convolution and recurrence over its cache, ``RMSNorm(y * silu(z)) *
+    w`` over all the channels (one group, the gate before the norm), the
+    output projection."""
+    import jax
+    import jax.numpy as jnp
+
+    if ssm is None:
+        raise NotImplementedError(
+            f"layer {i} is a `mamba2` mixer: its convolution tail and "
+            "recurrent state are the caller's cache, and this caller "
+            "handed stack_layers no `ssm` closure (generate()'s dense "
+            "cache keeps none: such a model is served through "
+            "ServeEngine)")
+    if spec.placement != "pre":
+        raise ValueError("a `mamba2` mixer is pre-norm")
+    st = p["ssm"]
+    dtype = p["embed"].dtype
+    inner = st["heads"] * st["dh"]
+    scope = jax.named_scope
+    with scope(f"layer{i}/ssm/in_proj"):
+        proj = norm(spec, x, lp, "ln1") @ lp["in_proj"]
+        z = proj[:, :inner]
+        xbc = proj[:, inner:inner + st["channels"]]
+        dt = proj[:, inner + st["channels"]:]
+    y, cache = ssm(i, spec, lp, xbc, dt, cache)
+    with scope(f"layer{i}/ssm/gate_norm"):
+        gated = rms(y * jax.nn.silu(z.astype(jnp.float32)), lp["gate_norm"],
+                    p["eps"], dtype)
+    with scope(f"layer{i}/ssm/out"):
+        x = x + scaled(gated @ lp["out_proj"])
+    return x, cache
+
+
+def stack_layers(p, x, rope, caches, write_kv, attn, *, ssm=None,
+                 valid=None, backend="auto"):
+    """ONE decoder stack for every cached decode path, read off each
     layer's ``LayerSpec``: norm and projection, q/k norm, rope, the new
-    K/V rows into the layer's cache, attention, residual + FFN (by
-    kind), final norm. ``x`` is [rows, H] and ``rope`` its rows' cos/sin
-    (:func:`embed` gives both); ``caches`` one (K, V) a layer, of
-    whatever kind the caller keeps. ``write_kv(i, spec, kc, vc, k, v) ->
-    (kc, vc)`` takes layer ``i``'s new rows ([rows, kvh, dh]) and
+    K/V rows into the layer's cache, attention (or, for a ``mamba2``
+    mixer, the input projection, the caller's convolution and recurrence,
+    the gated norm and the output projection), residual + FFN (by kind),
+    final norm. ``x`` is [rows, H] and ``rope`` its rows' cos/sin
+    (:func:`embed` gives both); ``caches`` one pair a layer, of whatever
+    kind the caller keeps: (K, V) for an attention layer, whatever
+    ``ssm`` takes for a state-space one. ``write_kv(i, spec, kc, vc, k,
+    v) -> (kc, vc)`` takes layer ``i``'s new rows ([rows, kvh, dh]) and
     ``attn(i, spec, q, k, v, kc, vc) -> [rows, nh*dh]`` attends over the
-    cache as written: they are all that the callers differ in. ``valid``
+    cache as written; ``ssm(i, spec, lp, xbc, dt, cache) -> (y [rows,
+    heads * dh] float32, cache)`` runs the layer's convolution and its
+    recurrence over the rows (under the scopes ``layer<i>/ssm/conv`` and
+    ``/scan``): they are all that the callers differ in. ``valid``
     marks the rows that are tokens (a sparse layer routes the others
     nowhere) and ``backend`` is ``moe_ffn``'s. Returns (normed hidden
     [rows, H], new caches, the held experts' group sizes of each sparse
@@ -213,52 +323,34 @@ def stack_layers(p, x, rope, caches, write_kv, attn, *, valid=None,
     import jax
 
     specs = specs_of(p)
-    rows = x.shape[0]
-    nh, kvh, dh = p["nh"], p["nkv"], p["dh"]
     dtype = p["embed"].dtype
     eps = p["eps"]
+    res = p.get("residual_multiplier", 1)
 
     def norm(spec, x, lp, which):
         if spec.norm == "rms":
             return rms(x, lp[which], eps, dtype)
         return ln(x, lp[which + "_w"], lp[which + "_b"], eps, dtype)
 
+    def scaled(h):
+        """A sub-layer's output as it joins the residual stream."""
+        return h if res == 1 else h * res
+
     # scopes by hand, as nn.Layer.__call__ gives them to the eager
     # stack: they are what the op metadata of the compiled steps, and
     # with it XProf and profiler.scope_seconds, name device time by
     scope = jax.named_scope
     new_caches, moe_sizes = [], []
-    for i, (lp, spec, (kc, vc)) in enumerate(
+    for i, (lp, spec, cache) in enumerate(
             zip(p["layers"], specs, caches)):
         pre = spec.placement == "pre"
-        with scope(f"layer{i}/qkv"):
-            h = norm(spec, x, lp, "ln1") if pre else x
-            if spec.proj == "split":
-                q = (h @ lp["wq"]).reshape(rows, nh, dh)
-                k = (h @ lp["wk"]).reshape(rows, kvh, dh)
-                v = (h @ lp["wv"]).reshape(rows, kvh, dh)
-            else:
-                qkv = (h @ lp["wqkv"] + lp["bqkv"]).reshape(
-                    rows, 3, nh, dh)
-                q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]
-            if spec.qk_norm:
-                q = rms(q, lp["qn"], eps, dtype)
-                k = rms(k, lp["kn"], eps, dtype)
-            if spec.rope:
-                q, k = rotate(q, k, *rope, dtype)
-        with scope(f"layer{i}/scatter_kv"):
-            kc, vc = write_kv(i, spec, kc, vc, k, v)
-        new_caches.append((kc, vc))
-        with scope(f"layer{i}/attn"):
-            ctx = attn(i, spec, q, k, v, kc, vc)
-        with scope(f"layer{i}/out"):
-            if spec.proj != "split":
-                x = x + ctx.astype(dtype) @ lp["wo"] + lp["bo"]
-            elif pre:
-                x = x + ctx.astype(dtype) @ lp["wo"]
-            else:
-                x = x + norm(spec, ctx.astype(dtype) @ lp["wo"], lp,
-                             "ln1")
+        if spec.mixer == "mamba2":
+            x, cache = _mamba2_mixer(i, spec, p, lp, x, cache, ssm, norm,
+                                     scaled)
+        else:
+            x, cache = _attention_mixer(i, spec, p, lp, x, cache, rope,
+                                        write_kv, attn, norm, scaled)
+        new_caches.append(cache)
         kind = FFN_KINDS[spec.ffn]
         with scope(f"layer{i}/{kind.outer}"):
             h = norm(spec, x, lp, "ln2") if pre else x
@@ -268,7 +360,7 @@ def stack_layers(p, x, rope, caches, write_kv, attn, *, valid=None,
         if sizes is not None:
             moe_sizes.append(sizes)
         with scope(f"layer{i}/{kind.outer}"):
-            x = x + (f if pre else norm(spec, f, lp, "ln2"))
+            x = x + scaled(f if pre else norm(spec, f, lp, "ln2"))
     with scope("final_norm"):
         if specs[-1].norm == "rms":
             out = rms(x, p["norm"], eps, dtype)

@@ -130,6 +130,19 @@ _M_PAGES_TABLE = obs.counter(
     "serve.paged_pages_table", "entries of the full layers' block table "
     "(max_slots x its width), summed over decode steps: what a walk of "
     "the whole table would visit")
+_M_SSM_ROWS_LIVE = obs.counter(
+    "serve.ssm_rows_live", "state rows a decode step moves on: its live "
+    "slots x the model's state-space layers, summed over steps (what "
+    "ssm_decode walks)")
+_M_SSM_ROWS_TABLE = obs.counter(
+    "serve.ssm_rows_table", "max_slots x state-space layers, summed over "
+    "decode steps: what a walk of every slot's state would visit")
+_M_SSM_PREFILL_TOKENS = obs.counter(
+    "serve.ssm_prefill_tokens", "prompt tokens a prefill's chunked scan "
+    "went over (re-prefills after a preemption count again)")
+_M_SSM_STATE_BYTES = obs.gauge(
+    "serve.ssm_state_bytes", "bytes the recurrent state and convolution "
+    "tails of all slots and state-space layers hold on the device")
 _M_BATCH_FILL = obs.gauge(
     "serve.batch_fill", "active streams / max_slots at the last step")
 _M_TOKENS_PER_SEC = obs.gauge(
@@ -291,15 +304,27 @@ class ServeEngine:
     """Continuous-batching server over a paged KV pool (module docstring
     has the admission/eviction contract). Any family whose
     ``decode_view()`` gives one ``LayerSpec`` a layer with FFN kinds that
-    work row by row (Llama, GPT, EXAONE-MoE): the layers themselves are
-    ``models/decoder_stack.py``'s.
+    work row by row: the layers themselves are
+    ``models/decoder_stack.py``'s, and what the engine reads of a layer
+    is its spec (the mixer's kind, the window), never its family.
 
-    Two kinds of cache: a full-attention layer's pool is the block table
-    the docstring describes (``num_blocks`` counts its blocks); a
-    sliding-window layer keeps a RING of ``ceil(window / block_size) + 1``
-    blocks a slot in a pool of its own (``window_pool``), taken with the
-    slot and given back with it, whatever the stream's length. A ring
-    cannot be shared, so ``prefix_cache`` is refused for such a model.
+    Three kinds of per-slot state: a full-attention layer's pool is the
+    block table the docstring describes (``num_blocks`` counts its
+    blocks); a sliding-window layer keeps a RING of ``ceil(window /
+    block_size) + 1`` blocks a slot in a pool of its own
+    (``window_pool``), taken with the slot and given back with it,
+    whatever the stream's length; a state-space (``mamba2``) layer keeps
+    one row a slot of a recurrent state (``heads x dh x n`` numbers, in
+    ``ops/pallas/ssm_decode.state_shape``'s layout) and of its
+    convolution's last inputs ``[taps - 1, max_slots, channels]``
+    (the taps lead, so that the channels fill the lanes and no tile is
+    padded), in the model's type, of a fixed size whatever the stream's
+    length. A slot brings its row: it is overwritten by the prompt's
+    prefill (a chunked scan from a zero state), moved on in place by
+    each decode program for the rows that decode, left as it lies when
+    the stream goes (a preempted stream's is rebuilt from its tokens).
+    Neither a ring nor a recurrent state can be shared, so
+    ``prefix_cache`` is refused for such models.
 
     Usage::
 
@@ -336,11 +361,14 @@ class ServeEngine:
         if not all(_stack.FFN_KINDS[s.ffn].per_row for s in self._specs):
             # (a capacity computed over the call's tokens makes a stream
             # depend on what it is batched with)
+            kinds = sorted({s.ffn for s in self._specs
+                            if not _stack.FFN_KINDS[s.ffn].per_row})
             raise NotImplementedError(
-                "ServeEngine supports the Llama and GPT families and "
-                "EXAONE-MoE (the paged-decode surface); capacity-padded "
-                "MoE models decode on the dense path — got "
-                f"{type(model).__name__}")
+                f"ServeEngine batches streams that do not know of each "
+                f"other, so every FFN kind has to work row by row; "
+                f"{type(model).__name__} has {kinds} (a capacity "
+                f"computed over a call's tokens): such a model decodes "
+                f"on the dense path, through generate()")
         #: the layers whose FFN is sparse (their group sizes come back
         #: from a decode step in this order)
         self._sparse = [i for i, s in enumerate(self._specs)
@@ -351,6 +379,9 @@ class ServeEngine:
                 f"one window size an engine, got {sorted(windows)}")
         #: the sliding layers' window, or None where every layer is full
         self.window = windows.pop() if windows else None
+        #: the state-space layers (each keeps a state row a slot)
+        self._mamba = [i for i, s in enumerate(self._specs)
+                       if s.mixer == "mamba2"]
         max_pos = p.get("max_positions")
         if max_pos is not None and max_seq_len > max_pos:
             raise ValueError(
@@ -385,15 +416,46 @@ class ServeEngine:
                         and not isinstance(v, list)}
         self._arrays = {k: v for k, v in p.items() if k not in self._static}
         self._nh, self._nkv, self._dh = p["nh"], p["nkv"], p["dh"]
+        #: what the scores are multiplied by
+        self._scale = p.get("attn_scale", self._dh ** -0.5)
         self._dtype = p["embed"].dtype
+        #: K/V heads that share a pool row's 128 lanes. The kernels move
+        #: whole lane tiles (Mosaic refuses a slice of 64 of 128 lanes,
+        #: and a pool padded to them would be twice its bytes), so heads
+        #: narrower than a tile lie side by side, ``pack`` of them a row:
+        #: the pool is ``[kvh / pack, blocks, block_size, pack * dh]``,
+        #: a new row is its heads reshaped, and a query is laid into its
+        #: key-value head's lanes with zeros in the others (so that the
+        #: product over the row is its own head's) and reads its head's
+        #: lanes of the result. The jnp reference has no tiles to fill.
+        lanes = 128
+        pack = lanes // self._dh if lanes % self._dh == 0 else 1
+        self._pack = pack if (self.attention_backend != "reference"
+                              and self._nkv % pack == 0) else 1
         import jax.numpy as jnp
 
-        def pool_of(spec):
-            pool = self.pool if spec.window is None else self.window_pool
-            return jnp.zeros((self._nkv, pool.num_blocks, self.block_size,
-                              self._dh), self._dtype)
+        def cache_of(spec):
+            if spec.mixer == "mamba2":
+                from ..ops.pallas.ssm_decode import state_shape
 
-        self._caches = [(pool_of(s), pool_of(s)) for s in self._specs]
+                st = p["ssm"]
+                return (jnp.zeros((st["taps"] - 1, self.max_slots,
+                                   st["channels"]), self._dtype),
+                        jnp.zeros(state_shape(self.max_slots, st["heads"],
+                                              st["dh"], st["n"]),
+                                  self._dtype))
+            pool = self.pool if spec.window is None else self.window_pool
+            shape = (self._nkv // self._pack, pool.num_blocks,
+                     self.block_size, self._dh * self._pack)
+            return jnp.zeros(shape, self._dtype), jnp.zeros(shape,
+                                                            self._dtype)
+
+        #: one pair a layer: (K, V) pools, or (tail, state) by slot
+        self._caches = [cache_of(s) for s in self._specs]
+        if self._mamba:
+            _M_SSM_STATE_BYTES.set(
+                sum(a.nbytes for i in self._mamba for a in self._caches[i]),
+                engine=self.name)
 
         # host-side slot state (jit DATA — shapes never change)
         self._slots: List[Optional[Request]] = [None] * self.max_slots
@@ -413,11 +475,15 @@ class ServeEngine:
             prefix_cache = os.environ.get(
                 "PADDLE_TPU_PREFIX_CACHE", "").strip().lower() in (
                     "1", "true", "yes", "on")
-        if prefix_cache and self.window is not None:
+        if prefix_cache and (self.window is not None or self._mamba):
             raise NotImplementedError(
                 "prefix_cache with sliding-window layers: a slot's ring "
                 "of window blocks is overwritten as the stream grows and "
-                "cannot be shared between streams")
+                "cannot be shared between streams" if not self._mamba else
+                "prefix_cache with state-space layers: a slot's recurrent "
+                "state is overwritten at every token and holds no copy of "
+                "what it was at a block's end, so a prefix's blocks "
+                "cannot be mounted without the state that goes with them")
         self._prefix: Optional[PrefixCache] = (
             PrefixCache(self.block_size) if prefix_cache else None)
         if decode_burst is None:
@@ -612,6 +678,10 @@ class ServeEngine:
                                    ("window", self.window_pool)):
                     _M_POOL_OCCUPANCY.set(round(pool.occupancy, 4),
                                           engine=self.name, kind=kind)
+            if self._mamba:
+                _M_POOL_OCCUPANCY.set(
+                    round(self.n_active / self.max_slots, 4),
+                    engine=self.name, kind="state")
             _M_BATCH_FILL.set(round(n_active / self.max_slots, 4),
                               engine=self.name)
             if serving_real_work:
@@ -664,7 +734,8 @@ class ServeEngine:
     def _table_args(self, slot: Optional[int] = None) -> tuple:
         """The block tables as a compiled step takes them: the full
         layers' table and, where layers have a window, the rings; one
-        slot's rows of each for a prefill."""
+        slot's rows of each for a prefill, and then, where layers keep a
+        recurrent state, the slot itself."""
         import jax.numpy as jnp
 
         # copies (``jnp.array``), not views: on the CPU ``jnp.asarray`` may
@@ -675,6 +746,9 @@ class ServeEngine:
         tables = (jnp.array(pick(self._tables)),)
         if self.window is not None:
             tables += (jnp.array(pick(self._rings)),)
+        if self._mamba and slot is not None:
+            # the row of the state arrays a prefill writes
+            tables += (jnp.int32(slot),)
         return tables
 
     def _free_slot(self) -> Optional[int]:
@@ -818,6 +892,8 @@ class ServeEngine:
             padded = np.zeros((1, bucket), np.int32)
             padded[0, :n] = suffix
             req.prefilled_tokens += n
+            if self._mamba:
+                _M_SSM_PREFILL_TOKENS.inc(n, engine=self.name)
             if start == 0:
                 self._caches, logits = self._prefill_fn(
                     self._arrays, self._caches, jnp.asarray(padded),
@@ -1148,6 +1224,11 @@ class ServeEngine:
             _M_PAGES_LIVE.inc(int((self._lens[active] // self.block_size
                                    + 1).sum()), engine=self.name)
             _M_PAGES_TABLE.inc(self._tables.size, engine=self.name)
+            if self._mamba:
+                _M_SSM_ROWS_LIVE.inc(int(active.sum()) * len(self._mamba),
+                                     engine=self.name)
+                _M_SSM_ROWS_TABLE.inc(self.max_slots * len(self._mamba),
+                                      engine=self.name)
         return rows
 
     def _decode_done(self, start: float, wait, emit, n: int = 1):
@@ -1330,6 +1411,9 @@ class ServeEngine:
 
         from ..ops.pallas.kv_write import kv_write
 
+        if self._pack > 1:
+            k_new, v_new = (a.reshape(-1, kc.shape[0], kc.shape[3])
+                            for a in (k_new, v_new))
         if spec.window is None:
             safe_slot = slots["full"]
         elif fresh:
@@ -1341,6 +1425,31 @@ class ServeEngine:
         kw = dict(slots=safe_slot, rows_start_blocks=fresh,
                   backend=self.attention_backend)
         return kv_write(kc, k_new, **kw), kv_write(vc, v_new, **kw)
+
+    def _paged_attn(self, q, kc, vc, lengths, tables, **window):
+        """``paged_attention_decode`` of ``q`` [rows, nh, dh] over a
+        layer's pool, as [rows, nh * dh]; where heads are packed
+        (``_pack``) each query goes in, and its result comes out, through
+        its key-value head's lanes of the pool's row."""
+        import jax.numpy as jnp
+
+        from ..ops.pallas.paged_attention import paged_attention_decode
+
+        rows, nh, dh = q.shape
+        if self._pack > 1:
+            # head h reads key-value head h // group, which lies in
+            # lanes [(kv % pack) * dh, +dh) of row kv // pack
+            at = (np.arange(nh) // (nh // self._nkv)) % self._pack
+            mine = jnp.asarray(at[:, None] == np.arange(self._pack),
+                               q.dtype)[None, :, :, None]   # [1,nh,pack,1]
+            q = (q[:, :, None, :] * mine).reshape(rows, nh, -1)
+        out = paged_attention_decode(
+            q, kc, vc, lengths, tables, sm_scale=self._scale,
+            backend=self.attention_backend, **window)
+        if self._pack > 1:
+            out = jnp.sum(out.reshape(rows, nh, self._pack, dh) * mine,
+                          axis=2)
+        return out.reshape(rows, nh * dh)
 
     def _full_slots(self, table, positions, written):
         """Flat pool slot of each position through a full layer's block
@@ -1413,8 +1522,6 @@ class ServeEngine:
         import jax
         import jax.numpy as jnp
 
-        from ..ops.pallas.paged_attention import paged_attention_decode
-
         if p is None:
             p = {**arrays, **self._static}
         b = self.max_slots
@@ -1432,18 +1539,25 @@ class ServeEngine:
 
         def attn(_i, spec, q, _k, _v, kc, vc):
             if spec.window is None:
-                return paged_attention_decode(
-                    q, kc, vc, lengths, table,
-                    backend=self.attention_backend).reshape(
-                        b, nh * self._dh)
-            return paged_attention_decode(
-                q, kc, vc, lengths, ring[0], starts=starts, ring=True,
-                backend=self.attention_backend).reshape(b, nh * self._dh)
+                return self._paged_attn(q, kc, vc, lengths, table)
+            return self._paged_attn(q, kc, vc, lengths, ring[0],
+                                    starts=starts, ring=True)
+
+        def ssm(i, _spec, lp, xbc, dt, cache):
+            """One recurrent step a slot that decodes; the other slots'
+            tails and states stay as they lie."""
+            from ..ops.ssm import mamba2_step
+
+            y, tail, state = mamba2_step(
+                lp, p["ssm"], xbc, dt, *cache, active,
+                backend=self.attention_backend, scope=f"layer{i}/ssm")
+            return y, (tail, state)
 
         self._count_kv_write(b)
         out, new_caches, moe_sizes = _stack.stack_layers(
             p, x, rope, caches, partial(self._scatter_kv, slots, False),
-            attn, valid=active, backend=self.attention_backend)
+            attn, ssm=ssm, valid=active,
+            backend=self.attention_backend)
         with jax.named_scope("head"):
             logits = _stack.head_logits(p, out).astype(jnp.float32)  # [B, V]
         with jax.named_scope("sample"):
@@ -1512,6 +1626,8 @@ class ServeEngine:
         nh, kvh, dh = self._nh, self._nkv, self._dh
         bs = self.block_size
         group = nh // kvh
+        if self._mamba:
+            *table_rows, slot = table_rows
         table_row, *ring_row = table_rows
 
         positions = jnp.arange(tp, dtype=jnp.int32)
@@ -1542,7 +1658,7 @@ class ServeEngine:
 
                 out, _ = _flash_fwd_bhsd(
                     *(a.transpose(1, 0, 2)[None] for a in (q, k, v)),
-                    causal=True, scale=dh ** -0.5, window=spec.window,
+                    causal=True, scale=self._scale, window=spec.window,
                     interpret=self.attention_backend == "interpret")
                 return out[0].transpose(1, 0, 2).reshape(tp, nh * dh)
             seen = causal
@@ -1557,17 +1673,38 @@ class ServeEngine:
             v_rep = jnp.repeat(v, group, axis=1) if group > 1 else v
             scores = jnp.einsum(
                 "qhd,khd->hqk", q.astype(jnp.float32),
-                k_rep.astype(jnp.float32)) * (dh ** -0.5)
+                k_rep.astype(jnp.float32)) * self._scale
             scores = jnp.where(seen[None], scores, -jnp.inf)
             probs = jax.nn.softmax(scores, axis=-1)
             return jnp.einsum(
                 "hqk,khd->qhd", probs,
                 v_rep.astype(jnp.float32)).reshape(tp, nh * dh)
 
+        def ssm(i, _spec, lp, xbc, dt, cache):
+            """The prompt's chunked scan from a zero state; what its last
+            real token leaves overwrites the slot's row."""
+            from jax import lax
+
+            from ..ops.ssm import mamba2_prefill
+
+            y, tail, state = mamba2_prefill(lp, p["ssm"], xbc, dt, n,
+                                            scope=f"layer{i}/ssm")
+            tails, states = cache
+            zero = jnp.int32(0)
+            with jax.named_scope(f"layer{i}/ssm/scan"):
+                tails = lax.dynamic_update_slice(
+                    tails, tail[:, None, :].astype(tails.dtype),
+                    (zero, slot, zero))
+                states = lax.dynamic_update_slice(
+                    states, state[None].astype(states.dtype),
+                    (slot, zero, zero, zero))
+            return y, (tails, states)
+
         self._count_kv_write(tp, fresh=True)
         out, new_caches, _ = _stack.stack_layers(
             p, x, rope, caches, partial(self._scatter_kv, slots, True),
-            attn, valid=valid, backend=self.attention_backend)
+            attn, ssm=ssm, valid=valid,
+            backend=self.attention_backend)
         with jax.named_scope("head"):
             h_last = jnp.take(out, n - 1, axis=0)          # [H]
             logits = _stack.head_logits(p, h_last[None, :])[0]
@@ -1586,8 +1723,6 @@ class ServeEngine:
         compiles once per pow2 suffix bucket."""
         import jax
         import jax.numpy as jnp
-
-        from ..ops.pallas.paged_attention import paged_attention_decode
 
         self.prefill_traces += 1
         _M_PREFILL_TRACES.inc(engine=self.name,
@@ -1609,9 +1744,7 @@ class ServeEngine:
             table_row[None, :], (tp, table_row.shape[0]))
 
         def attn(_i, _spec, q, _k, _v, kc, vc):
-            return paged_attention_decode(
-                q, kc, vc, lengths, tables_rep,
-                backend=self.attention_backend).reshape(tp, nh * dh)
+            return self._paged_attn(q, kc, vc, lengths, tables_rep)
 
         self._count_kv_write(tp)
         out, new_caches, _ = _stack.stack_layers(

@@ -21,6 +21,8 @@ from paddle_tpu.models import decoder_stack as ds
 from paddle_tpu.models.exaone_moe import (ExaoneMoeConfig,
                                           ExaoneMoeForCausalLM)
 from paddle_tpu.models.generation import _cached_forward, _decode_family
+from paddle_tpu.models.granite_hybrid import (GraniteHybridConfig,
+                                              GraniteHybridForCausalLM)
 from paddle_tpu.serve import ServeEngine
 
 T = 13          # longer than EXAONE's window of 8, not a block multiple
@@ -162,9 +164,112 @@ def test_ffn_kinds_are_looked_up():
     ernie = ErnieMoeForCausalLM(ErnieMoeConfig.tiny(moe_layer_interval=2))
     ernie.eval()
     kinds["ernie"] = {s.ffn for s in ernie.decode_view()["specs"]}
+    granite = GraniteHybridForCausalLM(GraniteHybridConfig.tiny())
+    kinds["granite"] = {s.ffn for s in granite.decode_view()["specs"]}
     assert kinds == {"gpt": {"gelu"}, "llama": {"swiglu"},
                      "exaone": {"swiglu", "moe"},
-                     "ernie": {"swiglu", "capacity_moe"}}
+                     "ernie": {"swiglu", "capacity_moe"},
+                     "granite": {"swiglu_fused"}}
     assert set().union(*kinds.values()) == set(ds.FFN_KINDS)
     assert [k for k, v in ds.FFN_KINDS.items() if not v.per_row] == [
         "capacity_moe"]
+
+
+def test_a_mamba2_layer_without_its_closure_is_refused_by_name():
+    """The state of a state-space layer is the caller's cache: a caller
+    that hands `stack_layers` no `ssm` closure is told which layer wants
+    it, and `generate()`'s dense-cache forward is such a caller."""
+    paddle.seed(5)
+    model = GraniteHybridForCausalLM(GraniteHybridConfig.tiny())
+    model.eval()
+    p = _decode_family(model)
+    assert [s.mixer for s in ds.specs_of(p)] == [
+        "mamba2", "attention", "mamba2", "mamba2"]
+    ids = jnp.arange(1, 6)
+    x, rope = ds.embed(p, ids, jnp.arange(5), 5)
+    assert rope is None                      # no position encoding at all
+    with pytest.raises(NotImplementedError, match="layer 0 is a `mamba2`"):
+        ds.stack_layers(p, x, rope, [(None, None)] * 4,
+                        lambda *a: a[2:4], lambda *a: a[2])
+    caches = [(jnp.zeros((1, 5, p["nkv"], p["dh"]), jnp.float32),) * 2
+              for _ in p["layers"]]
+    with pytest.raises(NotImplementedError, match="no `ssm` closure"):
+        _cached_forward(p, ids[None], caches, 0, 5)
+
+
+def test_the_scalings_default_to_one_and_are_then_not_applied():
+    """A view without the four scalings traces no multiplication for
+    them (the other families' programs do not change); a view with them
+    is scaled where the family's equations say."""
+    model, ids = _build("llama")
+    p = _decode_family(model)
+    assert not {"embedding_multiplier", "residual_multiplier",
+                "logits_scaling", "attn_scale"} & set(p)
+    base = _dense_last_logits(p, ids)
+    np.testing.assert_array_equal(
+        _dense_last_logits({**p, "embedding_multiplier": 1,
+                            "residual_multiplier": 1, "logits_scaling": 1},
+                           ids), base)
+    np.testing.assert_allclose(
+        _dense_last_logits({**p, "logits_scaling": 8}, ids), base / 8,
+        rtol=1e-6)
+    moved = _dense_last_logits({**p, "residual_multiplier": 0.22}, ids)
+    assert np.abs(moved - base).max() > 1e-3
+
+
+#: sha256 (first 16 hex digits) of the lowered text of `ServeEngine`'s
+#: programs for `tools/lowered_programs.py`'s small GPT, Llama and
+#: EXAONE-MoE engines (this host's CPU, `reference` backend, float32), as
+#: the commit before PR 31 lowers them: what PRs 27 and 29 compared by
+#: hand. A PR that means to change these programs writes the new hashes
+#: here and says why; one that adds a family or a kind of cache leaves
+#: them as they are.
+LOWERED_SHA256 = {
+    "exaone.burst.2": "7ab6fb9b22d20a40",
+    "exaone.decode": "f457cf96a089b529",
+    "exaone.prefill.128": "050f3afbdbac148c",
+    "exaone.prefill.8": "46833fff9c78955a",
+    "gpt.burst.2": "533d8644acd3b6b7",
+    "gpt.decode": "662778d932df4f4c",
+    "gpt.prefill.128": "a4bf86cc5f106c94",
+    "gpt.prefill.8": "beb847ac637ae0b2",
+    "llama.burst.2": "b78f3154f74f971e",
+    "llama.decode": "353f783501c32468",
+    "llama.prefill.128": "6c55b7bb1c93e5f7",
+    "llama.prefill.8": "3aa24102ab49f3c7",
+}
+
+
+@pytest.fixture(scope="module")
+def lowered_texts():
+    """{"<family>.<program>": lowered text} of the tool's CPU engines."""
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools", "lowered_programs.py")
+    spec = importlib.util.spec_from_file_location("lowered_programs", path)
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    out = {}
+    for name, model in tool.models("float32").items():
+        eng = ServeEngine(model, max_slots=8, block_size=128, num_blocks=16,
+                          max_seq_len=512, prefix_cache=name != "exaone",
+                          name=f"lowered-test-{name}", trace=False,
+                          slo=False)
+        for prog, low in eng.lowered(prompt_lens=(8, 100),
+                                     bursts=(2,)).items():
+            out[f"{name}.{prog}"] = tool.strip_kernel_locations(
+                low.as_text())
+    return out
+
+
+@pytest.mark.parametrize("program", sorted(LOWERED_SHA256))
+def test_other_families_programs_lower_to_the_stored_text(lowered_texts,
+                                                          program):
+    import hashlib
+
+    got = hashlib.sha256(lowered_texts[program].encode()).hexdigest()[:16]
+    assert got == LOWERED_SHA256[program], (
+        f"{program} no longer lowers to the text the parent commit "
+        f"lowered it to (tools/lowered_programs.py dumps both for a diff)")

@@ -338,6 +338,93 @@ def test_exaone_engine_programs_copy_no_pool(topo):
             assert kernel in hlo, (name, kernel)
 
 
+def test_ssm_decode_compiles_in_place(topo):
+    """The recurrent step at the Granite cell's state, 96 slots of 64
+    heads of [64, 128] in bfloat16 (laid out two heads a lane row,
+    ``[96, 32, 128, 128]``): Mosaic takes the kernel (the row ids in SMEM
+    driving the block index maps), the donated state is aliased to the
+    result and no ``copy`` of its size is in the compiled text."""
+    from paddle_tpu.ops.pallas.ssm_decode import (ssm_decode_kernel,
+                                                  state_shape)
+
+    assert state_shape(96, 64, 64, 128) == (96, 32, 128, 128)
+    state = _on(topo, (96, 32, 128, 128), BF16)
+    lowered = jax.jit(ssm_decode_kernel, donate_argnums=(0,)).lower(
+        state, _on(topo, (96, 64, 64), BF16),
+        _on(topo, (96, 64), jnp.float32), _on(topo, (64,), jnp.float32),
+        _on(topo, (96, 128), BF16), _on(topo, (96, 128), BF16),
+        _on(topo, (96,), jnp.bool_))
+    text = lowered.as_text()
+    assert "tpu_custom_call" in text and "ssm_decode" in text
+    hlo = lowered.compile().as_text()
+    assert not _pool_sized_copies(hlo, state.shape)
+    assert 0 in _aliased_params(hlo)
+
+
+def test_flash_forward_heads_of_64_compiles(topo):
+    """The causal forward of a prompt's attention at head size 64, 32
+    query heads over 8 key-value heads (half a lane tile a head), at the
+    largest bucket of the Granite cell."""
+    from paddle_tpu.ops.pallas.flash_attention import _flash_fwd_bhsd
+
+    def fn(q, k, v):
+        return _flash_fwd_bhsd(q, k, v, causal=True, scale=1 / 64)
+
+    text, _ = _compile(fn, _on(topo, (1, 32, 4096, 64), BF16),
+                       _on(topo, (1, 8, 4096, 64), BF16),
+                       _on(topo, (1, 8, 4096, 64), BF16))
+    assert "tpu_custom_call" in text and "flash_fwd" in text
+
+
+def test_heads_of_64_do_not_slice_a_lane_tile(topo):
+    """Why the engine packs two heads of 64 into a pool row: written as
+    they come, ``[8, blocks, 128, 64]``, the pool is padded to 128 lanes
+    on the device and Mosaic refuses ``kv_write``'s slice of 64 of them."""
+    from paddle_tpu.ops.pallas.kv_write import kv_write_kernel
+
+    with pytest.raises(Exception, match="aligned to tiling"):
+        _compile(kv_write_kernel, _on(topo, (8, 64, 128, 64), BF16),
+                 _on(topo, (96, 8, 64), BF16), _on(topo, (96,), jnp.int32))
+
+
+def test_granite_engine_programs_keep_state_and_pools_in_place(topo):
+    """Three layers of Granite 4.0-H at the published widths and the
+    cell's geometry (a Mamba-2 layer, an attention layer with heads of 64,
+    a Mamba-2 layer): the attention layer's pools hold two heads a row
+    (``[4, 1536, 128, 128]``: 1.6 GB over the model's four such layers
+    where ``[8, 1536, 128, 64]`` would be padded to 3.2), the decode step
+    and the 64 and 1,024 prefill buckets hold no ``copy`` the size of a
+    state or shaped like a pool, every donated cache is aliased to its
+    result, and the kernels are there by name."""
+    from paddle_tpu.models.granite_hybrid import (GraniteHybridConfig,
+                                                  GraniteHybridForCausalLM)
+    from paddle_tpu.serve import ServeEngine
+
+    model = GraniteHybridForCausalLM(GraniteHybridConfig(
+        num_hidden_layers=3, layer_types=("mamba", "attention", "mamba"),
+        dtype="bfloat16", deferred_init=True))
+    model.eval()
+    eng = ServeEngine(model, max_slots=96, block_size=128, num_blocks=1536,
+                      max_seq_len=4096, name="aot-granite", trace=False,
+                      slo=False)
+    assert eng.attention_backend == "kernel" and eng._pack == 2
+    state, tail, pool = (96, 32, 128, 128), (3, 96, 4352), (4, 1536, 128, 128)
+    assert [tuple(a.shape for a in c) for c in eng._caches] == [
+        (tail, state), (pool, pool), (tail, state)]
+    lowered = eng.lowered(prompt_lens=(64, 1024), device=topo.devices[0])
+    n_arrays = len(jax.tree.leaves(eng._arrays))
+    caches = set(range(n_arrays, n_arrays + 6))
+    for name, low in lowered.items():
+        hlo = low.compile().as_text()
+        assert not _pool_sized_copies(hlo, state), name
+        assert not _copies_shaped_like(hlo, pool), name
+        assert caches <= _aliased_params(hlo), name
+        for kernel in ("kv_write",) + (
+                ("ssm_decode", "paged_decode") if name == "decode"
+                else ("flash_fwd",)):
+            assert kernel in hlo, (name, kernel)
+
+
 class _TopoMesh:
     """The slice of ``ProcessMesh`` a KernelPartition needs, over
     compile-only devices (a ProcessMesh indexes ``jax.devices()``)."""

@@ -73,15 +73,17 @@ def join(programs, texts):
 
     module = {k: re.match(r"\s*HloModule ([\w.\-]+)", t).group(1)
               for k, t in texts.items()}
+    # the instructions each text defines, found once: a 40-layer program
+    # holds thousands, and a search of every text for each took hours
+    defined = {k: set(re.findall(r"^\s*(?:ROOT )?%?([\w.\-]+) = ", t, re.M))
+               for k, t in texts.items()}
     out = {}
     for program, secs in sorted(programs.items(),
                                 key=lambda kv: -sum(kv[1].values())):
         # a prefill program is told from the other buckets' by its
         # instructions: the text that holds most of the traced seconds
-        held = {k: sum(s for i, s in secs.items()
-                       if re.search(rf"^\s*(ROOT )?%?{re.escape(i)} = ", t,
-                                    re.M))
-                for k, t in texts.items() if program.startswith(module[k])}
+        held = {k: sum(s for i, s in secs.items() if i in defined[k])
+                for k in texts if program.startswith(module[k])}
         if not held:
             continue
         text = texts[max(held, key=held.get)]
