@@ -54,6 +54,17 @@ Scheduling policy (the contract the tests pin):
   (``serve.pipeline_drains``): a preemption, a fused burst, a step with
   nothing to decode. There is no other order: with nothing in flight
   the same code is the sequential loop.
+- **A dispatch sends the device only what changed.** A decode program
+  hands back the next program's slot state (its tokens, every active
+  length one on, the masks) and the sampling key split once, and both
+  stay on the device: while the same streams decode, a step builds and
+  uploads nothing for them. The block tables (and rings) and the
+  temperatures are kept there from their last upload and go up again
+  only once the scheduler has written them. A step on which a stream
+  came, went or got its first token, and the first after a drain or a
+  burst, sends the host's mirrors afresh, which stay whole throughout
+  (``serve.decode_uploads{what=state|tables|temps}`` over
+  ``serve.decode_steps``: how often each went up).
 
 What a decoder layer IS belongs to the model families: every compiled
 step here runs ``models/decoder_stack.stack_layers`` over the view the
@@ -169,6 +180,12 @@ _M_DECODE_OVERLAPPED = obs.counter(
     "serve.decode_overlapped", "decode programs dispatched while the one "
     "before was still unread: the host's work of that step ran behind "
     "the device (over serve.decode_steps: how often the pipeline holds)")
+_M_DECODE_UPLOADS = obs.counter(
+    "serve.decode_uploads", "arrays a decode dispatch sent up because the "
+    "device's copy no longer said what the host's does, by what (state: "
+    "the slots' tokens, lengths and masks; tables: the block tables and "
+    "rings; temps: the temperatures); 1 - this over serve.decode_steps is "
+    "the share of programs that found the device's copy good")
 _M_PIPELINE_DRAINS = obs.counter(
     "serve.pipeline_drains", "times the decode program in flight was "
     "read before the next could be dispatched, by reason (preempt: the "
@@ -298,6 +315,8 @@ class _Program:
     sizes: object                # the sparse layers' group sizes, flat
     reqs: List[Optional[Request]]   # by slot: whose row it computes
     start: float                 # when its dispatch began
+    state: object                # the slot state it leaves, on the device
+    active: np.ndarray           # [max_slots] bool: the rows it computes
 
 
 class ServeEngine:
@@ -523,6 +542,9 @@ class ServeEngine:
         self._first_tokens: List[tuple] = []
         # what a program takes for "the tokens before" with none in flight
         self._no_tokens = jnp.zeros(self.max_slots, jnp.int32)
+        # by what (tables, temps): the host arrays as a decode dispatch
+        # last sent them, and the device's copies (``_resident``)
+        self._sent: dict = {}
         # the caches are DONATED (argument 1 after the bound self):
         # the engine replaces self._caches with the returned pool every
         # call, so in-place aliasing is safe — and without it every
@@ -738,18 +760,47 @@ class ServeEngine:
         recurrent state, the slot itself."""
         import jax.numpy as jnp
 
-        # copies (``jnp.array``), not views: on the CPU ``jnp.asarray`` may
-        # alias an aligned numpy buffer, and the scheduler writes these
-        # tables in place between steps (a served stream then read
-        # another's ring, one run in three under load)
-        pick = (lambda a: a) if slot is None else (lambda a: a[slot])
-        tables = (jnp.array(pick(self._tables)),)
-        if self.window is not None:
-            tables += (jnp.array(pick(self._rings)),)
+        # a private numpy copy made NOW goes up, never the live table: on
+        # the CPU ``jnp.asarray`` may alias an aligned numpy buffer, and
+        # ``jnp.array`` is a program that reads the (aliased) buffer when
+        # the device gets to it, while the scheduler writes these tables
+        # in place between steps: a slot cleared straight after its
+        # prefill went out (a preemption) sent that prefill's rows
+        # through a zeroed table into another stream's block
+        hosts = self._host_tables()
+        if slot is not None:
+            hosts = tuple(a[slot] for a in hosts)
+        tables = tuple(jnp.asarray(a.copy()) for a in hosts)
         if self._mamba and slot is not None:
             # the row of the state arrays a prefill writes
             tables += (jnp.int32(slot),)
         return tables
+
+    def _host_tables(self) -> tuple:
+        """The scheduler's own block table and, where layers have a
+        window, rings (numpy, written in place)."""
+        return ((self._tables,) if self.window is None
+                else (self._tables, self._rings))
+
+    def _resident(self, what: str, *hosts) -> tuple:
+        """The device's copies of the numpy arrays ``hosts`` for a decode
+        dispatch: those of the last upload while the host's still say
+        what was sent, fresh ones (and one count of
+        ``serve.decode_uploads{what=}``) once they differ. What was sent
+        is kept and compared, microseconds for these sizes, so no writer
+        of the tables has to remember to say so; and it is that private
+        copy which goes up, for ``_table_args``'s reason: the device's
+        copy must not follow the scheduler's later writes."""
+        import jax.numpy as jnp
+
+        kept = self._sent.get(what)
+        if kept is None or not all(np.array_equal(h, s)
+                                   for h, s in zip(hosts, kept[0])):
+            sent = tuple(h.copy() for h in hosts)
+            kept = self._sent[what] = (sent,
+                                       tuple(jnp.asarray(c) for c in sent))
+            _M_DECODE_UPLOADS.inc(engine=self.name, what=what)
+        return kept[1]
 
     def _free_slot(self) -> Optional[int]:
         for i, r in enumerate(self._slots):
@@ -902,7 +953,7 @@ class ServeEngine:
                 self._caches, logits = self._suffix_prefill_fn(
                     self._arrays, self._caches, jnp.asarray(padded),
                     jnp.int32(n), jnp.int32(start),
-                    jnp.asarray(self._tables[req.slot]))
+                    *self._table_args(req.slot))
             # the blocks this prefill fills are matchable from here on:
             # whoever mounts them runs after it (a later request of this
             # very admission pass shares them, as it always could)
@@ -1142,33 +1193,45 @@ class ServeEngine:
 
     def _dispatch_decode(self, rows, prev: Optional[_Program]) -> _Program:
         """One decode program for ``rows`` (by slot, the stream or
-        None). A row whose last token ``prev`` is still making takes it
-        from ``prev``'s output on the device; the others take the
-        host's. The slot state goes up as fresh arrays: the host writes
-        its own on while the program runs."""
-        import jax
+        None), handed only what the device does not hold already. The
+        slot state: a program leaves the next one's on the device
+        (its tokens, each length one on, its rows as both masks), and
+        where ``rows`` are ``prev``'s, stream for stream, that IS this
+        program's state: nothing is built and nothing goes up. Any
+        other step (a stream came, went, or got its first token; nothing
+        in flight after a drain or a burst) sends the host's mirrors
+        afresh: a row whose last token ``prev`` is still making takes it
+        from ``prev``'s output, the others the host's. Tables and
+        temperatures go up when they were written (``_resident``); the
+        sampling key lives on the device and the program splits it."""
         import jax.numpy as jnp
 
         with self._span("serve.decode.dispatch", burst=1) as dispatch:
-            active = np.array([r is not None for r in rows], bool)
-            fed = np.array([r is not None and prev is not None
-                            and prev.reqs[slot] is r
-                            for slot, r in enumerate(rows)], bool)
-            state = np.stack([self._tokens, self._lens, active, fed],
-                             dtype=np.int32)
-            self._key, sub = jax.random.split(self._key)
-            nxt, sizes, self._caches = self._decode_fn(
+            if prev is not None and all(
+                    r is was for r, was in zip(rows, prev.reqs)):
+                state, active = prev.state, prev.active
+            else:
+                active = np.array([r is not None for r in rows], bool)
+                fed = np.array([r is not None and prev is not None
+                                and prev.reqs[slot] is r
+                                for slot, r in enumerate(rows)], bool)
+                state = jnp.asarray(np.stack(
+                    [self._tokens, self._lens, active, fed],
+                    dtype=np.int32))
+                _M_DECODE_UPLOADS.inc(engine=self.name, what="state")
+            nxt, sizes, self._caches, state, self._key = self._decode_fn(
                 self._arrays, self._caches,
-                self._no_tokens if prev is None else prev.nxt,
-                jnp.asarray(state), self._table_args(),
-                jnp.array(self._temps), sub)
+                self._no_tokens if prev is None else prev.nxt, state,
+                self._resident("tables", *self._host_tables()),
+                *self._resident("temps", self._temps), self._key)
             for out in (nxt, sizes):
                 out.copy_to_host_async()
             self._lens[active] += 1
         self._dispatched(dispatch)
         if prev is not None:
             _M_DECODE_OVERLAPPED.inc(engine=self.name)
-        return _Program(nxt, sizes, list(rows), dispatch.start)
+        return _Program(nxt, sizes, list(rows), dispatch.start, state,
+                        active)
 
     def _dispatched(self, dispatch, n: int = 1):
         """Count one dispatch of ``n`` decode ticks."""
@@ -1495,11 +1558,16 @@ class ServeEngine:
         ``serve.decode_traces``). The caches are DONATED: the pool
         updates in place instead of being copied per token. ``state``
         is the slots' tokens, lengths, active mask and ``fed`` mask in
-        one int32 array (one upload); a ``fed`` row's token is
-        ``prev``'s, the output of the program before as it lies on the
-        device, which the host may not have read yet. A model with
+        one int32 array; a ``fed`` row's token is ``prev``'s, the output
+        of the program before as it lies on the device, which the host
+        may not have read yet. The program hands back the state of the
+        one after it (its tokens, each active length one on, every
+        active row ``fed``), which stays on the device for as long as
+        the same streams decode, and ``key`` split once: the host's
+        ``jax.random.split`` chain, a link a program. A model with
         sparse layers hands their held experts' group sizes back beside
         the tokens (none for a dense model)."""
+        import jax
         import jax.numpy as jnp
 
         # executes at TRACE time only — the flatness counter the e2e
@@ -1507,12 +1575,15 @@ class ServeEngine:
         self.decode_traces += 1
         _M_DECODE_TRACES.inc(engine=self.name)
         tokens, lens, active, fed = state
+        key, sub = jax.random.split(key)
         nxt, new_caches, moe_sizes = self._decode_core(
             caches, jnp.where(fed != 0, prev, tokens), lens, active != 0,
-            tables, temps, key, arrays=arrays)
+            tables, temps, sub, arrays=arrays)
         sizes = (jnp.stack(moe_sizes).reshape(-1).astype(jnp.int32)
                  if moe_sizes else jnp.zeros(0, jnp.int32))
-        return nxt, sizes, new_caches
+        state = jnp.stack([nxt.astype(jnp.int32), lens + active, active,
+                           active])
+        return nxt, sizes, new_caches, state, key
 
     def _decode_core(self, caches, tokens, lens, active, tables, temps,
                      key, *, arrays=None, p=None):

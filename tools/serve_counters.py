@@ -10,8 +10,11 @@ result line is still the last line of standard output. The counters (the
 registry's series under the cell's name, which the harness gives its
 engine: warm-up, ramp, window and drain together) go to standard error as
 one JSON object: decode programs dispatched, how many of them went out
-while the one before was unread, the pipeline's drains by reason,
-preemptions. A checkout from before a counter has it prints null there.
+while the one before was unread, the share of them that found the device's
+copy of the slot state, of the tables and of the temperatures good and
+sent none up (`clean_share`: 1 - `serve.decode_uploads{what}` over the
+programs), the pipeline's drains by reason, preemptions. A checkout from
+before a counter has it prints null there.
 Then, from the engine's step ring (its last 4,096 steps), the five longest
 steps with their seconds by phase and the five longest gaps between two
 steps (the caller's time): where a one-off stall of seconds lies (ROADMAP
@@ -34,11 +37,17 @@ def counters(engine: str) -> dict:
 
     steps = value("serve.decode_steps")
     overlapped = value("serve.decode_overlapped")
+    uploads = {w: value("serve.decode_uploads", what=w)
+               for w in ("state", "tables", "temps")}
     return {
         "engine": engine, "decode_steps": steps,
         "decode_overlapped": overlapped,
         "overlapped_share": (overlapped / steps
                              if steps and overlapped is not None else None),
+        "decode_uploads": uploads,
+        "clean_share": {w: (round(1 - n / steps, 4)
+                            if steps and n is not None else None)
+                        for w, n in uploads.items()},
         "pipeline_drains": {r: value("serve.pipeline_drains", reason=r)
                             for r in ("preempt", "burst", "idle")},
         "preemptions": value("serve.preemptions", reason="pool_exhausted"),
