@@ -10,9 +10,13 @@ RoPE, FFN kind) and is handed what legitimately differs between its
 callers, and nothing else: ``write_kv`` (how new K/V rows enter a layer's
 cache), ``attn`` (how a row attends) and, for a state-space layer,
 ``ssm`` (the convolution and the recurrence over the caller's cached
-state). A view may also carry four scalings, each 1 (or ``dh ** -0.5``)
+state) and, for a latent-attention layer, ``mla`` (the latent rows into
+the caller's cache and attention over them). A view may also carry four
+scalings, each 1 (or ``dh ** -0.5``)
 where it is absent and then not applied: ``embedding_multiplier``,
-``residual_multiplier``, ``logits_scaling`` and ``attn_scale``. The
+``residual_multiplier``, ``logits_scaling`` and ``attn_scale``; and
+``rope_dim`` / ``rope_scaling`` where the rotated dims are not a whole
+head's or their frequencies are YaRN's. The
 callers are ``serve.ServeEngine``'s compiled
 steps (a paged pool, ``ops/pallas`` kernels) and
 ``models/generation._cached_forward`` (a dense cache, a masked softmax);
@@ -21,7 +25,9 @@ module's, a new family's leaves are its own ``decode_view``.
 
 The ``jax.named_scope`` names here (``layer<i>/qkv|scatter_kv|attn|out|
 ffn``, ``layer<i>/moe/...``, ``layer<i>/ssm/in_proj|conv|scan|gate_norm|
-out``, ``final_norm``) are what the compiled
+out``, ``layer<i>/mla/q|kv|scatter_latent|attn|out`` (and, by the caller,
+``/expand`` in a prefill, ``/absorb_q|absorb_o`` in a decode step),
+``final_norm``) are what the compiled
 steps' op metadata, XProf, ``profiler.scope_seconds`` and
 ``tools/scope_breakdown.py`` name device time by.
 """
@@ -46,7 +52,8 @@ class LayerSpec(NamedTuple):
     ffn: str = "swiglu"            # a key of FFN_KINDS
     #: what mixes the tokens: "attention" (everything above ``ffn``
     #: describes it) | "mamba2" (a state-space layer: pre-norm, its
-    #: sizes in the view's ``ssm`` statics)
+    #: sizes in the view's ``ssm`` statics) | "mla" (latent attention:
+    #: pre-norm, RMSNorm, its sizes in the view's ``mla`` statics)
     mixer: str = "attention"
 
 
@@ -179,10 +186,53 @@ def rope_rows(p, pos, s_max):
 
     from ..incubate.nn.functional import _rope_tables
 
-    cos_full, sin_full = _rope_tables(s_max, p["dh"], p["theta"], True,
-                                      jnp.float32)
+    if p.get("rope_scaling"):
+        cos_full, sin_full = yarn_tables(s_max, p["rope_dim"], p["theta"],
+                                         **p["rope_scaling"])
+    else:
+        cos_full, sin_full = _rope_tables(
+            s_max, p.get("rope_dim", p["dh"]), p["theta"], True,
+            jnp.float32)
     return (jnp.take(cos_full, pos, axis=0)[:, None, :],
             jnp.take(sin_full, pos, axis=0)[:, None, :])
+
+
+def yarn_inv_freq(dim, theta, *, factor, beta_fast, beta_slow, original):
+    """[dim / 2] float32 rotation frequencies under YaRN (Peng et al.,
+    2023, as ``transformers``' ``DeepseekV2YarnRotaryEmbedding`` has it):
+    pair ``i`` turns at ``theta ** (-2 i / dim)``, divided by ``factor``
+    for the pairs that make fewer than ``beta_slow`` turns in ``original``
+    positions, left as it is for those that make more than ``beta_fast``,
+    and blended by a linear ramp between the two (its ends rounded
+    outwards to whole pairs)."""
+    import math
+
+    import jax.numpy as jnp
+
+    def pair_of(turns):
+        return dim * math.log(original / (turns * 2 * math.pi)) / (
+            2 * math.log(theta))
+
+    low = max(math.floor(pair_of(beta_fast)), 0)
+    high = min(math.ceil(pair_of(beta_slow)), dim - 1)
+    plain = theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    return plain / factor * ramp + plain * (1.0 - ramp)
+
+
+def yarn_tables(s, dim, theta, *, factor, beta_fast, beta_slow, original,
+                mscale=1.0):
+    """(cos, sin) ``[s, dim]`` float32, half-split as ``_rope_tables``
+    gives them, at YaRN's frequencies and times ``mscale`` (the ratio of
+    the config's two ``yarn_get_mscale``: 1 where they are equal)."""
+    import jax.numpy as jnp
+
+    inv = yarn_inv_freq(dim, theta, factor=factor, beta_fast=beta_fast,
+                        beta_slow=beta_slow, original=original)
+    freqs = jnp.outer(jnp.arange(s, dtype=jnp.float32), inv)
+    emb = jnp.concatenate([freqs, freqs], axis=-1)
+    return jnp.cos(emb) * mscale, jnp.sin(emb) * mscale
 
 
 def rotate(q, k, cos, sin, dtype):
@@ -261,6 +311,16 @@ def _attention_mixer(i, spec, p, lp, x, cache, rope, write_kv, attn,
     return x, (kc, vc)
 
 
+def _no_closure(i, mixer, name, keeps):
+    """A mixer whose cache only its caller can keep, and a caller that
+    handed over no closure for it."""
+    raise NotImplementedError(
+        f"layer {i} is a `{mixer}` mixer: {keeps}, and this caller handed "
+        f"stack_layers no `{name}` closure (generate()'s dense cache keeps "
+        "per-head keys and values alone: such a model is served through "
+        "ServeEngine)")
+
+
 def _mamba2_mixer(i, spec, p, lp, x, cache, ssm, norm, scaled):
     """Layer ``i``'s state-space sub-layer (Mamba-2; ``ops/ssm.py`` has
     the equations): norm, ``[z | xBC | dt] = h W_in``, the caller's
@@ -271,12 +331,8 @@ def _mamba2_mixer(i, spec, p, lp, x, cache, ssm, norm, scaled):
     import jax.numpy as jnp
 
     if ssm is None:
-        raise NotImplementedError(
-            f"layer {i} is a `mamba2` mixer: its convolution tail and "
-            "recurrent state are the caller's cache, and this caller "
-            "handed stack_layers no `ssm` closure (generate()'s dense "
-            "cache keeps none: such a model is served through "
-            "ServeEngine)")
+        _no_closure(i, "mamba2", "ssm", "its convolution tail and recurrent "
+                    "state are the caller's cache")
     if spec.placement != "pre":
         raise ValueError("a `mamba2` mixer is pre-norm")
     st = p["ssm"]
@@ -297,7 +353,42 @@ def _mamba2_mixer(i, spec, p, lp, x, cache, ssm, norm, scaled):
     return x, cache
 
 
-def stack_layers(p, x, rope, caches, write_kv, attn, *, ssm=None,
+def _mla_mixer(i, spec, p, lp, x, cache, rope, mla, norm, scaled):
+    """Layer ``i``'s latent-attention sub-layer (``ops/mla.py`` has the
+    equations): the low-rank query ``RMSNorm(h W_qa) W_qb`` split a head
+    into ``[q_nope | q_pe]``, the joint compression ``[c_kv | k_pe] = h
+    W_kva`` with ``c = RMSNorm(c_kv)``, RoPE on ``q_pe`` and the one
+    ``k_pe``, the row ``[c | k_pe]`` and the queries to the caller's
+    cache and attention, the output projection."""
+    import jax
+    import jax.numpy as jnp
+
+    if mla is None:
+        _no_closure(i, "mla", "mla", "its cache is one latent row a token, "
+                    "the caller's to keep")
+    if spec.placement != "pre" or spec.norm != "rms":
+        raise ValueError("a `mla` mixer is pre-norm with RMSNorm")
+    st = p["mla"]
+    rows = x.shape[0]
+    dtype, eps = p["embed"].dtype, p["eps"]
+    scope = jax.named_scope
+    with scope(f"layer{i}/mla/q"):
+        h = norm(spec, x, lp, "ln1")
+        q = (rms(h @ lp["wqa"], lp["qan"], eps, dtype) @ lp["wqb"]).reshape(
+            rows, p["nh"], st["nope"] + st["rope"])
+        q_nope, q_pe = q[:, :, :st["nope"]], q[:, :, st["nope"]:]
+    with scope(f"layer{i}/mla/kv"):
+        ckv = h @ lp["wkva"]
+        c = rms(ckv[:, :st["rank"]], lp["kvan"], eps, dtype)
+        q_pe, k_pe = rotate(q_pe, ckv[:, None, st["rank"]:], *rope, dtype)
+        latent = jnp.concatenate([c, k_pe[:, 0]], axis=-1)
+    ctx, cache = mla(i, spec, lp, q_nope, q_pe, latent, cache)
+    with scope(f"layer{i}/mla/out"):
+        x = x + scaled(ctx.astype(dtype) @ lp["wo"])
+    return x, cache
+
+
+def stack_layers(p, x, rope, caches, write_kv, attn, *, ssm=None, mla=None,
                  valid=None, backend="auto"):
     """ONE decoder stack for every cached decode path, read off each
     layer's ``LayerSpec``: norm and projection, q/k norm, rope, the new
@@ -313,7 +404,11 @@ def stack_layers(p, x, rope, caches, write_kv, attn, *, ssm=None,
     cache as written; ``ssm(i, spec, lp, xbc, dt, cache) -> (y [rows,
     heads * dh] float32, cache)`` runs the layer's convolution and its
     recurrence over the rows (under the scopes ``layer<i>/ssm/conv`` and
-    ``/scan``): they are all that the callers differ in. ``valid``
+    ``/scan``); ``mla(i, spec, lp, q_nope, q_pe, latent, cache) -> (ctx
+    [rows, nh * v], cache)`` writes a latent layer's new rows ``latent``
+    ``[rows, rank + rope]`` into the caller's cache and attends (under
+    ``layer<i>/mla/scatter_latent`` and ``/attn``): they are all that the
+    callers differ in. ``valid``
     marks the rows that are tokens (a sparse layer routes the others
     nowhere) and ``backend`` is ``moe_ffn``'s. Returns (normed hidden
     [rows, H], new caches, the held experts' group sizes of each sparse
@@ -347,6 +442,9 @@ def stack_layers(p, x, rope, caches, write_kv, attn, *, ssm=None,
         if spec.mixer == "mamba2":
             x, cache = _mamba2_mixer(i, spec, p, lp, x, cache, ssm, norm,
                                      scaled)
+        elif spec.mixer == "mla":
+            x, cache = _mla_mixer(i, spec, p, lp, x, cache, rope, mla, norm,
+                                  scaled)
         else:
             x, cache = _attention_mixer(i, spec, p, lp, x, cache, rope,
                                         write_kv, attn, norm, scaled)

@@ -135,20 +135,48 @@ class ExaoneMoeConfig:
 
 
 # --- the mathematics, on arrays (model forward and serving stack alike) ----
-def route(h, router_w, bias, *, top_k, scale, norm_topk=True):
+def route(h, router_w, bias, *, top_k, scale, norm_topk=True,
+          scoring="sigmoid", n_group=1, topk_group=1):
     """(weights [T, top_k] float32, experts [T, top_k] int32) of the
-    sigmoid router, in float32 whatever ``h`` is."""
+    router, in float32 whatever ``h`` is. ``scoring``: ``"sigmoid"``
+    scores (this family) or a ``"softmax"`` over the experts
+    (``models/deepseek_v2.py``); ``bias`` (or None) picks but does not
+    weigh. With ``n_group > 1`` the selection is group-limited: the
+    experts lie in ``n_group`` groups side by side, a group scores as
+    its best expert, the ``topk_group`` best groups are kept, the others'
+    scores are zeroed, and the ``top_k`` are chosen of what is left."""
+    return _route(h, router_w, bias, top_k=top_k, scale=scale,
+                  norm_topk=norm_topk, scoring=scoring, n_group=n_group,
+                  topk_group=topk_group)[:2]
+
+
+def _route(h, router_w, bias, *, top_k, scale, norm_topk, scoring, n_group,
+           topk_group):
+    """:func:`route` and, third, the groups a token keeps ``[T, n_group]``
+    bool (None for a router with one group)."""
     import jax
     import jax.numpy as jnp
 
-    s = jax.nn.sigmoid(jnp.dot(
+    if scoring not in ("sigmoid", "softmax"):
+        raise ValueError(f"route: unknown scoring {scoring!r}")
+    logits = jnp.dot(
         h.astype(jnp.float32), router_w.astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST))
-    _, experts = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+        precision=jax.lax.Precision.HIGHEST)
+    s = (jax.nn.sigmoid(logits) if scoring == "sigmoid"
+         else jax.nn.softmax(logits, axis=-1))
+    pick = s if bias is None else s + bias.astype(jnp.float32)
+    kept = None
+    if n_group > 1:
+        t, e = pick.shape
+        best = jnp.max(pick.reshape(t, n_group, e // n_group), axis=-1)
+        _, groups = jax.lax.top_k(best, topk_group)
+        kept = jnp.any(groups[:, :, None] == jnp.arange(n_group), axis=1)
+        pick = jnp.where(jnp.repeat(kept, e // n_group, axis=1), pick, 0.0)
+    _, experts = jax.lax.top_k(pick, top_k)
     w = jnp.take_along_axis(s, experts, axis=-1)
     if norm_topk:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
-    return w * scale, experts.astype(jnp.int32)
+    return w * scale, experts.astype(jnp.int32), kept
 
 
 def moe_ffn(h, lp, st, dtype, *, valid=None, backend="auto", scope=None):
@@ -156,9 +184,13 @@ def moe_ffn(h, lp, st, dtype, *, valid=None, backend="auto", scope=None):
     experts' part of the routed sum and the shared expert. ``lp`` holds
     ``router`` [H, E], ``router_bias`` [E], ``gate_up`` [count, H, 2 I],
     ``down`` [count, I, H] and the shared expert's ``wg``/``wu``/``wd``;
-    ``st`` the statics (``top_k``, ``scale``, ``norm_topk``, ``first``).
+    ``st`` the statics (``top_k``, ``scale``, ``norm_topk``, ``first``
+    and, where they are not this family's, ``scoring``, ``n_group``,
+    ``topk_group``); a router without ``router_bias`` has none.
     Rows where ``valid`` is false (idle slots, a bucket's padding) are
-    routed nowhere. Returns (``[T, H]``, held group sizes ``[count]``)."""
+    routed nowhere. Returns (``[T, H]``, held group sizes ``[count]``); a
+    group-limited router's sizes are one number longer: the tokens whose
+    kept groups include one that holds a held expert."""
     import jax
     import jax.numpy as jnp
 
@@ -176,14 +208,27 @@ def moe_ffn(h, lp, st, dtype, *, valid=None, backend="auto", scope=None):
         return out.reshape(t, -1), jnp.sum(sizes, axis=0)
     named = scoped(scope)
     with named("router"):
-        w, experts = route(h, lp["router"], lp["router_bias"],
-                           top_k=st["top_k"], scale=st["scale"],
-                           norm_topk=st["norm_topk"])
+        w, experts, kept = _route(
+            h, lp["router"], lp.get("router_bias"), top_k=st["top_k"],
+            scale=st["scale"], norm_topk=st["norm_topk"],
+            scoring=st.get("scoring", "sigmoid"),
+            n_group=st.get("n_group", 1), topk_group=st.get("topk_group", 1))
         if valid is not None:
             experts = jnp.where(valid[:, None], experts, -1)
     routed, sizes = experts_ffn(h, w, experts, lp["gate_up"], lp["down"],
                                 first=st["first"], backend=backend,
                                 scope=scope)
+    if kept is not None:
+        with named("router"):
+            # the groups that hold a held expert: a static slice
+            per = lp["router"].shape[1] // kept.shape[1]
+            last = st["first"] + lp["gate_up"].shape[0] - 1
+            here = jnp.any(kept[:, st["first"] // per:last // per + 1],
+                           axis=1)
+            if valid is not None:
+                here &= valid
+            sizes = jnp.concatenate(
+                [sizes, jnp.sum(here, dtype=sizes.dtype)[None]])
     with named("shared"):
         shared = swiglu_ffn(h, lp, dtype)
     return (routed + shared.astype(jnp.float32)).astype(dtype), sizes

@@ -260,13 +260,15 @@ def _split_extras(extras, has_bias, rate, shard_axes):
 
 def _fwd_local(q, k, v, *extras, causal, scale, rate, has_bias, interpret,
                shard_axes=(), window=None):
-    """One shard's forward: q [B,H,Sq,D]; k,v [B,Hkv,Sk,D] ->
-    (out [B,H,Sq,D], lse [B,H,Sq]). ``window`` (with ``causal``): a
-    query sees its last ``window`` keys only; key blocks outside that
-    band are neither computed nor fetched (forward only)."""
+    """One shard's forward: q [B,H,Sq,D]; k [B,Hkv,Sk,D]; v
+    [B,Hkv,Sk,Dv] -> (out [B,H,Sq,Dv], lse [B,H,Sq]). The value head may
+    be another size than the query-key head (a latent-attention prefill:
+    192 and 128). ``window`` (with ``causal``): a query sees its last
+    ``window`` keys only; key blocks outside that band are neither
+    computed nor fetched (forward only)."""
     key_bias, seed = _split_extras(extras, has_bias, rate, shard_axes)
     B, H, Sq, D = q.shape
-    Hkv, Sk = k.shape[1], k.shape[2]
+    Hkv, Sk, Dv = k.shape[1], k.shape[2], v.shape[3]
     g = H // Hkv
     block_q = _pick_block(Sq)
     block_k = _pick_block(Sk)
@@ -294,7 +296,7 @@ def _fwd_local(q, k, v, *extras, causal, scale, rate, has_bias, interpret,
     in_specs = [
             pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, Z)),
             pl.BlockSpec((1, 1, block_k, D), kv_map),
-            pl.BlockSpec((1, 1, block_k, D), kv_map),
+            pl.BlockSpec((1, 1, block_k, Dv), kv_map),
     ]
     inputs = [q, k, v]
     if key_bias is not None:
@@ -316,25 +318,25 @@ def _fwd_local(q, k, v, *extras, causal, scale, rate, has_bias, interpret,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, Z)),
+            pl.BlockSpec((1, 1, block_q, Dv), lambda b, h, i, j: (b, h, i, Z)),
             pl.BlockSpec((1, 1, block_q, LANES),
                          lambda b, h, i, j: (b, h, i, Z)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype),
+            jax.ShapeDtypeStruct((B, H, Sq, Dv), q.dtype),
             jax.ShapeDtypeStruct((B, H, Sq, LANES), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, D), jnp.float32),
+            pltpu.VMEM((block_q, Dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(
-            flops=4 * B * H * Sq * Sk * D,
+            flops=2 * B * H * Sq * Sk * (D + Dv),
             bytes_accessed=(q.size + k.size + v.size) * q.dtype.itemsize,
             transcendentals=B * H * Sq * Sk,
         ),
@@ -361,7 +363,8 @@ def _flash_fwd_jit(q, k, v, seed, key_bias, *, causal, scale, dropout_rate,
 def _flash_fwd_bhsd(q, k, v, seed=None, key_bias=None, *, causal, scale,
                     dropout_rate=0.0, partition=None, window=None,
                     interpret=None):
-    """q: [B,H,Sq,D]; k,v: [B,Hkv,Sk,D] -> (out [B,H,Sq,D], lse [B,H,Sq]).
+    """q: [B,H,Sq,D]; k: [B,Hkv,Sk,D]; v: [B,Hkv,Sk,Dv] -> (out
+    [B,H,Sq,Dv], lse [B,H,Sq]); Dv may differ from D (forward only).
     seed: int32 [1] dropout seed, required when dropout_rate > 0.
     key_bias: [B, Sk] additive logit bias broadcast over heads/rows (the
     padding-mask pattern), added BEFORE the causal mask/softmax.

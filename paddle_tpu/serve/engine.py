@@ -75,7 +75,8 @@ CACHE and the SCHEDULE: it hands the stack ``write_kv`` (new K/V rows
 into the paged pool, ``ops/pallas/kv_write``) and ``attn`` (through
 ``ops/pallas/paged_attention.paged_attention_decode`` — the decode-
 specialized Pallas kernel on TPU, its jnp gather reference on CPU — or,
-in a cold prefill, causal attention within the prompt).
+in a cold prefill, causal attention within the prompt), and for the
+layers that keep another kind of cache ``ssm`` and ``mla``.
 
 Telemetry: the ``serve.`` metric subsystem (claimed in
 ``observability.metrics.CLAIMED_SUBSYSTEMS``, label discipline audited
@@ -154,6 +155,16 @@ _M_SSM_PREFILL_TOKENS = obs.counter(
 _M_SSM_STATE_BYTES = obs.gauge(
     "serve.ssm_state_bytes", "bytes the recurrent state and convolution "
     "tails of all slots and state-space layers hold on the device")
+_M_LATENT_BYTES = obs.gauge(
+    "serve.latent_cache_bytes", "bytes the latent layers' pools hold on the "
+    "device (one row a token and layer, padded to whole lane tiles)")
+_M_LATENT_ROWS = obs.counter(
+    "serve.latent_rows_written", "latent rows written into the pools: a "
+    "prefill's tokens and a decode step's rows, times the latent layers")
+_M_MLA_CTX = obs.counter(
+    "serve.mla_ctx_tokens", "sum over decode steps of the decoding "
+    "streams' lengths, the step's own token counted: the rows mla_decode "
+    "reads in each latent layer")
 _M_BATCH_FILL = obs.gauge(
     "serve.batch_fill", "active streams / max_slots at the last step")
 _M_TOKENS_PER_SEC = obs.gauge(
@@ -228,6 +239,10 @@ _M_MOE_ROUTED = obs.counter(
 _M_MOE_HELD = obs.counter(
     "serve.moe_assignments_held", "of those tokens' top-k assignments, "
     "the ones that fell on an expert this engine holds")
+_M_MOE_HELD_GROUP = obs.counter(
+    "serve.moe_tokens_to_held_group", "of the tokens routed by a "
+    "group-limited router, those whose kept groups include one this "
+    "engine holds experts of (tokens x sparse layers)")
 _M_MOE_MAX = obs.counter(
     "serve.moe_expert_tokens_max", "a decode step's largest held "
     "expert's tokens, summed over steps, by sparse layer")
@@ -327,7 +342,7 @@ class ServeEngine:
     ``models/decoder_stack.py``'s, and what the engine reads of a layer
     is its spec (the mixer's kind, the window), never its family.
 
-    Three kinds of per-slot state: a full-attention layer's pool is the
+    Four kinds of per-layer cache: a full-attention layer's pool is the
     block table the docstring describes (``num_blocks`` counts its
     blocks); a sliding-window layer keeps a RING of ``ceil(window /
     block_size) + 1`` blocks a slot in a pool of its own
@@ -343,7 +358,17 @@ class ServeEngine:
     each decode program for the rows that decode, left as it lies when
     the stream goes (a preempted stream's is rebuilt from its tokens).
     Neither a ring nor a recurrent state can be shared, so
-    ``prefix_cache`` is refused for such models.
+    ``prefix_cache`` is refused for such models. A latent-attention
+    (``mla``) layer keeps ONE pool of one row a token, ``[1, num_blocks,
+    block_size, lanes]`` (the row ``[c | k_pe]`` padded with zeros to whole
+    128-lane tiles: ``ops/mla.py:row_lanes``), under the full layers'
+    block table and everything the scheduler does with it: a latent block
+    is admitted, shared by the prefix cache, copied on write, preempted
+    and released like any block. A prompt attends with its own rows
+    expanded to per-head keys and values (the flash forward, a query-key
+    head of ``nope + rope`` and a value head of ``v``); every row that is
+    read from the pool is read as it lies, by ``ops/pallas/mla_decode``
+    with the expansion absorbed into the query.
 
     Usage::
 
@@ -401,6 +426,9 @@ class ServeEngine:
         #: the state-space layers (each keeps a state row a slot)
         self._mamba = [i for i, s in enumerate(self._specs)
                        if s.mixer == "mamba2"]
+        #: the latent-attention layers (each keeps one pool of rows)
+        self._mla = [i for i, s in enumerate(self._specs)
+                     if s.mixer == "mla"]
         max_pos = p.get("max_positions")
         if max_pos is not None and max_seq_len > max_pos:
             raise ValueError(
@@ -463,14 +491,24 @@ class ServeEngine:
                         jnp.zeros(state_shape(self.max_slots, st["heads"],
                                               st["dh"], st["n"]),
                                   self._dtype))
+            if spec.mixer == "mla":
+                from ..ops.mla import row_lanes
+
+                return (jnp.zeros((1, self.pool.num_blocks, self.block_size,
+                                   row_lanes(p["mla"])), self._dtype),)
             pool = self.pool if spec.window is None else self.window_pool
             shape = (self._nkv // self._pack, pool.num_blocks,
                      self.block_size, self._dh * self._pack)
             return jnp.zeros(shape, self._dtype), jnp.zeros(shape,
                                                             self._dtype)
 
-        #: one pair a layer: (K, V) pools, or (tail, state) by slot
+        #: one tuple a layer: (K, V) pools, (tail, state) by slot, or
+        #: the one pool of latent rows
         self._caches = [cache_of(s) for s in self._specs]
+        if self._mla:
+            _M_LATENT_BYTES.set(
+                sum(a.nbytes for i in self._mla for a in self._caches[i]),
+                engine=self.name)
         if self._mamba:
             _M_SSM_STATE_BYTES.set(
                 sum(a.nbytes for i in self._mamba for a in self._caches[i]),
@@ -945,6 +983,8 @@ class ServeEngine:
             req.prefilled_tokens += n
             if self._mamba:
                 _M_SSM_PREFILL_TOKENS.inc(n, engine=self.name)
+            if self._mla:
+                _M_LATENT_ROWS.inc(n * len(self._mla), engine=self.name)
             if start == 0:
                 self._caches, logits = self._prefill_fn(
                     self._arrays, self._caches, jnp.asarray(padded),
@@ -1266,6 +1306,12 @@ class ServeEngine:
         if not sizes.size:
             return
         sizes = sizes.reshape(len(self._sparse), -1)
+        count = self._static["moe"]["count"]
+        if sizes.shape[1] > count:
+            # a group-limited router's last number (models/exaone_moe.py)
+            _M_MOE_HELD_GROUP.inc(int(sizes[:, count:].sum()),
+                                  engine=self.name)
+            sizes = sizes[:, :count]
         _M_MOE_ROUTED.inc(n_tokens * len(self._sparse), engine=self.name)
         _M_MOE_HELD.inc(int(sizes.sum()), engine=self.name)
         for layer, row in zip(self._sparse, sizes):
@@ -1292,6 +1338,11 @@ class ServeEngine:
                                      engine=self.name)
                 _M_SSM_ROWS_TABLE.inc(self.max_slots * len(self._mamba),
                                       engine=self.name)
+            if self._mla:
+                _M_LATENT_ROWS.inc(int(active.sum()) * len(self._mla),
+                                   engine=self.name)
+                _M_MLA_CTX.inc(int((self._lens[active] + 1).sum()),
+                               engine=self.name)
         return rows
 
     def _decode_done(self, start: float, wait, emit, n: int = 1):
@@ -1514,6 +1565,53 @@ class ServeEngine:
                           axis=2)
         return out.reshape(rows, nh * dh)
 
+    def _scatter_latent(self, slots, fresh, pool, latent):
+        """Write the rows ``latent`` ``[rows, rank + rope]`` into a latent
+        layer's pool at ``slots["full"]``, padded with zeros to the pool
+        row's lanes: ``_scatter_kv`` for the one pool of a ``mla`` layer."""
+        import jax.numpy as jnp
+
+        from ..ops.pallas.kv_write import kv_write
+
+        pad = pool.shape[-1] - latent.shape[-1]
+        rows = jnp.pad(latent, ((0, 0), (0, pad)))[:, None, :]
+        return kv_write(pool, rows, slots=slots["full"],
+                        rows_start_blocks=fresh,
+                        backend=self.attention_backend)
+
+    def _latent_attn(self, i, lp, q_nope, q_pe, pool, lengths, tables):
+        """Attention of the rows' queries over a latent layer's pool as it
+        lies, ``[rows, nh * v]``: the expansion's key half folded into the
+        query, ``mla_decode`` over the rows, its value half applied to the
+        result. No cached token is expanded."""
+        import jax
+
+        from ..ops import mla as _mla
+        from ..ops.pallas.mla_decode import mla_decode
+
+        st = self._static["mla"]
+        with jax.named_scope(f"layer{i}/mla/absorb_q"):
+            q = _mla.absorb_q(lp, st, q_nope, q_pe, pool.shape[-1])
+        with jax.named_scope(f"layer{i}/mla/attn"):
+            out = mla_decode(q, pool, lengths, tables, dv=st["rank"],
+                             sm_scale=self._scale,
+                             backend=self.attention_backend)
+        with jax.named_scope(f"layer{i}/mla/absorb_o"):
+            return _mla.absorb_o(lp, st, out)
+
+    def _mla_cached(self, slots, lengths, tables):
+        """The ``mla`` closure of the steps whose rows attend through the
+        block table (a decode step, a suffix prefill)."""
+        import jax
+
+        def mla(i, _spec, lp, q_nope, q_pe, latent, cache):
+            with jax.named_scope(f"layer{i}/mla/scatter_latent"):
+                pool = self._scatter_latent(slots, False, cache[0], latent)
+            return self._latent_attn(i, lp, q_nope, q_pe, pool, lengths,
+                                     tables), (pool,)
+
+        return mla
+
     def _full_slots(self, table, positions, written):
         """Flat pool slot of each position through a full layer's block
         table (``table`` [rows, blocks] or one row); rows not
@@ -1628,6 +1726,7 @@ class ServeEngine:
         out, new_caches, moe_sizes = _stack.stack_layers(
             p, x, rope, caches, partial(self._scatter_kv, slots, False),
             attn, ssm=ssm, valid=active,
+            mla=self._mla_cached(slots, lengths, table),
             backend=self.attention_backend)
         with jax.named_scope("head"):
             logits = _stack.head_logits(p, out).astype(jnp.float32)  # [B, V]
@@ -1723,15 +1822,28 @@ class ServeEngine:
         flash = (p.get("prefill") == "flash"
                  and self.attention_backend != "reference")
 
+        def flash_fwd(q, k, v, window=None):
+            """[tp, heads, dv] through the flash forward kernel."""
+            from ..ops.pallas.flash_attention import _flash_fwd_bhsd
+
+            out, _ = _flash_fwd_bhsd(
+                *(a.transpose(1, 0, 2)[None] for a in (q, k, v)),
+                causal=True, scale=self._scale, window=window,
+                interpret=self.attention_backend == "interpret")
+            return out[0].transpose(1, 0, 2)
+
+        def softmax_attn(q, k, v, seen):
+            """[tp, heads, dv]: the float32 masked softmax."""
+            scores = jnp.einsum(
+                "qhd,khd->hqk", q.astype(jnp.float32),
+                k.astype(jnp.float32)) * self._scale
+            scores = jnp.where(seen[None], scores, -jnp.inf)
+            probs = jax.nn.softmax(scores, axis=-1)
+            return jnp.einsum("hqk,khd->qhd", probs, v.astype(jnp.float32))
+
         def attn(_i, spec, q, k, v, _kc, _vc):
             if flash:
-                from ..ops.pallas.flash_attention import _flash_fwd_bhsd
-
-                out, _ = _flash_fwd_bhsd(
-                    *(a.transpose(1, 0, 2)[None] for a in (q, k, v)),
-                    causal=True, scale=self._scale, window=spec.window,
-                    interpret=self.attention_backend == "interpret")
-                return out[0].transpose(1, 0, 2).reshape(tp, nh * dh)
+                return flash_fwd(q, k, v, spec.window).reshape(tp, nh * dh)
             seen = causal
             if spec.window is not None:
                 # (a pad row far past the prompt would see no key at
@@ -1742,14 +1854,22 @@ class ServeEngine:
                     positions[:, None] == positions[None, :])
             k_rep = jnp.repeat(k, group, axis=1) if group > 1 else k
             v_rep = jnp.repeat(v, group, axis=1) if group > 1 else v
-            scores = jnp.einsum(
-                "qhd,khd->hqk", q.astype(jnp.float32),
-                k_rep.astype(jnp.float32)) * self._scale
-            scores = jnp.where(seen[None], scores, -jnp.inf)
-            probs = jax.nn.softmax(scores, axis=-1)
-            return jnp.einsum(
-                "hqk,khd->qhd", probs,
-                v_rep.astype(jnp.float32)).reshape(tp, nh * dh)
+            return softmax_attn(q, k_rep, v_rep, seen).reshape(tp, nh * dh)
+
+        def mla(i, _spec, lp, q_nope, q_pe, latent, cache):
+            """The prompt's rows into the pool as they are, and causal
+            attention within the prompt with its own rows expanded."""
+            from ..ops import mla as _mla
+
+            with jax.named_scope(f"layer{i}/mla/scatter_latent"):
+                pool = self._scatter_latent(slots, True, cache[0], latent)
+            with jax.named_scope(f"layer{i}/mla/expand"):
+                k, v = _mla.expand(lp, p["mla"], latent)
+                q = jnp.concatenate([q_nope, q_pe], axis=-1)
+            with jax.named_scope(f"layer{i}/mla/attn"):
+                out = (flash_fwd(q, k, v) if flash
+                       else softmax_attn(q, k, v, causal))
+            return out.reshape(tp, -1), (pool,)
 
         def ssm(i, _spec, lp, xbc, dt, cache):
             """The prompt's chunked scan from a zero state; what its last
@@ -1774,7 +1894,7 @@ class ServeEngine:
         self._count_kv_write(tp, fresh=True)
         out, new_caches, _ = _stack.stack_layers(
             p, x, rope, caches, partial(self._scatter_kv, slots, True),
-            attn, ssm=ssm, valid=valid,
+            attn, ssm=ssm, mla=mla, valid=valid,
             backend=self.attention_backend)
         with jax.named_scope("head"):
             h_last = jnp.take(out, n - 1, axis=0)          # [H]
@@ -1820,17 +1940,19 @@ class ServeEngine:
         self._count_kv_write(tp)
         out, new_caches, _ = _stack.stack_layers(
             p, x, rope, caches, partial(self._scatter_kv, slots, False),
-            attn, valid=valid, backend=self.attention_backend)
+            attn, valid=valid,
+            mla=self._mla_cached(slots, lengths, tables_rep),
+            backend=self.attention_backend)
         with jax.named_scope("head"):
             h_last = jnp.take(out, n - 1, axis=0)          # [H]
             logits = _stack.head_logits(p, h_last[None, :])[0]
             return new_caches, logits.astype(jnp.float32)
 
     def _cow_impl(self, caches, src, dst):
-        """Copy-on-write: duplicate one physical block's K/V across
-        every layer into a private block, so a stream can diverge
-        inside a shared prefix block without mutating KV that other
-        streams are reading. src/dst are jit data — one trace ever."""
-        return [(kc.at[:, dst].set(kc[:, src]),
-                 vc.at[:, dst].set(vc[:, src]))
-                for kc, vc in caches]
+        """Copy-on-write: duplicate one physical block's K/V (or its
+        latent rows) across every layer into a private block, so a stream
+        can diverge inside a shared prefix block without mutating KV that
+        other streams are reading. src/dst are jit data — one trace
+        ever."""
+        return [tuple(c.at[:, dst].set(c[:, src]) for c in cache)
+                for cache in caches]

@@ -197,6 +197,31 @@ def test_a_mamba2_layer_without_its_closure_is_refused_by_name():
         _cached_forward(p, ids[None], caches, 0, 5)
 
 
+def test_a_mla_layer_without_its_closure_is_refused_by_name():
+    """A latent layer's cache is one row a token, which only the caller
+    knows how to keep: without the `mla` closure `stack_layers` says which
+    layer wants it, and `generate()`'s dense-cache forward is such a
+    caller."""
+    from paddle_tpu.models.deepseek_v2 import (DeepseekV2Config,
+                                               DeepseekV2ForCausalLM)
+
+    paddle.seed(5)
+    model = DeepseekV2ForCausalLM(DeepseekV2Config.tiny())
+    model.eval()
+    p = _decode_family(model)
+    assert {s.mixer for s in ds.specs_of(p)} == {"mla"}
+    ids = jnp.arange(1, 6)
+    x, rope = ds.embed(p, ids, jnp.arange(5), 5)
+    assert rope[0].shape == (5, 1, 8)        # the rotated dims alone
+    with pytest.raises(NotImplementedError, match="layer 0 is a `mla`"):
+        ds.stack_layers(p, x, rope, [(None,)] * 3,
+                        lambda *a: a[2:4], lambda *a: a[2])
+    caches = [(jnp.zeros((1, 5, p["nkv"], p["dh"]), jnp.float32),) * 2
+              for _ in p["layers"]]
+    with pytest.raises(NotImplementedError, match="no `mla` closure"):
+        _cached_forward(p, ids[None], caches, 0, 5)
+
+
 def test_the_scalings_default_to_one_and_are_then_not_applied():
     """A view without the four scalings traces no multiplication for
     them (the other families' programs do not change); a view with them
@@ -232,6 +257,11 @@ def test_the_scalings_default_to_one_and_are_then_not_applied():
 #: prefill and burst programs, which share `_decode_core` and the stack,
 #: keep their hashes).
 LOWERED_SHA256 = {
+    # PR 33's own family, held from its first commit on
+    "deepseek.burst.2": "ace2527bf2ca703c",
+    "deepseek.decode": "7c45e96695cf678a",
+    "deepseek.prefill.128": "3f33377f305c57cf",
+    "deepseek.prefill.8": "07ec600df57d040f",
     "exaone.burst.2": "7ab6fb9b22d20a40",
     "exaone.decode": "87330fbc3dd9577d",
     "exaone.prefill.128": "050f3afbdbac148c",

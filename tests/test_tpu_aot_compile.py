@@ -425,6 +425,120 @@ def test_granite_engine_programs_keep_state_and_pools_in_place(topo):
             assert kernel in hlo, (name, kernel)
 
 
+def test_mla_decode_compiles(topo):
+    """The latent decode kernel at the DeepSeek-V2 cell's shapes: 192
+    rows of 128 absorbed queries over a pool of 6,656 pages of 128 rows
+    in 640 lanes (a 576-wide row in five lane tiles), the value the first
+    512 lanes, four pages a turn."""
+    from paddle_tpu.ops.pallas.mla_decode import mla_decode_kernel
+
+    def fn(q, pool, lengths, tables):
+        return mla_decode_kernel(q, pool, lengths, tables, dv=512,
+                                 sm_scale=0.1147)
+
+    text, compiled = _compile(
+        fn, _on(topo, (192, 128, 640), BF16),
+        _on(topo, (1, 6656, 128, 640), BF16), _on(topo, (192,), jnp.int32),
+        _on(topo, (192, 64), jnp.int32))
+    assert "tpu_custom_call" in text and "mla_decode" in text
+    assert not _pool_sized_copies(compiled.as_text(), (1, 6656, 128, 640))
+
+
+@pytest.mark.parametrize("rows, fresh", [(192, False), (2048, True)])
+def test_latent_row_write_compiles_in_place(topo, rows, fresh):
+    """A decode step's 192 latent rows (the ``rows`` kernel) and a
+    prompt's 2,048 (whole blocks) into the 640-lane pool: aliased, no
+    pool-sized copy."""
+    from paddle_tpu.ops.pallas.kv_write import kv_write
+
+    pool = _on(topo, (1, 6656, 128, 640), BF16)
+    lowered = jax.jit(
+        lambda pool, new, slots: kv_write(pool, new, slots,
+                                          rows_start_blocks=fresh),
+        donate_argnums=(0,)).lower(
+            pool, _on(topo, (rows, 1, 640), BF16),
+            _on(topo, (rows,), jnp.int32))
+    hlo = lowered.compile().as_text()
+    assert not _pool_sized_copies(hlo, pool.shape)
+    assert 0 in _aliased_params(hlo)
+    assert ("tpu_custom_call" in hlo) == (not fresh)   # rows | blocks
+
+
+def test_a_latent_row_of_576_lanes_is_refused(topo):
+    """Why the engine pads a latent row to 640 lanes: a pool written as
+    the row comes, ``[1, blocks, 128, 576]``, lies in 640 lanes on the
+    device all the same (4.5 tiles round up), and Mosaic refuses both
+    kernels' slices of 576 of them."""
+    from paddle_tpu.ops.pallas.kv_write import kv_write_kernel
+    from paddle_tpu.ops.pallas.mla_decode import mla_decode_kernel
+
+    pool = _on(topo, (1, 6656, 128, 576), BF16)
+    with pytest.raises(Exception, match="aligned to tiling"):
+        _compile(kv_write_kernel, pool, _on(topo, (192, 1, 576), BF16),
+                 _on(topo, (192,), jnp.int32))
+    with pytest.raises(Exception, match="aligned to tiling"):
+        _compile(lambda q, pool, l, t: mla_decode_kernel(
+            q, pool, l, t, dv=512, sm_scale=0.1),
+            _on(topo, (192, 128, 576), BF16), pool,
+            _on(topo, (192,), jnp.int32), _on(topo, (192, 64), jnp.int32))
+
+
+@pytest.mark.parametrize("seq", [512, 8192])
+def test_flash_forward_two_head_sizes_compiles(topo, seq):
+    """The causal forward of a latent-attention prefill: 128 heads, a
+    query-key head of 192 and a value head of 128, at a short bucket and
+    the cell's largest."""
+    from paddle_tpu.ops.pallas.flash_attention import _flash_fwd_bhsd
+
+    def fn(q, k, v):
+        return _flash_fwd_bhsd(q, k, v, causal=True, scale=0.1147)[0]
+
+    text, compiled = _compile(fn, _on(topo, (1, 128, seq, 192), BF16),
+                              _on(topo, (1, 128, seq, 192), BF16),
+                              _on(topo, (1, 128, seq, 128), BF16))
+    assert "tpu_custom_call" in text and "flash_fwd" in text
+    assert jax.eval_shape(fn, *(jax.ShapeDtypeStruct(s, BF16) for s in (
+        (1, 128, seq, 192), (1, 128, seq, 192), (1, 128, seq, 128)))
+        ).shape == (1, 128, seq, 128)
+
+
+def test_deepseek_engine_programs_keep_the_latent_pools_in_place(topo):
+    """Two layers of DeepSeek-V2 at the published widths and the cell's
+    geometry (the dense layer and a sparse one holding routing group 0):
+    one pool a layer of 640-lane rows, the decode step and the 64 and
+    2,048 prefill buckets hold no ``copy`` shaped like a pool, alias both
+    donated pools, and run ``kv_write``, ``moe_experts`` and
+    ``mla_decode`` (decode) or ``flash_fwd`` (prefill); no program keeps
+    a per-head key or value of the pool's 6,656 blocks."""
+    from paddle_tpu.models.deepseek_v2 import (DeepseekV2Config,
+                                               DeepseekV2ForCausalLM)
+    from paddle_tpu.serve import ServeEngine
+
+    model = DeepseekV2ForCausalLM(DeepseekV2Config(
+        vocab_size=12800, num_hidden_layers=2, experts_held=(0, 20),
+        dtype="bfloat16", deferred_init=True))
+    model.eval()
+    eng = ServeEngine(model, max_slots=192, block_size=128, num_blocks=6656,
+                      max_seq_len=8192, name="aot-deepseek", trace=False,
+                      slo=False)
+    assert eng.attention_backend == "kernel"
+    pool = (1, 6656, 128, 640)
+    assert [tuple(a.shape for a in c) for c in eng._caches] == [(pool,)] * 2
+    lowered = eng.lowered(prompt_lens=(64, 2048), device=topo.devices[0])
+    n_arrays = len(jax.tree.leaves(eng._arrays))
+    caches = set(range(n_arrays, n_arrays + 2))
+    for name, low in lowered.items():
+        hlo = low.compile().as_text()
+        assert not _copies_shaped_like(hlo, pool), name
+        assert caches <= _aliased_params(hlo), name
+        for kernel in ("moe_experts",) + (
+                ("kv_write", "mla_decode") if name == "decode"
+                else ("flash_fwd",)):
+            assert kernel in hlo, (name, kernel)
+        assert not re.search(r"\[(?:\d+,)*6656,(?:\d+,)*128,(?:128|192|256)\]",
+                             hlo.replace("[1,6656,128,640]", "")), name
+
+
 class _TopoMesh:
     """The slice of ``ProcessMesh`` a KernelPartition needs, over
     compile-only devices (a ProcessMesh indexes ``jax.devices()``)."""

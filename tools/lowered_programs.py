@@ -2,12 +2,13 @@
 """Write the lowered text of ``ServeEngine``'s programs to a directory, to
 hold a refactoring to "the programs are the parent's".
 
-Small GPT, Llama and EXAONE-MoE engines; the decode step, three cold
+Small GPT, Llama, EXAONE-MoE and DeepSeek-V2 engines; the decode step, three cold
 prefill buckets, two bursts and (where the prefix cache applies) two
 suffix-prefill buckets and the copy-on-write: once for this host's CPU
 (``reference`` backend, float32) and once for a described compile-only
 ``TPU v5 lite`` (``kernel`` backend, bfloat16, as
-``tests/test_tpu_aot_compile.py`` builds one) — 48 programs. Needs no
+``tests/test_tpu_aot_compile.py`` builds one) — 66 programs (48 where
+the checkout has no ``models/deepseek_v2.py``). Needs no
 chip and runs nothing. To compare two checkouts, one after the other (two
 at once fight over libtpu's lock file)::
 
@@ -73,7 +74,21 @@ def models(dtype):
     if dtype != "float32":
         gpt.to(dtype=dtype)
         llama.to(dtype=dtype)
-    return {"gpt": gpt, "llama": llama, "exaone": exaone}
+    out = {"gpt": gpt, "llama": llama, "exaone": exaone}
+    try:      # a parent from before PR 33 has no such family
+        from paddle_tpu.models.deepseek_v2 import (DeepseekV2Config,
+                                                   DeepseekV2ForCausalLM)
+    except ImportError:
+        return out
+    # the published head (128 + 64 rotated, values of 128), a latent of
+    # 128: a row of 192 numbers in 256 lanes
+    out["deepseek"] = DeepseekV2ForCausalLM(DeepseekV2Config.tiny(
+        vocab_size=512, hidden_size=256, intermediate_size=512,
+        num_hidden_layers=2, num_attention_heads=2, q_lora_rank=128,
+        kv_lora_rank=128, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128, moe_intermediate_size=128, dtype=dtype))
+    out["deepseek"].eval()
+    return out
 
 
 def dump(out_dir, tag, dtype, device):

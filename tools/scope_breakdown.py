@@ -9,7 +9,11 @@ loader read; then the cell's program once more from the harness's builder,
 for the compiled texts (`ServeEngine.lowered()`, `StaticFunction.lowered()`).
 `paddle_tpu.profiler.scope_seconds` joins the two, for each traced program
 and for its `copy` and `fusion` instructions alone, layer indices folded
-(`layer*/scatter_kv`); the table goes to
+(`layer*/scatter_kv`; a latent-attention layer's are `layer*/mla/q`, `/kv`,
+`/scatter_latent`, `/attn`, `/out`, with `/absorb_q` and `/absorb_o` in a
+decode step and `/expand` in a prefill); for a serving cell the engine's
+own counters (`tools/serve_counters.py`'s) go into the file too; the table
+goes to
 `chiprun_out/scope_breakdown.<cell>.json` beside the traced run's own result
 line. Judges nothing.
 """
@@ -128,9 +132,15 @@ def main(argv=None):
     path = os.path.join(ROOT, "chiprun_out",
                         f"scope_breakdown.{args.workload}.json")
     os.makedirs(os.path.dirname(path), exist_ok=True)
+    report = {"workload": args.workload, "seed": args.seed, "line": line,
+              "programs": out}
+    if spec.Spec(args.workload).kind == "serve":
+        from tools.serve_counters import counters
+
+        report["counters"] = counters(args.workload)
+        print("\nserve counters: " + json.dumps(report["counters"]))
     with open(path, "w") as f:
-        json.dump({"workload": args.workload, "seed": args.seed,
-                   "line": line, "programs": out}, f, indent=1)
+        json.dump(report, f, indent=1)
     print(f"\nwritten {path}")
     return 0
 

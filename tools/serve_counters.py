@@ -13,8 +13,15 @@ one JSON object: decode programs dispatched, how many of them went out
 while the one before was unread, the share of them that found the device's
 copy of the slot state, of the tables and of the temperatures good and
 sent none up (`clean_share`: 1 - `serve.decode_uploads{what}` over the
-programs), the pipeline's drains by reason, preemptions. A checkout from
-before a counter has it prints null there.
+programs), the pipeline's drains by reason, preemptions; for a model with
+latent-attention layers the pools' bytes, the rows written into them and
+the rows `mla_decode` read (`serve.mla_ctx_tokens`, and their mean a
+decode step: the context the window really held); for a model with sparse
+layers the tokens routed, the assignments that fell on held experts and,
+under a group-limited router, the tokens whose kept groups include the
+held one (3/8 in expectation for one group of eight, three kept). A
+checkout from before a counter has it, and a model without such layers,
+prints null there.
 Then, from the engine's step ring (its last 4,096 steps), the five longest
 steps with their seconds by phase and the five longest gaps between two
 steps (the caller's time): where a one-off stall of seconds lies (ROADMAP
@@ -53,7 +60,35 @@ def counters(engine: str) -> dict:
         "preemptions": value("serve.preemptions", reason="pool_exhausted"),
         "requests_finished": value("serve.requests_finished",
                                    reason="max_new_tokens"),
+        "latent": latent(value, steps),
+        "moe": moe(value),
     }
+
+
+def _share(part, whole):
+    return round(part / whole, 4) if part is not None and whole else None
+
+
+def latent(value, steps) -> dict:
+    """The latent-attention layers' cache: bytes held, rows written and
+    rows read."""
+    ctx = value("serve.mla_ctx_tokens")
+    return {"cache_bytes": value("serve.latent_cache_bytes"),
+            "rows_written": value("serve.latent_rows_written"),
+            "mla_ctx_tokens": ctx,
+            "ctx_tokens_a_step": (round(ctx / steps, 1)
+                                  if ctx is not None and steps else None)}
+
+
+def moe(value) -> dict:
+    """Where the sparse layers' tokens went."""
+    routed = value("serve.moe_tokens_routed")
+    held = value("serve.moe_assignments_held")
+    group = value("serve.moe_tokens_to_held_group")
+    return {"tokens_routed": routed, "assignments_held": held,
+            "assignments_held_a_token": _share(held, routed),
+            "tokens_to_held_group": group,
+            "held_group_share": _share(group, routed)}
 
 
 def slowest(engine: str, k: int = 5) -> dict:
