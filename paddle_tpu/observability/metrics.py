@@ -69,6 +69,9 @@ CLAIMED_SUBSYSTEMS = {
     "health",      # observability/health.py — continuous-health
                    # detectors: latched alerts by rule/series,
                    # detector evaluations
+    "kernels",     # ops/pallas — what a kernel's wrapper chose from the
+                   # shapes where it was traced into a program (the
+                   # flash attention's pass, backward form and layout)
     "test",        # scratch names registered by the test suite
 }
 

@@ -3,15 +3,40 @@
 TPU-native replacement for the reference's CUDA flashattn integration
 (reference: phi/kernels/gpu/flash_attn_kernel.cu:35, Python surface
 python/paddle/nn/functional/flash_attention.py:198,991). Design: classic
-flash-attention online-softmax over a (batch, q_head, q_block, k_block)
-sequential grid — the k_block axis is innermost so VMEM scratch carries the
-running (max, sum, accumulator) across k blocks; backward recomputes P from
-the saved logsumexp (no O(S^2) residuals). GQA is expressed in the BlockSpec
-index maps (kv head = q head // group), so grouped KV blocks are fetched
-once per q head without materialising the repeat.
+flash-attention online softmax; the backward recomputes P from the saved
+logsumexp (no O(S^2) residuals). GQA is expressed in the BlockSpec index
+maps (kv head = q head // group), so grouped KV blocks are fetched once
+per q head without materialising the repeat.
 
-Layouts: public API uses paddle's [B, S, H, D]; kernels run [B, H, S, D].
-Compute is fp32 on the MXU (`preferred_element_type`), outputs cast back.
+Tiles. A call's grid is ``(batch, q head, tile)``: the third axis walks a
+list of the (q block, k block) pairs the mask leaves anything of
+(``_tile_list``), handed to the index maps by scalar prefetch, so a pair
+the causal diagonal or a window's band empties costs neither a DMA nor a
+grid step. Each pair carries flags: the first and last of its run (the
+run's scratch is reset and written out there). Every tile of a causal
+call is masked: on the chip the iota / compare / select hides behind the
+products (``tools/flash_kernel_probe.py``: masking only the tiles the
+edge crosses read 556 us against 560, 1,067 against 1,068).
+
+Forward (``flash_fwd``): q-block-major; the running maximum and sum stay
+lane-replicated ``[block_q, 128]`` in VMEM, q is scaled once a q block,
+operands reach the MXU in the dtype they arrive in, and the logsumexp
+leaves as ``[B, H, 1, Sq]`` rows.
+
+Backward. Where a float32 ``[Sq, D]`` dq accumulator fits the VMEM
+budget (``_single_pass_fits``: a rule on the shapes, no flag), one
+k-block-major kernel (``flash_bwd_dkv``) computes each transposed score
+tile ``k q^T`` once and from it dv, dk and the tile's share of dq; the
+row statistics arrive as ``[1, block_q]`` rows, which the transposed
+tile takes as a sublane broadcast. Longer sequences keep the two-kernel
+form (``flash_bwd_dq`` + ``flash_bwd_dkv``, lane-padded statistics).
+
+Layouts: the public API uses paddle's [B, S, H, D]. With heads a
+multiple of 128 wide the kernels read that layout directly (a
+``[B, S, H*D]`` view blocked ``(block, D)`` at lane block ``h``); other
+head sizes, the split backward and the ``[B, H, S, D]`` entries
+(``_flash_fwd_bhsd``, ``_flash_bwd_bhsd``) run head-major.
+Maxima, sums, lse, delta and accumulators are float32; outputs cast back.
 
 On non-TPU backends the same kernels run under `interpret=True`, which is
 how the OpTest suite checks their arithmetic against the XLA composition
@@ -30,40 +55,55 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ... import observability as _obs
 from ...core import dispatch
 from ...core.flags import pallas_mode
 from ..kernel_partition import shard_kernel
 
 NEG_INF = float("-inf")
-Z = __import__("numpy").int32(0)  # index-map literal: stays i32 under jax_enable_x64
-LANES = 128  # lse/delta lane padding (TPU (8,128) tiling; see _fwd_kernel)
+Z = np.int32(0)  # index-map literal: stays i32 under jax_enable_x64
+LANES = 128  # the forward's statistics; the split backward's lse/delta padding
+
+# a tile's flags in the prefetched list (see _tile_list)
+_FIRST, _LAST = 1, 2
+
+_NT = (((1,), (1,)), ((), ()))  # a @ b^T
+_NN = (((1,), (0,)), ((), ()))  # a @ b
+_TN = (((0,), (0,)), ((), ()))  # a^T @ b
+
+_M_TRACED = _obs.counter(
+    "kernels.flash_traced", "flash attention calls traced into a program, "
+    "by pass (fwd / bwd), the backward's form (single: one k-major kernel "
+    "for dq, dk and dv; split: two kernels; '-' for the forward) and the "
+    "layout the kernels read (bshd: the model's [B, S, H*D]; bhsd)")
 
 
 def _c32(u):
     """uint32 literal as a wrapping int32 constant."""
-    import numpy as np
-
     return jnp.int32(np.uint32(u).astype(np.int32))
 
 
-def _dropout_keep(seed, bh, i, j, block_q, block_k, rate):
+def _dropout_keep(seed, bh, i, j, block_q, block_k, rate, transposed=False):
     """Counter-based attention-dropout mask for the (i, j) tile of head bh.
 
     P(keep) = 1 - rate. murmur3-style int32 mixing over
     (seed, batch*head, global row, global col) — pure vector int ops, so
-    the SAME bits regenerate in the forward and both backward kernels
-    (their grids visit the same (b, h, i, j) tiles) and under
-    ``interpret=True`` (``pltpu.prng_*`` has no interpret lowering).
+    the SAME bits regenerate in the forward and the backward kernels,
+    whatever tiles each walks, and under ``interpret=True``
+    (``pltpu.prng_*`` has no interpret lowering). ``transposed``: the
+    ``[block_k, block_q]`` tile of the single backward pass.
     Reference semantics: dropout on the softmax WEIGHTS
     (flash_attention.py:991 attn_dropout), denominator excluded.
     """
+    shape = (block_k, block_q) if transposed else (block_q, block_k)
     rows = i * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
+        jnp.int32, shape, int(transposed))
     cols = j * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
+        jnp.int32, shape, 1 - int(transposed))
     x = (rows * _c32(0x9E3779B1)) ^ (cols * _c32(0x85EBCA77))
     x = x ^ (bh * _c32(0xC2B2AE3D)) ^ seed
     shr = lambda a, n: jax.lax.shift_right_logical(a, jnp.int32(n))
@@ -90,6 +130,12 @@ def _pick_block(n: int, target: int = 512) -> int:
     return max(b, 1)
 
 
+def _seq_block(n: int, target: int) -> int:
+    """A sequence block of the flash kernels: ``_pick_block``, but a
+    sequence no multiple of 128 is one block (its statistics are rows,
+    whose blocks are whole lane tiles or the whole row)."""
+    return _pick_block(n, target) if n % LANES == 0 else n
+
 
 def _kv_head_map(g: int):
     """Index-map component mapping q head -> kv head (GQA). `h // g` via
@@ -98,140 +144,206 @@ def _kv_head_map(g: int):
     same-dtype lax.div otherwise."""
     if g == 1:
         return lambda h: h
-    import numpy as _np
+    return lambda h: jax.lax.div(h, np.int32(g))
 
-    return lambda h: jax.lax.div(h, _np.int32(g))
+
+# ---------------------------------------------------------------------------
+# the tiles a call visits
+# ---------------------------------------------------------------------------
+def _tile_list(nq, nk, block_q, block_k, offset, causal, window, k_major):
+    """(q block, k block, flags) int32 arrays of the pairs the mask leaves
+    anything of, in visiting order: by q block, k ascending (the forward)
+    or, ``k_major``, by k block, q ascending (the single backward pass).
+    Query ``r`` sees keys ``<= r + offset`` (``causal``; offset = Sk - Sq,
+    the bottom-right-aligned mask) and ``> r + offset - window``. Flags:
+    ``_FIRST`` / ``_LAST`` of the run over one major block. A major block
+    the mask empties (queries before the first key) keeps one pair, so
+    that its outputs are written."""
+    r_lo = np.arange(nq)[:, None] * block_q
+    c_lo = np.arange(nk)[None, :] * block_k
+    need = np.ones((nq, nk), bool)
+    if causal:
+        need = c_lo <= r_lo + block_q - 1 + offset
+        if window is not None:
+            need &= c_lo + block_k - 1 > r_lo + offset - window
+    if k_major:
+        need = need.T
+    majors, minors, flags = [], [], []
+    for a, row in enumerate(need):
+        run = np.flatnonzero(row) if row.any() else np.zeros(1, int)
+        f = np.zeros(run.size, int)
+        f[0] |= _FIRST
+        f[-1] |= _LAST
+        majors += [a] * run.size
+        minors += run.tolist()
+        flags += f.tolist()
+    qi, kj = (minors, majors) if k_major else (majors, minors)
+    return tuple(jnp.asarray(np.asarray(x, np.int32)) for x in (qi, kj, flags))
+
+
+def _head_spec(layout, rows, width, seq, head=lambda h: h):
+    """BlockSpec of one head's ``[rows, width]`` tile of a q- or kv-shaped
+    array: ``[B, H, S, D]`` (``bhsd``) or its ``[B, S, H*D]`` view
+    (``bshd``: lane block ``head``). ``seq(t, qi, kj)`` is the block along
+    the sequence for grid step ``t`` of the prefetched tile list."""
+    if layout == "bhsd":
+        return pl.BlockSpec(
+            (None, None, rows, width),
+            lambda b, h, t, qi, kj, fl: (b, head(h), seq(t, qi, kj), Z))
+    return pl.BlockSpec(
+        (None, rows, width),
+        lambda b, h, t, qi, kj, fl: (b, seq(t, qi, kj), head(h)))
+
+
+def _q_of(t, qi, kj):
+    return qi[t]
+
+
+def _k_of(t, qi, kj):
+    return kj[t]
+
+
+def _row_spec(block_q):
+    """A ``[1, block_q]`` block of the ``[B, H, 1, Sq]`` row statistics."""
+    return pl.BlockSpec((None, None, 1, block_q),
+                        lambda b, h, t, qi, kj, fl: (b, h, Z, qi[t]))
+
+
+def _flat_shape(shape, layout):
+    """The shape a kernel blocks: ``[B, S, H, D]`` as ``[B, S, H*D]``."""
+    shape = tuple(shape)
+    return shape if layout == "bhsd" else shape[:2] + (shape[2] * shape[3],)
+
+
+def _flat(x, layout):
+    return x.reshape(_flat_shape(x.shape, layout))
+
+
+def _dims(q, k, v, layout):
+    """(B, H, Hkv, Sq, Sk, D, Dv) of a call's operands."""
+    s, h = (2, 1) if layout == "bhsd" else (1, 2)
+    return (q.shape[0], q.shape[h], k.shape[h], q.shape[s], k.shape[s],
+            q.shape[3], v.shape[3])
+
+
+def _lanes(x, n):
+    """A lane-replicated ``[rows, 128]`` statistic at width ``n``."""
+    if n == LANES:
+        return x
+    if n % LANES == 0:
+        return jnp.tile(x, (1, n // LANES))
+    if n < LANES:
+        return x[:, :n]
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
+def _visible(i, j, shape, block_q, block_k, offset, window, q_dim):
+    """The mask of the (i, j) tile: query rows along ``q_dim`` of
+    ``shape``, keys along the other."""
+    ahead = (jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_dim)
+             - jax.lax.broadcasted_iota(jnp.int32, shape, q_dim))
+    # col - row <= offset, in tile coordinates
+    lim = i * block_q - j * block_k + offset
+    seen = ahead <= lim
+    if window is not None:
+        seen &= ahead > lim - window
+    return seen
+
+
+def _optional_refs(refs, n, has_bias, rate):
+    """(bias_ref, seed_ref, rest) of a kernel's refs after its first n."""
+    bias_ref = refs[n] if has_bias else None
+    n += int(has_bias)
+    seed_ref = refs[n] if rate > 0.0 else None
+    n += int(rate > 0.0)
+    return bias_ref, seed_ref, refs[n:]
 
 
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
-def _band_blocks(i, block_q, block_k, offset, window):
-    """(first, last) key block that query block ``i`` sees under a causal
-    mask with a window: query ``r`` sees keys ``(r + offset - window,
-    r + offset]``. int32 throughout (``jax_enable_x64`` is on)."""
-    i32 = type(Z)
-    lo = jnp.maximum(i * i32(block_q) + i32(offset - window + 1), i32(0))
-    hi = i * i32(block_q) + i32(block_q - 1 + offset)
-    return jax.lax.div(lo, i32(block_k)), jax.lax.div(hi, i32(block_k))
-
-
-def _fwd_kernel(*refs, scale, causal, block_q, block_k, nk, offset,
-                rate, n_heads, has_bias=False, window=None):
+def _fwd_kernel(qi_ref, kj_ref, fl_ref, *refs, scale, causal, block_q,
+                block_k, offset, rate, n_heads, has_bias, window, guard):
     # offset = Sk - Sq: bottom-right-aligned causal mask (query i attends
     # keys <= i + offset), matching paddle/XLA semantics for Sq != Sk.
-    # window (causal only): and keys > i + offset - window, a band
-    refs = list(refs)
+    # window (causal only): and keys > i + offset - window, a band.
+    # guard: a row may have seen no key yet when a tile is computed (a
+    # key bias of -inf, the band's lower edge, queries before the first
+    # key); keep the exp args finite so it stays exactly zero, not NaN
     q_ref, k_ref, v_ref = refs[:3]
-    n = 3
-    bias_ref = refs[n] if has_bias else None
-    n += int(has_bias)
-    seed_ref = refs[n] if rate > 0.0 else None
-    n += int(rate > 0.0)
-    o_ref, lse_ref, m_scr, l_scr, acc_scr = refs[n:]
-    i = pl.program_id(2)
-    j = pl.program_id(3)
-    # hoisted: pl.program_id is not available inside a pl.when body under
-    # interpret mode
+    bias_ref, seed_ref, rest = _optional_refs(refs, 3, has_bias, rate)
+    o_ref, lse_ref, qs_scr, m_scr, l_scr, acc_scr = rest
+    t = pl.program_id(2)
+    i, j, fl = qi_ref[t], kj_ref[t], fl_ref[t]
     bh = pl.program_id(0) * n_heads + pl.program_id(1)
+    dv = acc_scr.shape[-1]
 
-    @pl.when(j == 0)
+    @pl.when((fl & _FIRST) != 0)
     def _init():
-        m_scr[:] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
-        l_scr[:] = jnp.zeros(l_scr.shape, jnp.float32)
-        acc_scr[:] = jnp.zeros(acc_scr.shape, jnp.float32)
+        m_scr[...] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
+        l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+        qs_scr[...] = (q_ref[...].astype(jnp.float32) * scale
+                       ).astype(qs_scr.dtype)
 
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)
-        k = k_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
-        if has_bias:
-            # additive per-key bias (broadcast over query rows): the
-            # [B, 1, 1, Sk] padding-mask pattern of sdpa_mask_p
-            s = s + bias_ref[0].astype(jnp.float32)
-        if causal:
-            rows = i * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            cols = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(rows + offset >= cols, s, NEG_INF)
-            if window is not None:
-                s = jnp.where(rows + offset - cols < window, s, NEG_INF)
-        m_prev = m_scr[:, :1]
-        l_prev = l_scr[:, :1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        # rows may be fully masked inside a partially-causal block; keep the
-        # exp args finite so those rows stay exactly zero instead of NaN
-        m_eff = jnp.where(m_new == NEG_INF, 0.0, m_new)
-        alpha = jnp.exp(m_prev - m_eff)  # exp(-inf)=0 for first visit
-        p = jnp.exp(s - m_eff)
-        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        if rate > 0.0:
-            # softmax denominator (l) stays over the UNDROPPED weights;
-            # only the value accumulation sees the mask (post-softmax
-            # dropout semantics, matching the XLA oracle path)
-            keep = _dropout_keep(seed_ref[0], bh, i, j, block_q, block_k,
-                                 rate)
-            p_use = p * keep * (1.0 / (1.0 - rate))
-        else:
-            p_use = p
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p_use, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32
-        )
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+    s = jax.lax.dot_general(qs_scr[...], k_ref[...], _NT,
+                            preferred_element_type=jnp.float32)
+    if has_bias:
+        # additive per-key bias (broadcast over query rows): the
+        # [B, 1, 1, Sk] padding-mask pattern of sdpa_mask_p
+        s = s + bias_ref[...].astype(jnp.float32)
+    if causal:
+        s = jnp.where(_visible(i, j, s.shape, block_q, block_k, offset,
+                               window, 0), s, NEG_INF)
+    m_prev = m_scr[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    m_eff = jnp.where(m_new == NEG_INF, 0.0, m_new) if guard else m_new
+    alpha = jnp.exp(m_prev - m_eff)  # exp(-inf)=0 for first visit
+    p = jnp.exp(s - _lanes(m_eff, block_k))
+    l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=-1, keepdims=True)
+    m_scr[...] = m_new
+    if rate > 0.0:
+        # softmax denominator (l) stays over the UNDROPPED weights;
+        # only the value accumulation sees the mask (post-softmax
+        # dropout semantics, matching the XLA oracle path)
+        p = p * (_dropout_keep(seed_ref[0], bh, i, j, block_q, block_k,
+                               rate) * (1.0 / (1.0 - rate)))
+    acc_scr[...] = acc_scr[...] * _lanes(alpha, dv) + jax.lax.dot_general(
+        p.astype(v_ref.dtype), v_ref[...], _NN,
+        preferred_element_type=jnp.float32)
 
-    if causal and window is not None:
-        # skip blocks outside the band on either side
-        first, last = _band_blocks(i, block_q, block_k, offset, window)
-
-        @pl.when((j >= first) & (j <= last))
-        def _():
-            _compute()
-    elif causal:
-        # skip blocks strictly above the (offset) diagonal
-        @pl.when(j * block_k <= i * block_q + (block_q - 1) + offset)
-        def _():
-            _compute()
-    else:
-        _compute()
-
-    @pl.when(j == nk - 1)
+    @pl.when((fl & _LAST) != 0)
     def _finalize():
-        l = l_scr[:, :1]
+        l = l_scr[...]
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
-        m = m_scr[:, :1]
-        lse = jnp.where(l == 0.0, NEG_INF, m + jnp.log(l_safe))
-        # lse is carried in a 128-lane layout ([..., Sq, LANES]) — TPU block
-        # shapes need the last two dims (8, 128)-tileable, so a [B, H, Sq]
-        # output with (1, 1, block_q) blocks is not expressible
-        lse_ref[0, 0] = jnp.broadcast_to(lse, lse_ref[0, 0].shape)
+        o_ref[...] = (acc_scr[...] / _lanes(l_safe, dv)).astype(o_ref.dtype)
+        lse = jnp.where(l == 0.0, NEG_INF, m_scr[...] + jnp.log(l_safe))
+        # a row of the [B, H, 1, Sq] logsumexp: the columns' transpose
+        lse_ref[...] = lse.T[:1]
 
 
 # ---------------------------------------------------------------------------
 # sharded programs: batch and head are independent, sequence and head_dim
 # are not (see ops/kernel_partition.py)
 # ---------------------------------------------------------------------------
-def _run_flash(local, operands, results, arrays, *, partition, **statics):
+def _run_flash(local, operands, results, arrays, *, partition, layout,
+               **statics):
     """Call one shard-level flash function: directly, or under shard_map
     when the program is sharded. ``operands``/``results`` name each
-    array's layout (x = q-shaped, k = kv-shaped, l = lse-shaped); the
-    optional [B|1, Sk] bias and [1] seed follow the operands."""
-    local = functools.partial(local, **statics)
+    array's kind (x = q-shaped, k = kv-shaped, in ``layout``; l =
+    lse-shaped [B, H, Sq]); the optional [B|1, Sk] bias and [1] seed
+    follow the operands."""
+    local = functools.partial(local, layout=layout, **statics)
     if partition is None:
         return local(*arrays)
     q, k = arrays[0], arrays[1]
     b_ax = partition.axis_if_divides(partition.batch, q.shape[0])
     # heads split on kv-head boundaries: a shard holds whole GQA groups
-    h_ax = partition.axis_if_divides(partition.heads, k.shape[1])
-    specs = {"x": (b_ax, h_ax, None, None), "l": (b_ax, h_ax, None)}
-    specs["k"] = specs["x"]
+    h_ax = partition.axis_if_divides(
+        partition.heads, k.shape[1 if layout == "bhsd" else 2])
+    specs = {"l": (b_ax, h_ax, None)}
+    specs["x"] = specs["k"] = ((b_ax, h_ax, None, None) if layout == "bhsd"
+                               else (b_ax, None, h_ax, None))
     in_specs = [specs[c] for c in operands]
     if statics["has_bias"]:
         bias = arrays[len(operands)]
@@ -258,83 +370,96 @@ def _split_extras(extras, has_bias, rate, shard_axes):
     return key_bias, seed
 
 
-def _fwd_local(q, k, v, *extras, causal, scale, rate, has_bias, interpret,
-               shard_axes=(), window=None):
-    """One shard's forward: q [B,H,Sq,D]; k [B,Hkv,Sk,D]; v
-    [B,Hkv,Sk,Dv] -> (out [B,H,Sq,Dv], lse [B,H,Sq]). The value head may
-    be another size than the query-key head (a latent-attention prefill:
-    192 and 128). ``window`` (with ``causal``): a query sees its last
-    ``window`` keys only; key blocks outside that band are neither
-    computed nor fetched (forward only)."""
-    key_bias, seed = _split_extras(extras, has_bias, rate, shard_axes)
-    B, H, Sq, D = q.shape
-    Hkv, Sk, Dv = k.shape[1], k.shape[2], v.shape[3]
-    g = H // Hkv
-    block_q = _pick_block(Sq)
-    block_k = _pick_block(Sk)
-    nq, nk = Sq // block_q, Sk // block_k
-    kv_head = _kv_head_map(g)
-    grid = (B, H, nq, nk)
-    kernel = functools.partial(
-        _fwd_kernel, scale=scale, causal=causal,
-        block_q=block_q, block_k=block_k, nk=nk, offset=Sk - Sq,
-        rate=rate, n_heads=H, has_bias=has_bias,
-        **({} if window is None else {"window": int(window)}))
-    if window is None:
-        kv_map = lambda b, h, i, j: (b, kv_head(h), j, Z)
-    else:
-        if not causal or has_bias or rate > 0.0:
-            raise ValueError("flash forward: a window needs causal=True "
-                             "and takes neither key bias nor dropout")
+def _bias_operand(key_bias, block_k, column):
+    """(BlockSpec, array) of the [B|1, Sk] key bias: ``[1, block_k]`` rows
+    for a ``[q, k]`` score tile, ``[block_k, 1]`` columns for a
+    transposed one. A batch-1 bias (a mask shared across the batch) pins
+    the index map to row 0 instead of materializing B copies."""
+    nb, sk = key_bias.shape
+    row = (lambda b: Z) if nb == 1 else (lambda b: b)
+    if column:
+        return (pl.BlockSpec((None, block_k, 1),
+                             lambda b, h, t, qi, kj, fl: (row(b), kj[t], Z)),
+                key_bias.reshape(nb, sk, 1))
+    return (pl.BlockSpec((None, 1, block_k),
+                         lambda b, h, t, qi, kj, fl: (row(b), Z, kj[t])),
+            key_bias.reshape(nb, 1, sk))
 
-        def kv_map(b, h, i, j):
-            # a block outside the band is pinned to the nearest inside
-            # it, which the step before or after holds: no DMA
-            first, last = _band_blocks(i, block_q, block_k, Sk - Sq,
-                                       int(window))
-            return (b, kv_head(h), jnp.clip(j, first, last), Z)
+
+_SEED_SPEC = pl.BlockSpec((1,), lambda b, h, t, qi, kj, fl: (Z,),
+                          memory_space=pltpu.SMEM)
+
+
+def _blocks(sq, sk):
+    """(block_q, block_k) of the forward and of the single backward pass,
+    from the shapes: 512 x 512 was the fastest of the tiles
+    ``tools/flash_kernel_probe.py`` swept at every cell's shape (heads of
+    64, 128 and 192/128, banded and plain): smaller tiles pay the
+    statistics and the grid step more often than they save on the
+    diagonal, larger ones waste more of it."""
+    return _seq_block(sq, 512), _seq_block(sk, 512)
+
+
+def _fwd_local(q, k, v, *extras, causal, scale, rate, has_bias, interpret,
+               layout="bhsd", shard_axes=(), window=None, blocks=None):
+    """One shard's forward: q [B,H,Sq,D]; k [B,Hkv,Sk,D]; v
+    [B,Hkv,Sk,Dv] (``layout`` bhsd; bshd: [B,S,H,D] each) -> (out like
+    q at width Dv, lse [B,H,Sq]). The value head may be another size than
+    the query-key head (a latent-attention prefill: 192 and 128).
+    ``window`` (with ``causal``): a query sees its last ``window`` keys
+    only; key blocks outside that band are neither computed nor fetched
+    (forward only). ``blocks``: (block_q, block_k) in place of the
+    shapes' own (``tools/flash_kernel_probe.py`` sweeps them)."""
+    key_bias, seed = _split_extras(extras, has_bias, rate, shard_axes)
+    if window is not None and (not causal or has_bias or rate > 0.0):
+        raise ValueError("flash forward: a window needs causal=True "
+                         "and takes neither key bias nor dropout")
+    B, H, Hkv, Sq, Sk, D, Dv = _dims(q, k, v, layout)
+    block_q, block_k = blocks or _blocks(Sq, Sk)
+    offset = Sk - Sq
+    tiles = _tile_list(Sq // block_q, Sk // block_k, block_q, block_k,
+                       offset, causal, window, k_major=False)
+    kv_head = _kv_head_map(H // Hkv)
+    _M_TRACED.inc(**{"pass": "fwd", "form": "-", "layout": layout})
+    kernel = functools.partial(
+        _fwd_kernel, scale=scale, causal=causal, block_q=block_q,
+        block_k=block_k, offset=offset, rate=rate, n_heads=H,
+        has_bias=has_bias, window=None if window is None else int(window),
+        guard=has_bias or window is not None or (causal and offset < 0))
     in_specs = [
-            pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, Z)),
-            pl.BlockSpec((1, 1, block_k, D), kv_map),
-            pl.BlockSpec((1, 1, block_k, Dv), kv_map),
+        _head_spec(layout, block_q, D, _q_of),
+        _head_spec(layout, block_k, D, _k_of, kv_head),
+        _head_spec(layout, block_k, Dv, _k_of, kv_head),
     ]
-    inputs = [q, k, v]
+    inputs = [_flat(x, layout) for x in (q, k, v)]
     if key_bias is not None:
-        # [B, 1, Sk] with (1, 1, block_k) blocks: Mosaic wants the last
-        # two block dims (8, 128)-divisible or equal to the array dims.
-        # A batch-1 bias (mask shared across the batch) pins the index
-        # map to row 0 instead of materializing B copies.
-        bmap = ((lambda b, h, i, j: (Z, Z, j)) if key_bias.shape[0] == 1
-                else (lambda b, h, i, j: (b, Z, j)))
-        in_specs.append(pl.BlockSpec((1, 1, block_k), bmap))
-        inputs.append(key_bias.reshape(key_bias.shape[0], 1,
-                                       key_bias.shape[1]))
+        spec, bias = _bias_operand(key_bias, block_k, column=False)
+        in_specs.append(spec)
+        inputs.append(bias)
     if rate > 0.0:
-        in_specs.append(pl.BlockSpec((1,), lambda b, h, i, j: (Z,),
-                                  memory_space=pltpu.SMEM))
+        in_specs.append(_SEED_SPEC)
         inputs.append(seed)
+    out_shape = q.shape[:3] + (Dv,)
     out, lse = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, block_q, Dv), lambda b, h, i, j: (b, h, i, Z)),
-            pl.BlockSpec((1, 1, block_q, LANES),
-                         lambda b, h, i, j: (b, h, i, Z)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B, H, tiles[0].shape[0]),
+            in_specs=in_specs,
+            out_specs=[_head_spec(layout, block_q, Dv, _q_of),
+                       _row_spec(block_q)],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, D), q.dtype),
+                pltpu.VMEM((block_q, LANES), jnp.float32),
+                pltpu.VMEM((block_q, LANES), jnp.float32),
+                pltpu.VMEM((block_q, Dv), jnp.float32),
+            ]),
         out_shape=[
-            jax.ShapeDtypeStruct((B, H, Sq, Dv), q.dtype),
-            jax.ShapeDtypeStruct((B, H, Sq, LANES), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, Dv), jnp.float32),
+            jax.ShapeDtypeStruct(_flat_shape(out_shape, layout), q.dtype),
+            jax.ShapeDtypeStruct((B, H, 1, Sq), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"),
-        ),
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         cost_estimate=pl.CostEstimate(
             flops=2 * B * H * Sq * Sk * (D + Dv),
             bytes_accessed=(q.size + k.size + v.size) * q.dtype.itemsize,
@@ -342,21 +467,22 @@ def _fwd_local(q, k, v, *extras, causal, scale, rate, has_bias, interpret,
         ),
         name="flash_fwd",
         interpret=interpret,
-    )(*inputs)
-    return out, lse[:, :, :, 0]
+    )(*tiles, *inputs)
+    return out.reshape(out_shape), lse.reshape(B, H, Sq)
 
 
-_JIT_STATICS = ("causal", "scale", "dropout_rate", "partition", "interpret")
+_JIT_STATICS = ("causal", "scale", "dropout_rate", "partition", "interpret",
+                "layout")
 
 
 @functools.partial(jax.jit, static_argnames=_JIT_STATICS + ("window",))
 def _flash_fwd_jit(q, k, v, seed, key_bias, *, causal, scale, dropout_rate,
-                   partition, interpret, window=None):
+                   partition, interpret, layout="bhsd", window=None):
     extras = [x for x in (key_bias, seed) if x is not None]
     band = {} if window is None else {"window": window}
     return _run_flash(
         _fwd_local, "xkk", "xl", (q, k, v, *extras), partition=partition,
-        causal=causal, scale=scale, rate=dropout_rate,
+        layout=layout, causal=causal, scale=scale, rate=dropout_rate,
         has_bias=key_bias is not None, interpret=interpret, **band)
 
 
@@ -380,7 +506,152 @@ def _flash_fwd_bhsd(q, k, v, seed=None, key_bias=None, *, causal, scale,
 
 
 # ---------------------------------------------------------------------------
-# backward
+# backward: one k-block-major pass for dq, dk and dv
+# ---------------------------------------------------------------------------
+def _bwd_kernel(qi_ref, kj_ref, fl_ref, *refs, scale, causal, block_q,
+                block_k, offset, rate, n_heads, has_bias):
+    """Transposed tiles ``[block_k, block_q]``: the row statistics are
+    ``[1, block_q]`` rows (a sublane broadcast), dv = p^T do and dk =
+    ds^T q are plain products, and only dq's contracts the tile's rows."""
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
+    bias_ref, seed_ref, rest = _optional_refs(refs, 6, has_bias, rate)
+    dq_ref, dk_ref, dv_ref, ks_scr, dq_scr, dk_scr, dv_scr = rest
+    t = pl.program_id(2)
+    i, j, fl = qi_ref[t], kj_ref[t], fl_ref[t]
+    bh = pl.program_id(0) * n_heads + pl.program_id(1)
+    q_rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+
+    @pl.when(t == 0)
+    def _init_dq():
+        dq_scr[...] = jnp.zeros(dq_scr.shape, jnp.float32)
+
+    @pl.when((fl & _FIRST) != 0)
+    def _init():
+        dk_scr[...] = jnp.zeros(dk_scr.shape, jnp.float32)
+        dv_scr[...] = jnp.zeros(dv_scr.shape, jnp.float32)
+        # k is scaled once a k block: s and dq take it so, dk is scaled
+        # where it is written out
+        ks_scr[...] = (k_ref[...].astype(jnp.float32) * scale
+                       ).astype(ks_scr.dtype)
+
+    q, do = q_ref[...], do_ref[...]
+    s = jax.lax.dot_general(ks_scr[...], q, _NT,
+                            preferred_element_type=jnp.float32)
+    if has_bias:
+        s = s + bias_ref[...].astype(jnp.float32)   # [block_k, 1]
+    if causal:
+        s = jnp.where(_visible(i, j, s.shape, block_q, block_k, offset,
+                               None, 1), s, NEG_INF)
+    lse = lse_ref[...]                              # [1, block_q]
+    p = jnp.exp(s - jnp.where(lse == NEG_INF, 0.0, lse))
+    dp = jax.lax.dot_general(v_ref[...], do, _NT,
+                             preferred_element_type=jnp.float32)
+    if rate > 0.0:
+        # d/ds of out = (keep∘c∘softmax(s)) @ v with the softmax
+        # denominator undropped: ds_j = p_j (keep_j c dp_j - delta),
+        # delta = rowsum(do∘o) (absorbs the Σ p·dp term exactly);
+        # the forward's bits of these rows and columns
+        keep = _dropout_keep(seed_ref[0], bh, i, j, block_q, block_k,
+                             rate, transposed=True) * (1.0 / (1.0 - rate))
+        p_drop, dp = p * keep, dp * keep
+    else:
+        p_drop = p
+    # dV += (keep∘c∘P)^T dO
+    dv_scr[...] += jax.lax.dot_general(
+        p_drop.astype(do.dtype), do, _NN,
+        preferred_element_type=jnp.float32)
+    ds = (p * (dp - delta_ref[...])).astype(q.dtype)
+    # dK += dS^T Q (scaled at the end); dQ[i] += dS (K scale)
+    dk_scr[...] += jax.lax.dot_general(
+        ds, q, _NN, preferred_element_type=jnp.float32)
+    dq_scr[q_rows, :] += jax.lax.dot_general(
+        ds, ks_scr[...], _TN, preferred_element_type=jnp.float32)
+
+    @pl.when((fl & _LAST) != 0)
+    def _finalize():
+        dk_ref[...] = (dk_scr[...] * scale).astype(dk_ref.dtype)
+        dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
+
+    @pl.when(t == pl.num_programs(2) - 1)
+    def _finalize_dq():
+        dq_ref[...] = dq_scr[...].astype(dq_ref.dtype)
+
+
+# the single pass keeps dq of one (batch, head) in VMEM: a float32
+# [Sq, D] accumulator and the output's two buffers
+_DQ_ACC_BYTES = 4 << 20
+_VMEM_LIMIT = 64 << 20
+
+
+def _single_pass_fits(sq, d):
+    return sq * d * 4 <= _DQ_ACC_BYTES
+
+
+def _bwd_single(q, k, v, do, lse, delta, key_bias, seed, *, causal, scale,
+                rate, interpret, layout, blocks=None):
+    """dq, and dk, dv a q head (the caller reduces GQA groups)."""
+    B, H, Hkv, Sq, Sk, D, _ = _dims(q, k, v, layout)
+    block_q, block_k = blocks or _blocks(Sq, Sk)
+    offset = Sk - Sq
+    tiles = _tile_list(Sq // block_q, Sk // block_k, block_q, block_k,
+                       offset, causal, None, k_major=True)
+    kv_head = _kv_head_map(H // Hkv)
+    has_bias = key_bias is not None
+    kernel = functools.partial(
+        _bwd_kernel, scale=scale, causal=causal, block_q=block_q,
+        block_k=block_k, offset=offset, rate=rate, n_heads=H,
+        has_bias=has_bias)
+    q_spec = _head_spec(layout, block_q, D, _q_of)
+    k_spec = _head_spec(layout, block_k, D, _k_of, kv_head)
+    in_specs = [q_spec, k_spec, k_spec, q_spec,
+                _row_spec(block_q), _row_spec(block_q)]
+    inputs = [_flat(x, layout) for x in (q, k, v, do)]
+    inputs += [lse.reshape(B, H, 1, Sq), delta.reshape(B, H, 1, Sq)]
+    if has_bias:
+        spec, bias = _bias_operand(key_bias, block_k, column=True)
+        in_specs.append(spec)
+        inputs.append(bias)
+    if rate > 0.0:
+        in_specs.append(_SEED_SPEC)
+        inputs.append(seed)
+    # dk, dv a q head: q's shape with Sk for Sq
+    seq = 2 if layout == "bhsd" else 1
+    dkv = jax.ShapeDtypeStruct(
+        _flat_shape(q.shape[:seq] + (Sk,) + q.shape[seq + 1:], layout),
+        k.dtype)
+    dkv_spec = _head_spec(layout, block_k, D, _k_of)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B, H, tiles[0].shape[0]),
+            in_specs=in_specs,
+            out_specs=[_head_spec(layout, Sq, D, lambda t, qi, kj: Z),
+                       dkv_spec, dkv_spec],
+            scratch_shapes=[
+                pltpu.VMEM((block_k, D), k.dtype),
+                pltpu.VMEM((Sq, D), jnp.float32),
+                pltpu.VMEM((block_k, D), jnp.float32),
+                pltpu.VMEM((block_k, D), jnp.float32),
+            ]),
+        out_shape=[jax.ShapeDtypeStruct(_flat_shape(q.shape, layout),
+                                        q.dtype), dkv, dkv],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        cost_estimate=pl.CostEstimate(
+            flops=10 * B * H * Sq * Sk * D,
+            bytes_accessed=(3 * q.size + 2 * k.size + 2 * B * H * Sk * D)
+            * q.dtype.itemsize,
+            transcendentals=B * H * Sq * Sk,
+        ),
+        name="flash_bwd_dkv",
+        interpret=interpret,
+    )(*tiles, *inputs)
+
+
+# ---------------------------------------------------------------------------
+# backward: the two-kernel form, for a dq accumulator over the budget
 # ---------------------------------------------------------------------------
 def _bwd_dq_kernel(*refs, scale, causal, block_q, block_k, nk, offset,
                    rate, n_heads, has_bias=False):
@@ -519,19 +790,18 @@ def _bwd_dkv_kernel(*refs, scale, causal, block_q, block_k, nq, offset,
         dv_ref[0, 0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-def _bwd_local(q, k, v, out, lse, do, *extras, causal, scale, rate,
-               has_bias, interpret, shard_axes=()):
-    """One shard's backward -> (dq, dk, dv)."""
-    key_bias, seed = _split_extras(extras, has_bias, rate, shard_axes)
+def _bwd_split(q, k, v, do, lse, delta, key_bias, seed, *, causal, scale,
+               rate, interpret):
+    """dq, and dk, dv a q head, head-major ([B, H, S, D]) by two kernels:
+    dq over q blocks, dk and dv over k blocks, each recomputing the
+    scores; the row statistics lane-padded."""
     B, H, Sq, D = q.shape
     Hkv, Sk = k.shape[1], k.shape[2]
-    g = H // Hkv
+    has_bias = key_bias is not None
     block_q = _pick_block(Sq)
     block_k = _pick_block(Sk)
     nq, nk = Sq // block_q, Sk // block_k
-    kv_head = _kv_head_map(g)
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
-    # lane-pad lse/delta to [B, H, Sq, LANES] (see _fwd_kernel finalize)
+    kv_head = _kv_head_map(H // Hkv)
     lse = jnp.broadcast_to(lse[..., None], (B, H, Sq, LANES))
     delta = jnp.broadcast_to(delta[..., None], (B, H, Sq, LANES))
 
@@ -606,7 +876,6 @@ def _bwd_local(q, k, v, out, lse, do, *extras, causal, scale, rate,
         dkv_in_specs.append(pl.BlockSpec((1,), lambda b, h, i, j: (Z,),
                                   memory_space=pltpu.SMEM))
         dkv_inputs.append(seed)
-    # dK/dV computed per q-head ([B,H,Sk,D]) then group-reduced to kv heads
     dk_h, dv_h = pl.pallas_call(
         dkv_kernel,
         grid=(B, H, nk, nq),
@@ -630,22 +899,51 @@ def _bwd_local(q, k, v, out, lse, do, *extras, causal, scale, rate,
         name="flash_bwd_dkv",
         interpret=interpret,
     )(*dkv_inputs)
-    if g > 1:
-        dk = dk_h.reshape(B, Hkv, g, Sk, D).sum(axis=2).astype(k.dtype)
-        dv = dv_h.reshape(B, Hkv, g, Sk, D).sum(axis=2).astype(v.dtype)
+    return dq, dk_h, dv_h
+
+
+def _bwd_local(q, k, v, out, lse, do, *extras, causal, scale, rate,
+               has_bias, interpret, layout="bhsd", shard_axes=(),
+               blocks=None):
+    """One shard's backward -> (dq, dk, dv), in ``layout`` like q, k, v,
+    out and do; lse is [B, H, Sq]. One pass where its dq accumulator fits
+    (``_single_pass_fits``), else the two kernels, which run head-major."""
+    key_bias, seed = _split_extras(extras, has_bias, rate, shard_axes)
+    B, H, Hkv, Sq, Sk, D, _ = _dims(q, k, v, layout)
+    single = _single_pass_fits(Sq, D)
+    if not single and layout != "bhsd":
+        raise ValueError("flash backward: the split form runs head-major")
+    _M_TRACED.inc(**{"pass": "bwd", "form": "single" if single else "split",
+                     "layout": layout})
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    if layout == "bshd":
+        delta = delta.transpose(0, 2, 1)                # [B, H, Sq]
+    kw = dict(causal=causal, scale=scale, rate=rate, interpret=interpret)
+    if single:
+        dq, dk, dv = _bwd_single(q, k, v, do, lse, delta, key_bias, seed,
+                                 layout=layout, blocks=blocks, **kw)
     else:
-        dk, dv = dk_h, dv_h
-    return dq, dk, dv
+        dq, dk, dv = _bwd_split(q, k, v, do, lse, delta, key_bias, seed,
+                                **kw)
+    # dk, dv come a q head: reduce each GQA group to its kv head
+    g = H // Hkv
+    grouped = ((B, Hkv, g, Sk, D) if layout == "bhsd"
+               else (B, Sk, Hkv, g, D))
+    dk, dv = (x.reshape(grouped).sum(axis=2 if layout == "bhsd" else 3)
+              .astype(x.dtype) if g > 1 else x.reshape(k.shape)
+              for x in (dk, dv))
+    return dq.reshape(q.shape), dk, dv
 
 
 @functools.partial(jax.jit, static_argnames=_JIT_STATICS)
 def _flash_bwd_jit(q, k, v, out, lse, do, seed, key_bias, *, causal, scale,
-                   dropout_rate, partition, interpret):
+                   dropout_rate, partition, interpret, layout="bhsd"):
     extras = [x for x in (key_bias, seed) if x is not None]
     return _run_flash(
         _bwd_local, "xkkxlx", "xkk", (q, k, v, out, lse, do, *extras),
-        partition=partition, causal=causal, scale=scale, rate=dropout_rate,
-        has_bias=key_bias is not None, interpret=interpret)
+        partition=partition, layout=layout, causal=causal, scale=scale,
+        rate=dropout_rate, has_bias=key_bias is not None,
+        interpret=interpret)
 
 
 def _flash_bwd_bhsd(q, k, v, out, lse, do, seed=None, key_bias=None, *,
@@ -659,6 +957,12 @@ def _flash_bwd_bhsd(q, k, v, out, lse, do, seed=None, key_bias=None, *,
 # ---------------------------------------------------------------------------
 # array-level API (paddle [B, S, H, D] layout) + primitive registration
 # ---------------------------------------------------------------------------
+def _reads_bshd(*widths):
+    """Heads a multiple of the lane width are blocked out of the model's
+    own [B, S, H*D] layout; others go head-major."""
+    return all(w % LANES == 0 for w in widths)
+
+
 def flash_attention_bshd(q, k, v, *extras, causal=False, scale=None,
                          dropout_rate=0.0, has_bias=False, partition=None):
     """Array-level flash attention in paddle layout. Returns (out, lse).
@@ -672,13 +976,13 @@ def flash_attention_bshd(q, k, v, *extras, causal=False, scale=None,
     extras = list(extras)
     key_bias = extras.pop(0) if has_bias else None
     seed = extras.pop(0) if dropout_rate > 0.0 else None
-    qt = jnp.swapaxes(q, 1, 2)
-    kt = jnp.swapaxes(k, 1, 2)
-    vt = jnp.swapaxes(v, 1, 2)
-    out, lse = _flash_fwd_bhsd(qt, kt, vt, seed, key_bias, causal=causal,
-                               scale=float(scale),
-                               dropout_rate=float(dropout_rate),
-                               partition=partition)
+    kw = dict(causal=causal, scale=float(scale),
+              dropout_rate=float(dropout_rate), partition=partition,
+              interpret=_interpret())
+    if _reads_bshd(q.shape[-1], v.shape[-1]):
+        return _flash_fwd_jit(q, k, v, seed, key_bias, layout="bshd", **kw)
+    qt, kt, vt = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))
+    out, lse = _flash_fwd_jit(qt, kt, vt, seed, key_bias, **kw)
     return jnp.swapaxes(out, 1, 2), lse
 
 
@@ -690,17 +994,20 @@ def _flash_vjp(grads_out, saved, *, causal, scale, dropout_rate=0.0,
     key_bias = rest.pop(0) if has_bias else None
     seed = rest.pop(0) if dropout_rate > 0.0 else None
     do = grads_out[0]
-    qt, kt, vt = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))
-    ot, dot = jnp.swapaxes(out, 1, 2), jnp.swapaxes(do, 1, 2)
-    dq, dk, dv = _flash_bwd_bhsd(qt, kt, vt, ot, lse, dot, seed, key_bias,
-                                 causal=causal, scale=float(scale),
-                                 dropout_rate=float(dropout_rate),
-                                 partition=partition)
-    grads = (jnp.swapaxes(dq, 1, 2), jnp.swapaxes(dk, 1, 2),
-             jnp.swapaxes(dv, 1, 2))
+    kw = dict(causal=causal, scale=float(scale),
+              dropout_rate=float(dropout_rate), partition=partition,
+              interpret=_interpret())
+    if _reads_bshd(q.shape[-1]) and _single_pass_fits(q.shape[1],
+                                                      q.shape[-1]):
+        grads = _flash_bwd_jit(q, k, v, out, lse, do, seed, key_bias,
+                               layout="bshd", **kw)
+    else:
+        qt, kt, vt, ot, dot = (jnp.swapaxes(x, 1, 2)
+                               for x in (q, k, v, out, do))
+        grads = tuple(jnp.swapaxes(g, 1, 2) for g in _flash_bwd_jit(
+            qt, kt, vt, ot, lse, dot, seed, key_bias, **kw))
     # optional inputs (bias, seed) take no grads: the bias is a mask
-    grads = grads + (None,) * (len(ins) - 3)
-    return grads
+    return tuple(grads) + (None,) * (len(ins) - 3)
 
 
 dispatch.register_primitive(

@@ -741,6 +741,245 @@ class TestFlashKeyBias:
 
 
 # ---------------------------------------------------------------------------
+# the single backward pass, the tile list and the [B, S, H*D] entry
+# ---------------------------------------------------------------------------
+def _composition_vjp(q, k, v, do, causal, bias=None, keep=None):
+    """(out, (dq, dk, dv)) of float32 masked-softmax attention on
+    [B, H, S, D] operands: GQA by repeat, the causal mask bottom-right
+    aligned, ``bias`` [B|1, Sk] on the logits, ``keep`` ([B, H, Sq, Sk],
+    already scaled) on the weights after the softmax."""
+    import jax
+    import jax.numpy as jnp
+
+    g = q.shape[1] // k.shape[1]
+
+    def f(q, k, v):
+        q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+        k, v = jnp.repeat(k, g, axis=1), jnp.repeat(v, g, axis=1)
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                       precision="highest") * q.shape[-1] ** -0.5
+        if bias is not None:
+            s = s + bias[:, None, None, :]
+        if causal:
+            sq, sk = s.shape[-2:]
+            s = jnp.where(jnp.tril(jnp.ones((sq, sk), bool), sk - sq), s,
+                          -jnp.inf)
+        p = jax.nn.softmax(s, axis=-1)
+        if keep is not None:
+            p = p * keep
+        return jnp.einsum("bhqk,bhkd->bhqd", p, v, precision="highest")
+
+    out, vjp = jax.vjp(f, q, k, v)
+    return out, vjp(do.astype(jnp.float32))
+
+
+class TestFlashSinglePass:
+    """One k-block-major kernel computes dq, dk and dv (named
+    ``flash_bwd_dkv``); tiles of 128 make every shape here walk several
+    tiles, some crossed by the mask's edge and some not, and skip those
+    the mask empties."""
+
+    @staticmethod
+    def _operands(dtype, sq, sk, g, d=64, seed=0):
+        import jax.numpy as jnp
+
+        rng = np.random.RandomState(seed)
+        mk = lambda *shape: jnp.asarray(
+            rng.randn(*shape).astype(np.float32) * 0.4).astype(dtype)
+        return (mk(1, 4, sq, d), mk(1, 4 // g, sk, d), mk(1, 4 // g, sk, d),
+                mk(1, 4, sq, d))
+
+    @pytest.mark.parametrize("g", [1, 4])
+    @pytest.mark.parametrize("sq,sk", [(256, 256), (128, 384)])
+    @pytest.mark.parametrize("causal", [False, True])
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    def test_grads_match_composition(self, dtype, causal, sq, sk, g):
+        from paddle_tpu.ops.pallas import flash_attention as fa
+
+        q, k, v, do = self._operands(dtype, sq, sk, g)
+        kw = dict(causal=causal, scale=64 ** -0.5, rate=0.0, has_bias=False,
+                  interpret=True)
+        out, lse = fa._fwd_local(q, k, v, blocks=(128, 128), **kw)
+        got = fa._bwd_local(q, k, v, out, lse, do, blocks=(128, 128), **kw)
+        ref, want = _composition_vjp(q, k, v, do, causal)
+        tol = 3e-3 if dtype == "float32" else 4e-2
+        np.testing.assert_allclose(np.asarray(out, np.float32), ref,
+                                   rtol=tol, atol=tol)
+        for a, b in zip(got, want):
+            assert a.shape == b.shape and a.dtype == q.dtype
+            np.testing.assert_allclose(np.asarray(a, np.float32), b,
+                                       rtol=tol, atol=tol)
+
+    @pytest.mark.parametrize("causal", [False, True])
+    def test_dropout_takes_the_forwards_bits(self, causal):
+        """The forward walks (256, 128) tiles and the backward (128, 256)
+        transposed ones: both regenerate the bits of the global row and
+        column, which the oracle applies as a dense mask."""
+        import jax.numpy as jnp
+        from paddle_tpu.ops.pallas import flash_attention as fa
+
+        rate, s = 0.3, 256
+        q, k, v, do = self._operands("float32", s, s, 1, seed=1)
+        seed = jnp.array([77], jnp.int32)
+        kw = dict(causal=causal, scale=0.125, rate=rate, has_bias=False,
+                  interpret=True)
+        out, lse = fa._fwd_local(q, k, v, seed, blocks=(256, 128), **kw)
+        got = fa._bwd_local(q, k, v, out, lse, do, seed, blocks=(128, 256),
+                            **kw)
+        keep = jnp.stack([fa._dropout_keep(seed[0], bh, 0, 0, s, s, rate)
+                          for bh in range(4)])[None] / (1.0 - rate)
+        ref, want = _composition_vjp(q, k, v, do, causal, keep=keep)
+        np.testing.assert_allclose(np.asarray(out), ref, rtol=3e-3, atol=3e-3)
+        for a, b in zip(got, want):
+            np.testing.assert_allclose(np.asarray(a), b, rtol=3e-3,
+                                       atol=3e-3)
+
+    @pytest.mark.parametrize("rows", [1, 2])
+    def test_key_bias(self, rows):
+        """The bias reaches the transposed tile as a [block_k, 1] column;
+        a padded key (-inf) gets no weight and no gradient."""
+        import jax.numpy as jnp
+        from paddle_tpu.ops.pallas import flash_attention as fa
+
+        rng = np.random.RandomState(4)
+        q, k, v, do = (jnp.asarray(rng.randn(2, 2, 256, 64)
+                                   .astype(np.float32) * 0.4)
+                       for _ in range(4))
+        bias = jnp.asarray(rng.randn(rows, 256).astype(np.float32))
+        bias = bias.at[:, 200:].set(-jnp.inf)
+        kw = dict(causal=False, scale=0.125, rate=0.0, has_bias=True,
+                  interpret=True)
+        out, lse = fa._fwd_local(q, k, v, bias, blocks=(128, 128), **kw)
+        got = fa._bwd_local(q, k, v, out, lse, do, bias, blocks=(128, 128),
+                            **kw)
+        ref, want = _composition_vjp(q, k, v, do, False, bias=bias)
+        np.testing.assert_allclose(np.asarray(out), ref, rtol=3e-3, atol=3e-3)
+        for a, b in zip(got, want):
+            np.testing.assert_allclose(np.asarray(a), b, rtol=3e-3,
+                                       atol=3e-3)
+        assert not np.asarray(got[1])[:, :, 200:].any()
+
+    @pytest.mark.parametrize("g", [1, 2])
+    def test_model_layout_entry_is_the_transposed_one_bit_for_bit(self, g):
+        """Heads of 128: ``flash_attention_bshd`` / ``_flash_vjp`` block
+        the [B, S, H*D] view; the head-major entries on swapped operands
+        run the same tiles."""
+        import jax.numpy as jnp
+        from paddle_tpu.ops.pallas import flash_attention as fa
+
+        q, k, v, do = self._operands("float32", 256, 256, g, d=128, seed=2)
+        kw = dict(causal=True, scale=128 ** -0.5)
+        out, lse = fa._flash_fwd_bhsd(q, k, v, **kw)
+        grads = fa._flash_bwd_bhsd(q, k, v, out, lse, do, **kw)
+        sw = lambda x: jnp.swapaxes(x, 1, 2)
+        before = fa._M_TRACED.value(**{"pass": "bwd", "form": "single",
+                                       "layout": "bshd"})
+        out_m, lse_m = fa.flash_attention_bshd(sw(q), sw(k), sw(v), **kw)
+        grads_m = fa._flash_vjp((sw(do),), (sw(q), sw(k), sw(v), out_m,
+                                            lse_m), **kw)
+        np.testing.assert_array_equal(np.asarray(sw(out_m)), np.asarray(out))
+        np.testing.assert_array_equal(np.asarray(lse_m), np.asarray(lse))
+        for a, b in zip(grads_m, grads):
+            np.testing.assert_array_equal(np.asarray(sw(a)), np.asarray(b))
+        # a cached trace counts nothing: at most one more, never fewer
+        assert fa._M_TRACED.value(**{"pass": "bwd", "form": "single",
+                                     "layout": "bshd"}) >= before
+
+    def test_heads_of_64_go_head_major(self):
+        from paddle_tpu.ops.pallas import flash_attention as fa
+
+        assert fa._reads_bshd(128, 256) and not fa._reads_bshd(64)
+        assert not fa._reads_bshd(192, 128)
+
+    @pytest.mark.parametrize("causal", [False, True])
+    def test_over_the_budget_takes_the_split_form_and_agrees(
+            self, causal, monkeypatch):
+        """The rule reads the shapes: a [Sq, D] float32 accumulator over
+        the budget keeps the two kernels. (The budget is shrunk here; at
+        its own size the sequence would be 16,384 at heads of 64.)"""
+        from paddle_tpu.ops.pallas import flash_attention as fa
+
+        q, k, v, do = self._operands("float32", 256, 256, 2, seed=3)
+        kw = dict(causal=causal, scale=0.125, rate=0.0, has_bias=False,
+                  interpret=True)
+        out, lse = fa._fwd_local(q, k, v, **kw)
+        assert fa._single_pass_fits(256, 64)
+        single = fa._bwd_local(q, k, v, out, lse, do, blocks=(128, 128), **kw)
+        monkeypatch.setattr(fa, "_DQ_ACC_BYTES", 256 * 64 * 4 - 1)
+        assert not fa._single_pass_fits(256, 64)
+        labels = {"pass": "bwd", "form": "split", "layout": "bhsd"}
+        before = fa._M_TRACED.value(**labels)
+        split = fa._bwd_local(q, k, v, out, lse, do, **kw)
+        assert fa._M_TRACED.value(**labels) == before + 1
+        for a, b in zip(single, split):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-4, atol=2e-5)
+        with pytest.raises(ValueError, match="head-major"):
+            fa._bwd_local(*(x.swapaxes(1, 2) for x in (q, k, v, out)), lse,
+                          do.swapaxes(1, 2), layout="bshd", **kw)
+
+    @pytest.mark.parametrize("case,tiles", [
+        # (nq, nk, block_q, block_k, offset, causal, window, k_major)
+        ((4, 4, 512, 512, 0, True, None, False), 10),    # the train cell
+        ((4, 8, 512, 256, 0, True, None, False), 20),
+        ((4, 4, 512, 512, 0, True, None, True), 10),
+        ((4, 4, 512, 512, 0, False, None, False), 16),
+        ((4, 16, 512, 128, 0, True, 128, False), 19),     # a band of 128
+        ((2, 1, 128, 128, -128, True, None, False), 2),  # queries before
+    ])
+    def test_tile_list(self, case, tiles):
+        """Only the pairs the mask leaves anything of, each run's first
+        and last flagged."""
+        from paddle_tpu.ops.pallas import flash_attention as fa
+
+        nq, nk, bq, bk, offset, causal, window, k_major = case
+        qi, kj, fl = (np.asarray(x) for x in fa._tile_list(*case))
+        assert len(qi) == len(kj) == len(fl) == tiles
+        rows = np.arange(nq * bq)[:, None] + offset
+        cols = np.arange(nk * bk)[None, :]
+        seen = np.ones((nq * bq, nk * bk), bool)
+        if causal:
+            seen = cols <= rows
+            if window is not None:
+                seen &= cols > rows - window
+        listed = set(zip(qi.tolist(), kj.tolist()))
+        assert len(listed) == tiles
+        wanted = {(i, j) for i in range(nq) for j in range(nk)
+                  if seen[i * bq:(i + 1) * bq, j * bk:(j + 1) * bk].any()}
+        # a block the mask empties keeps one pair, for its outputs
+        assert wanted <= listed and len(listed - wanted) <= 1
+        major = kj if k_major else qi
+        assert sorted(major) == list(major)
+        starts = np.flatnonzero(np.diff(major, prepend=-1))
+        assert [bool(f & fa._FIRST) for f in fl] == [
+            t in starts for t in range(tiles)]
+        assert [bool(f & fa._LAST) for f in fl] == [
+            t + 1 in starts or t + 1 == tiles for t in range(tiles)]
+
+    def test_queries_before_the_first_key_read_zero(self):
+        """Sq > Sk under the bottom-right-aligned mask: the first rows
+        see no key; their output and gradients are zero, lse -inf."""
+        from paddle_tpu.ops.pallas import flash_attention as fa
+
+        q, k, v, do = self._operands("float32", 256, 128, 1, seed=5)
+        kw = dict(causal=True, scale=0.125, rate=0.0, has_bias=False,
+                  interpret=True)
+        out, lse = fa._fwd_local(q, k, v, blocks=(128, 128), **kw)
+        got = fa._bwd_local(q, k, v, out, lse, do, blocks=(128, 128), **kw)
+        assert not np.asarray(out)[:, :, :128].any()
+        assert np.isneginf(np.asarray(lse)[:, :, :128]).all()
+        assert not np.asarray(got[0])[:, :, :128].any()
+        ref, want = _composition_vjp(q[:, :, 128:], k, v, do[:, :, 128:],
+                                     True)
+        np.testing.assert_allclose(np.asarray(out)[:, :, 128:], ref,
+                                   rtol=3e-3, atol=3e-3)
+        for a, b in zip(got, want):
+            a = np.asarray(a)
+            a = a[:, :, 128:] if a.shape[2] == 256 else a
+            np.testing.assert_allclose(a, b, rtol=3e-3, atol=3e-3)
+
+
+# ---------------------------------------------------------------------------
 # sharded programs: the kernels run per shard (ops/kernel_partition.py)
 # ---------------------------------------------------------------------------
 class TestShardedProgram:
