@@ -31,10 +31,10 @@ Three consumers sit on top:
   worst-latency requests keep their full span trees with a per-phase
   breakdown ("p99 request spent 82% in queue"), attached to SLO-breach
   flight dumps by ``observability/slo.py``.
-- **Step and request records** (:func:`ring`): what the engine and
-  ``jit.to_static`` measure of every step and request, tracer or not,
-  in bounded rings that outlive them — read by the benchmark's
-  per-layer metrics.
+- **Step, request and program records** (:func:`ring`): what the engine
+  and ``jit.to_static`` measure of every step, request and dispatched
+  program, tracer or not, in bounded rings that outlive them — read by
+  the benchmark's per-layer metrics.
 - **Decode-gap accounting**: host-side time between consecutive decode
   steps while slots were runnable (``trace.decode_gap_seconds``) — the
   signal behind the ROADMAP's fused-decode item, linted as PTL404 by
@@ -106,20 +106,47 @@ M_OVERHEAD = registry.gauge(
     "bench tracing-overhead guard (PTL402 above tolerance)")
 
 
-# --- step and request records: always on, bounded, outlive their owner ---
+# --- step, request and program records: always on, bounded, and they ---
+# --- outlive their owner                                              ---
 
-#: entries a ring keeps; at a step of 100 ms that is the last 7 minutes
-RING_LEN = 4096
+#: entries a ring keeps: 49 s of 3 ms steps back to back, so a benchmark
+#: window of 50 s fits whole at the shortest step a cell has (6,267 steps
+#: a window where the engine idles half of it). A full ring of an engine's
+#: steps is 25 MB of host memory (a decode step's record is 1.5 KB, its
+#: five phase spans half of that), one of its programs 14 MB, one of
+#: requests 11 MB: 50 MB an engine at most
+RING_LEN = 16384
 
 _rings: Dict[tuple, collections.deque] = {}
 
 
 def ring(owner: str, kind: str) -> collections.deque:
-    """The bounded ring of ``kind`` records (``"steps"``, ``"requests"``)
-    kept under ``owner`` — an engine's ``name``, ``jit.<function>`` —
-    created on first use. Like the registry's series it is keyed by name
-    and outlives the object that fills it, so a reader can ask for it
-    after the engine is freed; two owners of one name share it."""
+    """The bounded ring of ``kind`` records (``"steps"``, ``"requests"``,
+    ``"programs"``) kept under ``owner`` — an engine's ``name``,
+    ``jit.<function>`` — created on first use. Like the registry's series
+    it is keyed by name and outlives the object that fills it, so a
+    reader can ask for it after the engine is freed; two owners of one
+    name share it. All times are the owner's clock's. An engine's
+    records (``serve/engine.py`` fills them from its spans' clock pairs):
+
+    - ``"steps"``, one a ``step()``: ``step`` (its index), ``begin``,
+      ``end``, ``seconds`` by phase (``STEP_PHASES``) and ``spans``, the
+      phases' intervals ``(phase, start, end)`` in the order they began
+      (``admit`` holds the ``prefill`` dispatches inside it);
+    - ``"requests"``, one a ``submit()``: ``id``, ``submit``, ``admit``,
+      ``first_token``, ``finish``, ``warmup``;
+    - ``"programs"``, one a dispatched program, appended when its result
+      is on the host. ``kind`` ``"decode"``: ``step`` (the step that
+      dispatched it), ``read_step`` (the step that read it), ``rows``
+      (live rows), ``dispatch`` and ``dispatched`` (the span
+      ``serve.decode.dispatch``), ``read`` and ``tokens`` (the span
+      ``serve.decode.wait``), ``overlapped`` (it went out behind a
+      program still unread), and ``ticks`` for a fused burst. ``kind``
+      ``"prefill"``: ``request``, ``bucket``, ``tokens``, ``step``,
+      ``dispatch`` and ``dispatched`` (the prompt's first
+      ``serve.prefill`` span), ``read`` and ``tokens_at`` (its second:
+      the wait for the logits and the first token's sampling; both None
+      for a resumed stream, whose logits nobody reads)."""
     r = _rings.get((owner, kind))
     if r is None:
         r = _rings[(owner, kind)] = collections.deque(maxlen=RING_LEN)

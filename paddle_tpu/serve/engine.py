@@ -112,6 +112,7 @@ steps are all fed from that one measurement.
 from __future__ import annotations
 
 import collections
+import operator
 import os
 from functools import partial
 import time
@@ -261,6 +262,8 @@ FINISHED = "FINISHED"
 #: the step (gauges, SLO and health hooks, the clock reads themselves)
 STEP_PHASES = ("admit", "prefill", "ensure_blocks", "dispatch", "wait",
                "emit", "other")
+#: orders a step record's ``spans``, each ``(phase, start, end)``
+_span_start = operator.itemgetter(1)
 
 
 @dataclass
@@ -329,7 +332,7 @@ class _Program:
     nxt: object                  # [max_slots] tokens, on the device
     sizes: object                # the sparse layers' group sizes, flat
     reqs: List[Optional[Request]]   # by slot: whose row it computes
-    start: float                 # when its dispatch began
+    record: dict                 # its entry-to-be in the ring of programs
     state: object                # the slot state it leaves, on the device
     active: np.ndarray           # [max_slots] bool: the rows it computes
 
@@ -568,15 +571,17 @@ class ServeEngine:
         self._n_steps = 0
         self._step_ring = obs.tracing.ring(self.name, "steps")
         self._request_ring = obs.tracing.ring(self.name, "requests")
-        # seconds by phase of the step under way (a prefill outside any
-        # step, as warm-up makes them, adds to a dict nobody reads)
+        self._program_ring = obs.tracing.ring(self.name, "programs")
+        # seconds by phase of the step under way, and its phases' spans
+        # as they ran (``_phase``)
         self._secs = dict.fromkeys(STEP_PHASES, 0.0)
+        self._spans: List[tuple] = []
         self._key = jax.random.PRNGKey(seed)
         self._rng = np.random.default_rng(seed)
         #: the decode program dispatched and not yet read, if any
         self._inflight: Optional[_Program] = None
         #: prefills whose first token is still to be read: (request,
-        #: logits on the device, when the prefill's dispatch began)
+        #: logits on the device, the program's record-to-be)
         self._first_tokens: List[tuple] = []
         # what a program takes for "the tokens before" with none in flight
         self._no_tokens = jnp.zeros(self.max_slots, jnp.int32)
@@ -715,11 +720,13 @@ class ServeEngine:
             not r.warmup for r in self._live_requests())
         tok0, pre0 = self._n_tokens, self._n_preempts
         secs = self._secs = dict.fromkeys(STEP_PHASES, 0.0)
+        spans = self._spans = []
         with self._span("serve.step", step=self._n_steps,
                         queued=len(self.queue)) as whole:
             with self._span("serve.admit") as sp:
                 sp.note(admitted=self._admit())
-            secs["admit"] = sp.seconds - secs["prefill"]
+            self._phase("admit", sp)
+            secs["admit"] -= secs["prefill"]
             n_active = self.n_active
             if self.decode_burst > 1:
                 # a burst carries its streams' tokens on from the host's
@@ -752,15 +759,27 @@ class ServeEngine:
                                  preemptions=self._n_preempts - pre0,
                                  now=self._clock())
             obs.health.maybe_on_step(self._clock())
-        self._n_steps += 1
         secs["other"] = whole.seconds - sum(secs.values())
+        # a span is put down as it closes: an admission after the
+        # prefills inside it
+        spans.sort(key=_span_start)
         self._step_ring.append(
-            {"begin": whole.start, "end": whole.end, "seconds": secs})
+            {"step": self._n_steps, "begin": whole.start, "end": whole.end,
+             "seconds": secs, "spans": spans})
+        self._n_steps += 1
         return n_active
 
     def _span(self, name: str, **attrs):
         """A phase of the step, on the engine's clock."""
         return obs.span(name, clock=self._clock, **attrs)
+
+    def _phase(self, phase: str, sp):
+        """Put a closed span down to ``phase`` (one of ``STEP_PHASES``) of
+        the step under way: its seconds and its interval. Outside any
+        step, as nothing of the engine's runs, it would add to a record
+        that nobody reads."""
+        self._secs[phase] += sp.seconds
+        self._spans.append((phase, sp.start, sp.end))
 
     def _live_requests(self):
         for r in self.queue:
@@ -998,13 +1017,17 @@ class ServeEngine:
             # whoever mounts them runs after it (a later request of this
             # very admission pass shares them, as it always could)
             self._register_full_blocks(req, written=len(prefill_ids))
-        self._secs["prefill"] += sp.seconds
+        self._phase("prefill", sp)
+        record = {"kind": "prefill", "request": req.id, "bucket": bucket,
+                  "tokens": n, "step": self._n_steps, "dispatch": sp.start,
+                  "dispatched": sp.end, "read": None, "tokens_at": None}
         if req.n_generated == 0:
             # fresh stream: its FIRST token comes from these logits
-            self._first_tokens.append((req, logits, sp.start))
+            self._first_tokens.append((req, logits, record))
             return
         # resumed streams already hold their pending token, the logits
-        # are discarded
+        # are discarded: nobody reads this program's result
+        self._program_ring.append(record)
         _M_PREFILL_SECONDS.observe(sp.seconds, engine=self.name)
         if self.tracer is not None:
             self.tracer.on_decode_begin(req)
@@ -1015,7 +1038,7 @@ class ServeEngine:
         token on the host (this is the TTFT moment). A second
         ``serve.prefill`` span a prompt: the wait for its program."""
         pending, self._first_tokens = self._first_tokens, []
-        for req, logits, start in pending:
+        for req, logits, record in pending:
             with self._span("serve.prefill", request=req.id,
                             first_token=True) as sp:
                 tok = self._sample_host(np.asarray(logits),
@@ -1034,8 +1057,11 @@ class ServeEngine:
                 self._append_token(req, tok)
                 if req.state is not FINISHED:
                     self._tokens[slot] = tok
-            _M_PREFILL_SECONDS.observe(sp.end - start, engine=self.name)
-            self._secs["prefill"] += sp.seconds
+            _M_PREFILL_SECONDS.observe(sp.end - record["dispatch"],
+                                       engine=self.name)
+            self._phase("prefill", sp)
+            record.update(read=sp.start, tokens_at=sp.end)
+            self._program_ring.append(record)
             if self.tracer is not None and req.state is not FINISHED:
                 self.tracer.on_decode_begin(req)
 
@@ -1267,17 +1293,24 @@ class ServeEngine:
             for out in (nxt, sizes):
                 out.copy_to_host_async()
             self._lens[active] += 1
-        self._dispatched(dispatch)
         if prev is not None:
             _M_DECODE_OVERLAPPED.inc(engine=self.name)
-        return _Program(nxt, sizes, list(rows), dispatch.start, state,
-                        active)
+        return _Program(nxt, sizes, list(rows),
+                        self._dispatched(dispatch, active, prev is not None),
+                        state, active)
 
-    def _dispatched(self, dispatch, n: int = 1):
-        """Count one dispatch of ``n`` decode ticks."""
-        self._secs["dispatch"] += dispatch.seconds
+    def _dispatched(self, dispatch, active, overlapped: bool,
+                    n: int = 1) -> dict:
+        """Count one dispatch of ``n`` decode ticks over the rows
+        ``active``; returns the program's record, which ``_decode_done``
+        completes and rings once its tokens are on the host."""
+        self._phase("dispatch", dispatch)
         _M_DECODE_STEPS.inc(n, engine=self.name)
         _M_HOST_RT.inc(engine=self.name)
+        return {"kind": "decode", "step": self._n_steps, "read_step": None,
+                "rows": int(np.count_nonzero(active)),
+                "dispatch": dispatch.start, "dispatched": dispatch.end,
+                "read": None, "tokens": None, "overlapped": overlapped}
 
     def _read_decode(self, prog: _Program):
         """Block on a dispatched program's tokens and emit them. A row
@@ -1296,7 +1329,7 @@ class ServeEngine:
                 if req.state is not FINISHED:
                     self._tokens[slot] = req.ids[-1]
             emit.note(tokens=self._n_tokens - tok0)
-        self._decode_done(prog.start, wait, emit)
+        self._decode_done(prog.record, wait, emit)
 
     def _count_moe(self, sizes: np.ndarray, n_tokens: int):
         """Feed the ``serve.moe_*`` counters from the held experts' group
@@ -1326,7 +1359,7 @@ class ServeEngine:
         with self._span("serve.ensure_blocks") as sp:
             self._ensure_blocks(lookahead)
             sp.note(preemptions=self._n_preempts - pre0)
-        self._secs["ensure_blocks"] += sp.seconds
+        self._phase("ensure_blocks", sp)
         rows = self._decodable()
         active = np.array([r is not None for r in rows], bool)
         if active.any():          # a decode step follows
@@ -1345,14 +1378,18 @@ class ServeEngine:
                                engine=self.name)
         return rows
 
-    def _decode_done(self, start: float, wait, emit, n: int = 1):
+    def _decode_done(self, record: dict, wait, emit, n: int = 1):
         """Feed everything that reads one decode program's times, from
         the start of its dispatch to its tokens on the host (the step
         after, for a program that another was dispatched behind): the
-        step record, the histogram and the tracer's engine lane."""
-        secs = self._secs
-        secs["wait"] += wait.seconds
-        secs["emit"] += emit.seconds
+        step record, the program's own record, the histogram and the
+        tracer's engine lane."""
+        self._phase("wait", wait)
+        self._phase("emit", emit)
+        record.update(read_step=self._n_steps, read=wait.start,
+                      tokens=wait.end)
+        self._program_ring.append(record)
+        start = record["dispatch"]
         _M_DECODE_SECONDS.observe(wait.end - start, engine=self.name)
         if self.tracer is not None:
             # active_after = runnable slots LEFT BEHIND by this step —
@@ -1413,6 +1450,8 @@ class ServeEngine:
                 jnp.asarray(active_np), self._table_args(),
                 jnp.asarray(self._temps), jnp.asarray(self._eos),
                 jnp.stack(subs))
+        record = self._dispatched(dispatch, active_np, False, n)
+        record["ticks"] = n
         with self._span("serve.decode.wait") as wait:
             ys = np.asarray(ys)
             emitted = np.asarray(emitted)
@@ -1434,8 +1473,7 @@ class ServeEngine:
                     self._tokens[slot] = req.ids[-1]
             emit.note(tokens=n_emitted)
         _M_BURST_TOKENS.inc(n_emitted, engine=self.name)
-        self._dispatched(dispatch, n)
-        self._decode_done(dispatch.start, wait, emit, n)
+        self._decode_done(record, wait, emit, n)
 
     def warm_burst(self, n: int):
         """Compile the ``n``-step fused burst against idle slot state

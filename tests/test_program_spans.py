@@ -159,7 +159,7 @@ def test_step_record_phases_sum_to_the_step_on_a_fake_clock():
         assert before["end"] <= after["begin"]
     for r in records:
         secs = r["seconds"]
-        assert set(r) == {"begin", "end", "seconds"}
+        assert set(r) == {"step", "begin", "end", "seconds", "spans"}
         assert tuple(secs) == STEP_PHASES
         assert all(v >= 0 for v in secs.values())
         assert sum(secs.values()) == pytest.approx(r["end"] - r["begin"],
@@ -222,6 +222,176 @@ def test_request_records_keep_their_order_through_a_preemption():
             s["end"] - s["begin"], abs=1e-9)
 
 
+# --------------------------------------------------------------------------
+# 2b. a record a dispatched program, and a step's phases as intervals
+# --------------------------------------------------------------------------
+#: name -> (engine options, batches of (prompt length, outputs); the engine
+#: runs dry between two batches, which drains its pipeline)
+PROGRAM_CASES = {
+    "one-stream": ({}, [[(8, 9)]]),
+    "three-streams": ({}, [[(8, 5), (4, 7), (6, 3)]]),
+    "drained": ({}, [[(8, 4)], [(6, 5), (5, 3)]]),
+    "preempted": ({"num_blocks": 7, "max_seq_len": 28},
+                  [[(10, 8), (9, 7), (5, 6)]]),
+    "burst": ({"decode_burst": 4}, [[(8, 9), (5, 6)]]),
+}
+
+
+@pytest.fixture(scope="module", params=sorted(PROGRAM_CASES))
+def ran(request):
+    """(engine, step records, program records) of one case, run to its
+    end on a clock that moves a millisecond a read."""
+    options, batches = PROGRAM_CASES[request.param]
+    name = f"programs-{request.param}"
+    eng = _engine(name, clock=obs.FakeClock(start=50.0, tick=0.001),
+                  **options)
+    rng = np.random.RandomState(2)
+    for batch in batches:
+        for n, k in batch:
+            eng.submit(rng.randint(1, 97, n), max_new_tokens=k)
+        eng.run(max_steps=2000)
+    return (eng, list(tracing.ring(name, "steps")),
+            list(tracing.ring(name, "programs")))
+
+
+def _counter(name, eng, **labels):
+    return obs.registry.get(name).value(engine=eng.name, **labels)
+
+
+def test_one_record_a_program_with_its_four_times_in_order(ran):
+    eng, steps, programs = ran
+    decodes = [p for p in programs if p["kind"] == "decode"]
+    prefills = [p for p in programs if p["kind"] == "prefill"]
+    assert len(decodes) + len(prefills) == len(programs)
+    assert len(decodes) == _counter("serve.host_roundtrips", eng)
+    assert sum(p.get("ticks", 1) for p in decodes) == _counter(
+        "serve.decode_steps", eng)
+    # a prompt's program, and one more each time a stream came back
+    assert len(prefills) == len(eng.finished) + eng._n_preempts
+    for p in decodes:
+        assert list(p)[:9] == ["kind", "step", "read_step", "rows",
+                               "dispatch", "dispatched", "read", "tokens",
+                               "overlapped"]
+        assert p["dispatch"] <= p["dispatched"] <= p["read"] <= p["tokens"]
+        assert 1 <= p["rows"] <= eng.max_slots
+    for p in prefills:
+        assert list(p) == ["kind", "request", "bucket", "tokens", "step",
+                           "dispatch", "dispatched", "read", "tokens_at"]
+        assert p["tokens"] <= p["bucket"]
+        if p["read"] is None:       # a resumed stream's: nobody reads it
+            assert p["tokens_at"] is None and eng._n_preempts
+        else:
+            assert (p["dispatch"] <= p["dispatched"] <= p["read"]
+                    <= p["tokens_at"])
+    fresh = [p for p in prefills if p["read"] is not None]
+    assert sorted(p["request"] for p in fresh) == sorted(
+        r.id for r in eng.finished)
+    # the pair that the histogram is fed from is the record's
+    hist = obs.registry.get("serve.decode_step_seconds").stats(
+        engine=eng.name)
+    assert hist["sum"] == pytest.approx(
+        sum(p["tokens"] - p["dispatch"] for p in decodes), abs=1e-6)
+
+
+def test_overlapped_says_whether_a_program_went_out_behind_another(ran):
+    eng, steps, programs = ran
+    decodes = [p for p in programs if p["kind"] == "decode"]
+    assert not decodes[0]["overlapped"]
+    for before, after in zip(decodes, decodes[1:]):
+        assert before["dispatch"] < after["dispatch"]
+        # behind another: dispatched before that one's tokens were read
+        assert after["overlapped"] == (after["dispatch"] < before["tokens"])
+    assert sum(p["overlapped"] for p in decodes) == (
+        _counter("serve.decode_overlapped", eng) or 0)
+    drains = sum(_counter("serve.pipeline_drains", eng, reason=r) or 0
+                 for r in ("preempt", "burst", "idle"))
+    alone = sum(not p["overlapped"] for p in decodes)
+    if eng.decode_burst > 1:
+        assert alone == len(decodes) and not drains
+    else:
+        # the first program, and the first after each drain
+        assert 1 <= alone <= 1 + drains
+        assert len(decodes) > alone
+
+
+def test_step_and_read_step_point_at_the_steps_that_hold_the_spans(ran):
+    eng, steps, programs = ran
+    assert [s["step"] for s in steps] == list(range(eng._n_steps))
+    for p in programs:
+        step = steps[p["step"]]
+        assert step["begin"] <= p["dispatch"] <= p["dispatched"] \
+            <= step["end"]
+        if p["kind"] == "prefill":
+            if p["read"] is not None:     # read at the end of its own step
+                assert p["dispatched"] <= p["read"] <= p["tokens_at"] \
+                    <= step["end"]
+            continue
+        # one program in flight: read by the step after the one that
+        # dispatched it; a burst is read where it is dispatched
+        assert p["read_step"] - p["step"] == (0 if "ticks" in p else 1)
+        read = steps[p["read_step"]]
+        assert read["begin"] <= p["read"] <= p["tokens"] <= read["end"]
+        assert ("wait", p["read"], p["tokens"]) in read["spans"]
+        assert ("dispatch", p["dispatch"], p["dispatched"]) in step["spans"]
+
+
+def test_a_steps_spans_add_up_to_its_seconds_by_phase(ran):
+    eng, steps, programs = ran
+    for s in steps:
+        spans = s["spans"]
+        assert spans == sorted(spans, key=lambda x: x[1])
+        assert spans[0][0] == "admit"
+        by_phase = dict.fromkeys(STEP_PHASES, 0.0)
+        for phase, start, end in spans:
+            assert s["begin"] <= start <= end <= s["end"]
+            by_phase[phase] += end - start
+        _, a0, a1 = spans[0]
+        # an admission's span holds the dispatches of its prompts
+        by_phase["admit"] -= sum(
+            end - start for phase, start, end in spans[1:]
+            if phase == "prefill" and a0 <= start and end <= a1)
+        assert by_phase.pop("other") == 0.0
+        other = s["seconds"]["other"]
+        assert {p: v for p, v in s["seconds"].items() if p != "other"} \
+            == pytest.approx(by_phase, abs=1e-9)
+        assert sum(by_phase.values()) + other == pytest.approx(
+            s["end"] - s["begin"], abs=1e-9)
+    n_prefill = sum(phase == "prefill" for s in steps
+                    for phase, _, _ in s["spans"])
+    fresh = sum(p["kind"] == "prefill" and p["read"] is not None
+                for p in programs)
+    assert n_prefill == len(programs) - sum(
+        p["kind"] == "decode" for p in programs) + fresh
+
+
+def test_the_counters_tool_names_the_longest_programs_and_their_steps(ran):
+    from tools.serve_counters import programs, slowest
+
+    eng, steps, records = ran
+    out = programs(eng.name)
+    decodes = [p for p in records if p["kind"] == "decode"]
+    assert out["programs_in_ring"] == len(records)
+    assert out["decode_programs"] == len(decodes)
+    times = sorted(p["tokens"] - p["dispatch"] for p in decodes)
+    assert out["dispatch_to_tokens_ms"]["p100"] == pytest.approx(
+        times[-1] * 1e3, abs=1e-3)
+    assert out["dispatch_to_tokens_ms"]["p5"] <= \
+        out["dispatch_to_tokens_ms"]["p50"] <= \
+        out["dispatch_to_tokens_ms"]["p99"]
+    longest = out["longest_programs"]
+    assert len(longest) == min(5, len(records))
+    assert [p["ms"] for p in longest] == sorted(
+        (p["ms"] for p in longest), reverse=True)
+    for p in longest:
+        assert 0 <= p["step"] <= p["read_step"] < len(steps)
+        assert ("rows" in p) == (p["kind"] == "decode")
+        assert ("request" in p) == (p["kind"] == "prefill")
+    # the steps it points at are named by the same index in the other list
+    assert {s["step"] for s in slowest(eng.name)["longest_steps"]} <= set(
+        range(len(steps)))
+    assert programs("no-such-engine") == {}
+
+
 def test_rings_are_bounded_and_outlive_the_engine():
     ring = tracing.ring("spans-bounded", "steps")
     assert ring is tracing.ring("spans-bounded", "steps")
@@ -229,6 +399,9 @@ def test_rings_are_bounded_and_outlive_the_engine():
     for i in range(tracing.RING_LEN + 10):
         ring.append({"step": i})
     assert len(ring) == tracing.RING_LEN and ring[0]["step"] == 10
+    # the benchmark's shortest steps: 6,267 in a window of the prefill
+    # cell, 49 s of them back to back at 3 ms
+    assert tracing.RING_LEN >= 2 * 6267 and tracing.RING_LEN * 0.003 > 49
 
     eng = _engine("spans-outlive")
     eng.submit(np.arange(1, 6), max_new_tokens=3, warmup=True)

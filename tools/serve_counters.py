@@ -22,10 +22,13 @@ under a group-limited router, the tokens whose kept groups include the
 held one (3/8 in expectation for one group of eight, three kept). A
 checkout from before a counter has it, and a model without such layers,
 prints null there.
-Then, from the engine's step ring (its last 4,096 steps), the five longest
+Then, from the engine's step ring (its last 16,384 steps), the five longest
 steps with their seconds by phase and the five longest gaps between two
-steps (the caller's time): where a one-off stall of seconds lies (ROADMAP
-S8), if the run held one. Judges nothing.
+steps (the caller's time), and from its ring of programs the percentiles of
+a decode program's time from dispatch to tokens and the five longest
+programs of either kind, each with the step that dispatched it and the step
+that read it: where a one-off stall of seconds lies (ROADMAP S8), if the
+run held one, and whether a program or the host held it. Judges nothing.
 """
 import json
 import os
@@ -106,7 +109,8 @@ def slowest(engine: str, k: int = 5) -> dict:
     return {
         "steps_in_ring": len(steps),
         "longest_steps": [
-            {"before_end_s": round(t1 - r["begin"], 3),
+            {"step": r.get("step"),
+             "before_end_s": round(t1 - r["begin"], 3),
              "ms": round((r["end"] - r["begin"]) * 1e3, 2),
              "phases_ms": {p: round(v * 1e3, 2)
                            for p, v in r["seconds"].items() if v > 5e-4}}
@@ -115,6 +119,44 @@ def slowest(engine: str, k: int = 5) -> dict:
             {"before_end_s": round(t1 - a["end"], 3),
              "ms": round((b["begin"] - a["end"]) * 1e3, 2)}
             for a, b in reversed(gaps)]}
+
+
+def programs(engine: str, k: int = 5) -> dict:
+    """Of the ring's decode programs the percentiles of dispatch-to-tokens
+    in milliseconds, and the ``k`` longest programs of either kind (a
+    prompt's runs from its dispatch to its first token) with the steps that
+    dispatched and read them; each step's own record is in ``slowest``'s
+    list if the step was long too."""
+    import numpy as np
+
+    from paddle_tpu.observability import tracing
+
+    def whole(r):
+        end = r["tokens"] if r["kind"] == "decode" else r["tokens_at"]
+        return (end if end is not None else r["dispatched"]) - r["dispatch"]
+
+    ring = list(tracing.ring(engine, "programs"))
+    decodes = [whole(r) * 1e3 for r in ring if r["kind"] == "decode"]
+    if not decodes:
+        return {}
+    t1 = max(r["dispatched"] for r in ring)
+    return {
+        "programs_in_ring": len(ring), "decode_programs": len(decodes),
+        "dispatch_to_tokens_ms": {
+            f"p{q}": round(float(np.percentile(decodes, q)), 3)
+            for q in (5, 50, 95, 99, 100)},
+        "overlapped_share": round(sum(
+            r["overlapped"] for r in ring if r["kind"] == "decode")
+            / len(decodes), 4),
+        "longest_programs": [
+            {"kind": r["kind"], "ms": round(whole(r) * 1e3, 2),
+             "before_end_s": round(t1 - r["dispatch"], 3),
+             "step": r["step"], "read_step": r.get("read_step", r["step"]),
+             **{key: r[key] for key in ("rows", "ticks", "request", "bucket")
+                if key in r},
+             "dispatch_ms": round((r["dispatched"] - r["dispatch"]) * 1e3,
+                                  2)}
+            for r in sorted(ring, key=whole, reverse=True)[:k]]}
 
 
 def main(argv=None):
@@ -126,6 +168,8 @@ def main(argv=None):
     print("serve counters: " + json.dumps(counters(cell)), file=sys.stderr,
           flush=True)
     print("serve slowest: " + json.dumps(slowest(cell)), file=sys.stderr,
+          flush=True)
+    print("serve programs: " + json.dumps(programs(cell)), file=sys.stderr,
           flush=True)
     return rc
 
