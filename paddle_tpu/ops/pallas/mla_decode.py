@@ -21,9 +21,28 @@ zeros. What differs follows from the arithmetic. A page of 128 rows is
 128 heads that is 242 FLOPs a byte, the v5e's ridge, so the products go
 to the MXU in the pool's own type (bfloat16 operands, float32
 accumulation; the softmax state is float32) and a turn takes ``TURN``
-pages at once, one product over all of them, so that the loop's fixed
-cost a turn (the scalar work, starting and awaiting the copies) is paid
-once for ``TURN x 128`` rows.
+pages at once, so that the loop's fixed cost a turn (the scalar work,
+starting and awaiting the copies) is paid once for ``TURN x 128`` rows.
+
+What a turn computes follows what it holds (PR 36). A turn that ends at
+or under the row's length (every turn of a stream but its last) carries
+no mask: no iota, compare or select on its scores. The last turn is
+compiled once for each number of pages it can hold, 1 to ``TURN``, and
+runs the body of its size, masked: its products run over the pages it
+holds, not over ``TURN`` of them. Within a turn the softmax goes by
+blocks of ``BLOCK`` pages, and a block's score product stands in the
+program BEFORE the softmax of the block before it: the MXU returns its
+results in program order, so what is written between two products waits
+for the first and holds up the second, and written this way the vector
+unit's work on one block runs under the MXU's on the next. The scale
+(positive) is on no operand and costs no operation: the scores and their
+running maximum stay unscaled in float32 and ``exp(scale * x)`` is
+``2 ** (x * (scale * log2 e))``, the multiply an exponential makes
+anyway, so ``q`` is rounded to bfloat16 once as before. The copies a turn
+starts are chosen by selects, not by branches, and ``q`` is read inside
+each body: a value read once above the bodies is loaded and spilled anew
+for every one of them in every row (nine bodies: 720 loads and stores a
+row).
 
 The pool is ``[1, pages, page_size, lanes]`` with ``lanes`` a whole
 number of 128-lane tiles (the engine pads a row with zeros: a row of 576
@@ -49,17 +68,26 @@ from jax.experimental.pallas import tpu as pltpu
 from .flash_attention import Z
 from .paged_attention import _for, resolve_backend
 
-__all__ = ["mla_decode", "mla_decode_kernel", "mla_decode_reference"]
+__all__ = ["mla_decode", "mla_decode_kernel", "mla_decode_reference",
+           "pages_computed"]
 
 _i32 = np.int32
 
-#: pages a turn of the loop takes. A call's time fits ``0.33 us a page +
-#: 0.47 us a turn`` at the DeepSeek-V2 cell's shapes (1, 2, 4 and 8 pages
-#: a turn read 2.25, 1.59, 1.28 and 1.08 ms for 360k rows of 192 streams:
-#: PERF.md §6, PR 33), against 0.18 us a page at either peak; past 8 the
-#: turn's share is small and a stream's last turn, computed whole
-#: whatever it holds, wastes more.
+#: pages a turn of the loop takes: the unit of the copies. A call's time
+#: fits ``0.18 us a page + 0.57 us a turn + 0.68 us a stream`` at the
+#: DeepSeek-V2 cell's shapes (``tools/mla_kernel_probe.py`` over 2, 4 and
+#: 8 pages a turn: PERF.md §6, PR 36; ``0.33 us a page + 0.47 us a turn``
+#: before it, PR 33), against 0.18 us a page at the MXU's peak and 0.20 at
+#: the HBM's: what is left is a turn's and a stream's fixed cost, which a
+#: longer turn spreads wider and a longer last turn's code pays for.
 TURN = 8
+
+#: pages of a block, the unit of the softmax within a turn: two blocks a
+#: turn, the second's score product under way while the vector unit is on
+#: the first. Blocks of two pages schedule 9% worse (the accumulator's
+#: rescale, once a block, is bound by the one store a cycle), a turn in
+#: one block 11% (nothing overlaps): my chip runs, PR 36.
+BLOCK = 4
 
 
 def _check_shapes(q, pool, lengths, block_tables, dv):
@@ -102,6 +130,29 @@ def mla_decode_reference(q, pool, lengths, block_tables, *, dv,
                       rows[..., :dv].astype(jnp.float32)).astype(q.dtype)
 
 
+def _scores(q, k):
+    """``[NH, rows]`` float32: the absorbed queries against ``k``'s rows."""
+    return jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _values(p, k, dv):
+    """``[NH, dv]`` float32: the weights ``p`` over the value lanes of
+    ``k``'s rows."""
+    return jax.lax.dot_general(p.astype(k.dtype), k[:, :dv],
+                               (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def pages_computed(lengths, page_size, pages_per_seq):
+    """Pages the kernel's two products run over for streams of these
+    lengths (numpy, ``[B]``): the pages a stream holds and no other,
+    since its last turn runs the body of its own size. What
+    ``serve.mla_pages_computed`` counts."""
+    held = -(-np.asarray(lengths, np.int64) // page_size)
+    return np.minimum(held, pages_per_seq)
+
+
 def _kernel_body(len_ref, tbl_ref, q_ref, pool_hbm, o_ref, buf, sems, turn,
                  m_scr, l_scr, acc_scr, *, page, pps, per_turn, dv, scale):
     """One row of the batch a program. ``buf`` ``[2, per_turn * page,
@@ -112,6 +163,7 @@ def _kernel_body(len_ref, tbl_ref, q_ref, pool_hbm, o_ref, buf, sems, turn,
     b = pl.program_id(0)
     rows = pl.num_programs(0)
     span = per_turn * page
+    rate = np.float32(scale * np.log2(np.e))
 
     def pages_of(r):
         return jnp.minimum(
@@ -120,10 +172,12 @@ def _kernel_body(len_ref, tbl_ref, q_ref, pool_hbm, o_ref, buf, sems, turn,
     def turns_of(r):
         return jax.lax.div(pages_of(r) + _i32(per_turn - 1), _i32(per_turn))
 
-    def each_copy(r, t, slot, fn):
+    def each_copy(r, t, slot, fn, held=None):
         """``fn`` of the copy of each page that turn ``t`` of row ``r``
-        holds, into its place in buffer ``slot``."""
-        held = pages_of(r)
+        holds (of the row's first ``held`` pages, where given), into its
+        place in buffer ``slot``. A page's condition becomes a predicate
+        on its copy, no branch: the eight lie in the caller's block."""
+        held = pages_of(r) if held is None else held
         for j in range(per_turn):
             pg = t * _i32(per_turn) + _i32(j)
             pid = tbl_ref[r, jnp.minimum(pg, _i32(pps - 1))]
@@ -132,16 +186,10 @@ def _kernel_body(len_ref, tbl_ref, q_ref, pool_hbm, o_ref, buf, sems, turn,
                 sems.at[slot, _i32(j)])
             pl.when(pg < held)(functools.partial(fn, copy))
 
-    def start(r, t, slot):
-        each_copy(r, t, slot, lambda copy: copy.start())
-
     @pl.when(b == 0)
     def _first():
         turn[0] = _i32(0)
         turn[1] = _i32(-1)
-        # a turn's unheld pages are masked out of the softmax, but the
-        # value product would still multiply 0 by what lies there
-        buf[...] = jnp.zeros_like(buf)
 
     m_scr[...] = jnp.full(m_scr.shape, -jnp.inf, jnp.float32)
     l_scr[...] = jnp.zeros_like(l_scr)
@@ -154,7 +202,7 @@ def _kernel_body(len_ref, tbl_ref, q_ref, pool_hbm, o_ref, buf, sems, turn,
 
         @pl.when(turn[1] != b)
         def _():                  # the call's first live row
-            start(b, _i32(0), buf0)
+            each_copy(b, _i32(0), buf0, lambda copy: copy.start())
 
         def holds_none(r):
             return (r < rows) & (turns_of(jnp.minimum(r, rows - 1)) == 0)
@@ -163,44 +211,74 @@ def _kernel_body(len_ref, tbl_ref, q_ref, pool_hbm, o_ref, buf, sems, turn,
         # row's last into the free buffer
         nxt = jax.lax.while_loop(holds_none, lambda r: r + _i32(1),
                                  b + _i32(1))
-        length = len_ref[b]
-        q = q_ref[0]                                   # [NH, lanes]
+        length = jnp.minimum(len_ref[b], _i32(pps * page))
+        n_full = jax.lax.div(length, _i32(span))   # turns under length
 
-        def one_turn(t, c):
-            slot = jax.lax.rem(buf0 + t, _i32(2))
-
-            @pl.when(t + 1 < n_turns)
-            def _():
-                start(b, t + _i32(1), _i32(1) - slot)
-
-            @pl.when((t + 1 == n_turns) & (nxt < rows))
-            def _():
-                start(jnp.minimum(nxt, rows - 1), _i32(0), _i32(1) - slot)
-
-            each_copy(b, t, slot, lambda copy: copy.wait())
-            k = buf[slot]                              # [span, lanes]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale   # [NH, span]
-            pos = t * _i32(span) + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)
-            seen = pos < length
-            s = jnp.where(seen, s, -jnp.inf)
+        def update(s, k, base, masked):
+            """The softmax state taken over the rows ``k`` with scores
+            ``s``, the first of them at position ``base``. Every block
+            handed in holds a row under ``length``."""
             m_prev = m_scr[:, :1]
+            if masked:
+                seen = base + jax.lax.broadcasted_iota(
+                    jnp.int32, s.shape, 1) < length
+                s = jnp.where(seen, s, -jnp.inf)
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
-            # m_prev is -inf until the first turn; exp(-inf - -inf)
-            alpha = jnp.where(jnp.isfinite(m_prev),
-                              jnp.exp(m_prev - m_new), 0.0)
+            # exp(scale * x) as 2 ** (x * (scale * log2 e)): the scale
+            # rides the multiply an exponential makes anyway
+            p = jnp.exp2((s - m_new) * rate)
+            if masked:
+                p = jnp.where(seen, p, 0.0)
+            alpha = jnp.exp2((m_prev - m_new) * rate)   # 0 from -inf
             l_new = l_scr[:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-                p.astype(k.dtype), k[:, :dv], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)    # [NH, dv]
+            acc_scr[...] = acc_scr[...] * alpha + _values(p, k, dv)
             m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
             l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+
+        def compute(slot, n, base, masked):
+            """The first ``n`` (static) pages of buffer ``slot``, in
+            blocks of ``BLOCK`` pages. The MXU's results come back in
+            program order, so a block's score product stands BEFORE the
+            softmax of the block before it: the vector unit works on one
+            block while the MXU is on the next."""
+            q = q_ref[0]          # [NH, lanes]; read in each body
+            blocks = [buf[slot, pl.ds(j * page, min(BLOCK, n - j) * page)]
+                      for j in range(0, n, BLOCK)]
+            s = _scores(q, blocks[0])
+            for i, k in enumerate(blocks):
+                s_next = (_scores(q, blocks[i + 1])
+                          if i + 1 < len(blocks) else None)
+                update(s, k, base + _i32(i * BLOCK * page), masked)
+                s = s_next
+
+        def one_turn(t):
+            """Turn ``t``: the next pages started, this turn's awaited."""
+            slot = jax.lax.rem(buf0 + t, _i32(2))
+            # what follows this turn: the row's next turn, or the first
+            # of the next row that holds a page, or nothing. Chosen by
+            # selects, not by branches: one block a turn
+            more = t + 1 < n_turns
+            r = jnp.where(more, b, jnp.minimum(nxt, rows - 1))
+            each_copy(r, jnp.where(more, t + 1, _i32(0)), _i32(1) - slot,
+                      lambda copy: copy.start(),
+                      jnp.where(more | (nxt < rows), pages_of(r), _i32(0)))
+            each_copy(b, t, slot, lambda copy: copy.wait())
+            return slot
+
+        def full_turn(t, c):      # no row past ``length``: no mask
+            compute(one_turn(t), per_turn, t * _i32(span), False)
             return c
 
-        _for(n_turns, one_turn, _i32(0))
+        _for(n_full, full_turn, _i32(0))
+
+        @pl.when(n_full < n_turns)
+        def _last():              # computed at the size of what it holds
+            slot = one_turn(n_full)
+            held = pages_of(b) - n_full * _i32(per_turn)
+            for n in range(1, per_turn + 1):
+                pl.when(held == n)(functools.partial(
+                    compute, slot, n, n_full * _i32(span), True))
+
         turn[0] = jax.lax.rem(buf0 + n_turns, _i32(2))
         turn[1] = jnp.where(nxt < rows, nxt, _i32(-1))
 

@@ -166,6 +166,12 @@ _M_MLA_CTX = obs.counter(
     "serve.mla_ctx_tokens", "sum over decode steps of the decoding "
     "streams' lengths, the step's own token counted: the rows mla_decode "
     "reads in each latent layer")
+_M_MLA_PAGES = obs.counter(
+    "serve.mla_pages_computed", "sum over decode steps and decoding "
+    "streams of the pages mla_decode's two products run over in each "
+    "latent layer (ops/pallas/mla_decode.pages_computed of the same "
+    "lengths): x block_size / serve.mla_ctx_tokens is what the kernel "
+    "computes for a row it needs, 1 plus half a page a stream at best")
 _M_BATCH_FILL = obs.gauge(
     "serve.batch_fill", "active streams / max_slots at the last step")
 _M_TOKENS_PER_SEC = obs.gauge(
@@ -1374,8 +1380,13 @@ class ServeEngine:
             if self._mla:
                 _M_LATENT_ROWS.inc(int(active.sum()) * len(self._mla),
                                    engine=self.name)
-                _M_MLA_CTX.inc(int((self._lens[active] + 1).sum()),
-                               engine=self.name)
+                from ..ops.pallas.mla_decode import pages_computed
+
+                ctx = self._lens[active] + 1
+                _M_MLA_CTX.inc(int(ctx.sum()), engine=self.name)
+                _M_MLA_PAGES.inc(int(pages_computed(
+                    ctx, self.block_size, self.max_blocks_per_seq).sum()),
+                    engine=self.name)
         return rows
 
     def _decode_done(self, record: dict, wait, emit, n: int = 1):

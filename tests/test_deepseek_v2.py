@@ -237,6 +237,9 @@ def test_counters_read_the_latent_cache_and_the_held_group():
     assert value("serve.latent_rows_written") == 3 * (17 + 38)
     assert value("serve.mla_ctx_tokens") == sum(range(12, 31)) + sum(
         range(7, 26))
+    # pages of 4 rows, computed as held: ceil(context / 4) a stream a step
+    assert value("serve.mla_pages_computed") == sum(
+        -(-n // 4) for n in list(range(12, 31)) + list(range(7, 26)))
     routed = value("serve.moe_tokens_routed")
     here = value("serve.moe_tokens_to_held_group")
     held = value("serve.moe_assignments_held")
@@ -247,11 +250,16 @@ def test_counters_read_the_latent_cache_and_the_held_group():
     # tools/serve_counters.py prints them
     from tools.serve_counters import counters
 
-    got = counters("ds-count")
+    got = counters("ds-count", block_size=4)
     assert got["latent"] == {
         "cache_bytes": 3 * 40 * 4 * 128 * 4, "rows_written": 3 * 55,
         "mla_ctx_tokens": value("serve.mla_ctx_tokens"),
-        "ctx_tokens_a_step": round(value("serve.mla_ctx_tokens") / 19, 1)}
+        "ctx_tokens_a_step": round(value("serve.mla_ctx_tokens") / 19, 1),
+        "mla_pages_computed": value("serve.mla_pages_computed"),
+        "computed_over_read": round(
+            value("serve.mla_pages_computed") * 4
+            / value("serve.mla_ctx_tokens"), 4)}
+    assert counters("ds-count")["latent"]["computed_over_read"] is None
     assert got["moe"]["tokens_to_held_group"] == here
     assert got["moe"]["held_group_share"] == round(here / routed, 4)
     assert counters("no-such-engine")["latent"]["mla_ctx_tokens"] in (0, None)
@@ -322,28 +330,44 @@ def test_absorbed_attention_equals_expanded_attention_on_the_same_rows():
                                atol=2e-5, rtol=2e-5)
 
 
+# name: (pages_per_seq, lengths). Pages of 8 rows and eight pages a turn: a
+# turn is 64 rows.
 LENGTHS = {
-    "ragged": [13, 5, 88, 32, 1],
-    "idle_slots": [0, 40, 0, 0, 7],
-    "first_row_idle": [0, 0, 21, 64, 3],
-    "page_edges": [8, 16, 64, 88, 32],         # every row ends on an edge
-    "all_idle": [0, 0, 0, 0, 0],
-    "a_turn_and_a_page": [72, 65, 64, 63, 88],
+    "ragged": (11, [13, 5, 88, 32, 1]),
+    "idle_slots": (11, [0, 40, 0, 0, 7]),
+    "first_row_idle": (11, [0, 0, 21, 64, 3]),
+    "page_edges": (11, [8, 16, 64, 88, 32]),    # every row ends on an edge
+    "all_idle": (11, [0, 0, 0, 0, 0]),
+    "a_turn_and_a_page": (11, [72, 65, 64, 63, 88]),
+    # the unmasked body alone: every row ends on a turn's edge, an idle
+    # slot between live ones
+    "turn_edges": (22, [64, 128, 0, 64, 128]),
+    "a_row_past_the_edge": (22, [65, 129, 0, 65, 129]),
+    "one_page": (11, [8, 8, 8, 8, 8]),
+    "one_row": (11, [1, 1, 1, 1, 1]),
+    # a last turn of 1 to 8 pages with no turn before it, then with one
+    "every_last_turn": (11, [3, 12, 20, 30, 36, 44, 52, 60, 69, 76, 84]),
+    # tables narrower than a turn: a turn is five pages there
+    "tables_under_a_turn": (5, [40, 33, 8, 0, 39, 24, 17]),
+    "two_turns_and_more": (22, [128, 176, 129, 0, 150, 175]),
+    "mixed": (22, [0, 64, 65, 8, 1, 0, 176, 128, 57, 130]),
 }
 
 
 @pytest.mark.parametrize("case", sorted(LENGTHS))
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 def test_mla_decode_kernel_equals_its_reference(case, dtype):
-    """Pages of 8 rows, 11 a row, eight a turn: rows of one turn, of a
-    turn and a page, of a turn less a row, idle rows before, between and
-    after."""
+    """Rows of one turn, of a turn and a page, of a turn less a row, of
+    whole turns only (no masked body runs), of every size of last turn,
+    idle rows before, between and after: the first copies of the next
+    live row follow each of them."""
     rng = np.random.default_rng(6)
-    b, nh, lanes, dv, page, pps, nb = 5, 4, 256, 128, 8, 11, 64
+    pps, lens = LENGTHS[case]
+    b, nh, lanes, dv, page, nb = len(lens), 4, 256, 128, 8, 256
     dt = jnp.dtype(dtype)
     pool = jnp.asarray(rng.normal(size=(1, nb, page, lanes)), dt)
     q = jnp.asarray(rng.normal(size=(b, nh, lanes)), dt)
-    lengths = jnp.asarray(LENGTHS[case], jnp.int32)
+    lengths = jnp.asarray(lens, jnp.int32)
     tables = jnp.asarray(rng.permutation(nb)[:b * pps].reshape(b, pps),
                          jnp.int32)
     got = mla_decode_kernel(q, pool, lengths, tables, dv=dv, sm_scale=0.07,
@@ -356,6 +380,17 @@ def test_mla_decode_kernel_equals_its_reference(case, dtype):
                                np.asarray(want, np.float32), atol=tol)
     idle = np.asarray(lengths) == 0
     assert not np.asarray(got, np.float32)[idle].any()
+
+
+def test_pages_computed_follows_what_a_stream_holds():
+    """The pages a stream holds, whole turns and the last one alike;
+    lengths past the table are cut to it."""
+    from paddle_tpu.ops.pallas.mla_decode import pages_computed
+
+    lens = [0, 1, 128, 129, 1024, 1025, 1700, 8192, 9000]
+    assert pages_computed(lens, 128, 64).tolist() == [
+        0, 1, 1, 2, 8, 9, 14, 64, 64]
+    assert pages_computed([40, 41, 8, 0], 8, 5).tolist() == [5, 5, 1, 0]
 
 
 def test_mla_decode_refuses_shapes_that_are_not_a_latent_pool():

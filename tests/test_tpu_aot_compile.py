@@ -471,7 +471,8 @@ def test_mla_decode_compiles(topo):
     """The latent decode kernel at the DeepSeek-V2 cell's shapes: 192
     rows of 128 absorbed queries over a pool of 6,656 pages of 128 rows
     in 640 lanes (a 576-wide row in five lane tiles), the value the first
-    512 lanes, four pages a turn."""
+    512 lanes, eight pages a turn: the unmasked turn in two blocks of
+    four pages and a last turn of each size from one page to eight."""
     from paddle_tpu.ops.pallas.mla_decode import mla_decode_kernel
 
     def fn(q, pool, lengths, tables):
@@ -484,6 +485,22 @@ def test_mla_decode_compiles(topo):
         _on(topo, (192, 64), jnp.int32))
     assert "tpu_custom_call" in text and "mla_decode" in text
     assert not _pool_sized_copies(compiled.as_text(), (1, 6656, 128, 640))
+
+
+@pytest.mark.parametrize("tables", [16, 5, 1])
+def test_mla_decode_compiles_where_a_turn_is_narrower(topo, tables):
+    """The same kernel over tables of two turns, of five pages (a turn is
+    the table: a block of four pages and one of one) and of one page: the
+    forms of turn a short ``max_seq_len`` makes."""
+    from paddle_tpu.ops.pallas.mla_decode import mla_decode_kernel
+
+    text, _ = _compile(
+        lambda q, pool, lengths, t: mla_decode_kernel(
+            q, pool, lengths, t, dv=512, sm_scale=0.1147),
+        _on(topo, (192, 128, 640), BF16),
+        _on(topo, (1, 6656, 128, 640), BF16), _on(topo, (192,), jnp.int32),
+        _on(topo, (192, tables), jnp.int32))
+    assert "tpu_custom_call" in text and "mla_decode" in text
 
 
 @pytest.mark.parametrize("rows, fresh", [(192, False), (2048, True)])

@@ -16,7 +16,10 @@ sent none up (`clean_share`: 1 - `serve.decode_uploads{what}` over the
 programs), the pipeline's drains by reason, preemptions; for a model with
 latent-attention layers the pools' bytes, the rows written into them and
 the rows `mla_decode` read (`serve.mla_ctx_tokens`, and their mean a
-decode step: the context the window really held); for a model with sparse
+decode step: the context the window really held) beside the pages its
+products ran over (`serve.mla_pages_computed`; `computed_over_read` is
+those pages' rows over the rows read: 1 plus half a page a stream where
+the kernel computes what a stream holds); for a model with sparse
 layers the tokens routed, the assignments that fell on held experts and,
 under a group-limited router, the tokens whose kept groups include the
 held one (3/8 in expectation for one group of eight, three kept). A
@@ -38,7 +41,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 
-def counters(engine: str) -> dict:
+def counters(engine: str, block_size=None) -> dict:
     from paddle_tpu import observability as obs
 
     def value(name, **labels):
@@ -63,7 +66,7 @@ def counters(engine: str) -> dict:
         "preemptions": value("serve.preemptions", reason="pool_exhausted"),
         "requests_finished": value("serve.requests_finished",
                                    reason="max_new_tokens"),
-        "latent": latent(value, steps),
+        "latent": latent(value, steps, block_size),
         "moe": moe(value),
     }
 
@@ -72,15 +75,21 @@ def _share(part, whole):
     return round(part / whole, 4) if part is not None and whole else None
 
 
-def latent(value, steps) -> dict:
-    """The latent-attention layers' cache: bytes held, rows written and
-    rows read."""
+def latent(value, steps, block_size=None) -> dict:
+    """The latent-attention layers' cache: bytes held, rows written, rows
+    read and pages computed (over the rows read, where the caller knows
+    the rows of a page)."""
     ctx = value("serve.mla_ctx_tokens")
+    pages = value("serve.mla_pages_computed")
     return {"cache_bytes": value("serve.latent_cache_bytes"),
             "rows_written": value("serve.latent_rows_written"),
             "mla_ctx_tokens": ctx,
             "ctx_tokens_a_step": (round(ctx / steps, 1)
-                                  if ctx is not None and steps else None)}
+                                  if ctx is not None and steps else None),
+            "mla_pages_computed": pages,
+            "computed_over_read": (_share(pages * block_size, ctx)
+                                   if pages is not None and block_size
+                                   else None)}
 
 
 def moe(value) -> dict:
@@ -92,6 +101,16 @@ def moe(value) -> dict:
             "assignments_held_a_token": _share(held, routed),
             "tokens_to_held_group": group,
             "held_group_share": _share(group, routed)}
+
+
+def _block_size(cell: str):
+    """Rows of a cache page in the cell's engine, or nothing."""
+    try:
+        with open(os.path.join(ROOT, "benchmark", "workloads",
+                               cell + ".json")) as f:
+            return json.load(f).get("engine", {}).get("block_size")
+    except OSError:
+        return None
 
 
 def slowest(engine: str, k: int = 5) -> dict:
@@ -165,8 +184,8 @@ def main(argv=None):
     argv = sys.argv[1:] if argv is None else argv
     rc = run.main(argv)
     cell = argv[argv.index("--workload") + 1]
-    print("serve counters: " + json.dumps(counters(cell)), file=sys.stderr,
-          flush=True)
+    print("serve counters: " + json.dumps(counters(cell, _block_size(cell))),
+          file=sys.stderr, flush=True)
     print("serve slowest: " + json.dumps(slowest(cell)), file=sys.stderr,
           flush=True)
     print("serve programs: " + json.dumps(programs(cell)), file=sys.stderr,
