@@ -26,8 +26,9 @@ module's, a new family's leaves are its own ``decode_view``.
 The ``jax.named_scope`` names here (``layer<i>/qkv|scatter_kv|attn|out|
 ffn``, ``layer<i>/moe/...``, ``layer<i>/ssm/in_proj|conv|scan|gate_norm|
 out``, ``layer<i>/mla/q|kv|scatter_latent|attn|out`` (and, by the caller,
-``/expand`` in a prefill, ``/absorb_q|absorb_o`` in a decode step),
-``final_norm``) are what the compiled
+``/expand`` in a prefill, ``/absorb_q|absorb_o`` in a decode step; with
+an indexer ``/index`` here and, by the caller, ``/scatter_index`` and
+``/select``), ``final_norm``) are what the compiled
 steps' op metadata, XProf, ``profiler.scope_seconds`` and
 ``tools/scope_breakdown.py`` name device time by.
 """
@@ -353,13 +354,38 @@ def _mamba2_mixer(i, spec, p, lp, x, cache, ssm, norm, scaled):
     return x, cache
 
 
+def _indexer(i, p, lp, h, c_q, rope, dtype):
+    """Layer ``i``'s lightning indexer (DSA; ``ops/dsa.py`` has the
+    score): ``heads`` index queries ``c_q W_Iq`` of ``dim`` from the
+    normed query latent, ONE index key ``LayerNorm(h W_Ik)`` a token (a
+    weight and a bias), RoPE on the first ``rope`` dims of each (the
+    tables the latent's rotated dims use), and the heads' weights ``h
+    W_Iw x heads^-0.5`` in float32. Returns (q_I ``[rows, heads, dim]``,
+    k_I ``[rows, dim]``, w ``[rows, heads]``)."""
+    import jax
+    import jax.numpy as jnp
+
+    st = p["dsa"]
+    rows, r = h.shape[0], st["rope"]
+    with jax.named_scope(f"layer{i}/mla/index"):
+        q = (c_q @ lp["iwq"]).reshape(rows, st["heads"], st["dim"])
+        k = ln(h @ lp["iwk"], lp["ikn_w"], lp["ikn_b"], st["eps"], dtype)
+        q_pe, k_pe = rotate(q[:, :, :r], k[:, None, :r], *rope, dtype)
+        q = jnp.concatenate([q_pe, q[:, :, r:]], axis=-1)
+        k = jnp.concatenate([k_pe[:, 0], k[:, r:]], axis=-1)
+        w = (h @ lp["iww"]).astype(jnp.float32) * st["heads"] ** -0.5
+    return q, k, w
+
+
 def _mla_mixer(i, spec, p, lp, x, cache, rope, mla, norm, scaled):
     """Layer ``i``'s latent-attention sub-layer (``ops/mla.py`` has the
     equations): the low-rank query ``RMSNorm(h W_qa) W_qb`` split a head
     into ``[q_nope | q_pe]``, the joint compression ``[c_kv | k_pe] = h
     W_kva`` with ``c = RMSNorm(c_kv)``, RoPE on ``q_pe`` and the one
     ``k_pe``, the row ``[c | k_pe]`` and the queries to the caller's
-    cache and attention, the output projection."""
+    cache and attention, the output projection. A view with ``dsa``
+    statics (sparse attention) also hands the caller the layer's
+    lightning indexer (:func:`_indexer`) as ``mla``'s last argument."""
     import jax
     import jax.numpy as jnp
 
@@ -374,15 +400,17 @@ def _mla_mixer(i, spec, p, lp, x, cache, rope, mla, norm, scaled):
     scope = jax.named_scope
     with scope(f"layer{i}/mla/q"):
         h = norm(spec, x, lp, "ln1")
-        q = (rms(h @ lp["wqa"], lp["qan"], eps, dtype) @ lp["wqb"]).reshape(
-            rows, p["nh"], st["nope"] + st["rope"])
+        c_q = rms(h @ lp["wqa"], lp["qan"], eps, dtype)
+        q = (c_q @ lp["wqb"]).reshape(rows, p["nh"], st["nope"] + st["rope"])
         q_nope, q_pe = q[:, :, :st["nope"]], q[:, :, st["nope"]:]
     with scope(f"layer{i}/mla/kv"):
         ckv = h @ lp["wkva"]
         c = rms(ckv[:, :st["rank"]], lp["kvan"], eps, dtype)
         q_pe, k_pe = rotate(q_pe, ckv[:, None, st["rank"]:], *rope, dtype)
         latent = jnp.concatenate([c, k_pe[:, 0]], axis=-1)
-    ctx, cache = mla(i, spec, lp, q_nope, q_pe, latent, cache)
+    index = (() if "dsa" not in p
+             else (_indexer(i, p, lp, h, c_q, rope, dtype),))
+    ctx, cache = mla(i, spec, lp, q_nope, q_pe, latent, cache, *index)
     with scope(f"layer{i}/mla/out"):
         x = x + scaled(ctx.astype(dtype) @ lp["wo"])
     return x, cache
@@ -404,11 +432,13 @@ def stack_layers(p, x, rope, caches, write_kv, attn, *, ssm=None, mla=None,
     cache as written; ``ssm(i, spec, lp, xbc, dt, cache) -> (y [rows,
     heads * dh] float32, cache)`` runs the layer's convolution and its
     recurrence over the rows (under the scopes ``layer<i>/ssm/conv`` and
-    ``/scan``); ``mla(i, spec, lp, q_nope, q_pe, latent, cache) -> (ctx
+    ``/scan``); ``mla(i, spec, lp, q_nope, q_pe, latent, cache[, index]) -> (ctx
     [rows, nh * v], cache)`` writes a latent layer's new rows ``latent``
     ``[rows, rank + rope]`` into the caller's cache and attends (under
-    ``layer<i>/mla/scatter_latent`` and ``/attn``): they are all that the
-    callers differ in. ``valid``
+    ``layer<i>/mla/scatter_latent`` and ``/attn``; a view with ``dsa``
+    statics adds the indexer's ``(q_I, k_I, w)``, whose keys the caller
+    caches under ``/scatter_index`` and whose selection it applies): they
+    are all that the callers differ in. ``valid``
     marks the rows that are tokens (a sparse layer routes the others
     nowhere) and ``backend`` is ``moe_ffn``'s. Returns (normed hidden
     [rows, H], new caches, the held experts' group sizes of each sparse

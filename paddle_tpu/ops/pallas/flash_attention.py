@@ -263,15 +263,21 @@ def _optional_refs(refs, n, has_bias, rate):
 # forward
 # ---------------------------------------------------------------------------
 def _fwd_kernel(qi_ref, kj_ref, fl_ref, *refs, scale, causal, block_q,
-                block_k, offset, rate, n_heads, has_bias, window, guard):
+                block_k, offset, rate, n_heads, has_bias, window, guard,
+                has_select=False):
     # offset = Sk - Sq: bottom-right-aligned causal mask (query i attends
     # keys <= i + offset), matching paddle/XLA semantics for Sq != Sk.
     # window (causal only): and keys > i + offset - window, a band.
+    # has_select: an int8 [Sq, Sk] mask after v (a sparse-attention
+    # prefill's selection): a score where it is 0 is -inf.
     # guard: a row may have seen no key yet when a tile is computed (a
     # key bias of -inf, the band's lower edge, queries before the first
-    # key); keep the exp args finite so it stays exactly zero, not NaN
+    # key, keys it did not select); keep the exp args finite so it stays
+    # exactly zero, not NaN
     q_ref, k_ref, v_ref = refs[:3]
-    bias_ref, seed_ref, rest = _optional_refs(refs, 3, has_bias, rate)
+    sel_ref = refs[3] if has_select else None
+    bias_ref, seed_ref, rest = _optional_refs(refs, 3 + int(has_select),
+                                              has_bias, rate)
     o_ref, lse_ref, qs_scr, m_scr, l_scr, acc_scr = rest
     t = pl.program_id(2)
     i, j, fl = qi_ref[t], kj_ref[t], fl_ref[t]
@@ -295,6 +301,8 @@ def _fwd_kernel(qi_ref, kj_ref, fl_ref, *refs, scale, causal, block_q,
     if causal:
         s = jnp.where(_visible(i, j, s.shape, block_q, block_k, offset,
                                window, 0), s, NEG_INF)
+    if has_select:
+        s = jnp.where(sel_ref[...].astype(jnp.int32) != 0, s, NEG_INF)
     m_prev = m_scr[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     m_eff = jnp.where(m_new == NEG_INF, 0.0, m_new) if guard else m_new
@@ -401,19 +409,27 @@ def _blocks(sq, sk):
 
 
 def _fwd_local(q, k, v, *extras, causal, scale, rate, has_bias, interpret,
-               layout="bhsd", shard_axes=(), window=None, blocks=None):
+               layout="bhsd", shard_axes=(), window=None, blocks=None,
+               select=None):
     """One shard's forward: q [B,H,Sq,D]; k [B,Hkv,Sk,D]; v
     [B,Hkv,Sk,Dv] (``layout`` bhsd; bshd: [B,S,H,D] each) -> (out like
     q at width Dv, lse [B,H,Sq]). The value head may be another size than
     the query-key head (a latent-attention prefill: 192 and 128).
     ``window`` (with ``causal``): a query sees its last ``window`` keys
     only; key blocks outside that band are neither computed nor fetched
-    (forward only). ``blocks``: (block_q, block_k) in place of the
-    shapes' own (``tools/flash_kernel_probe.py`` sweeps them)."""
+    (forward only). ``select`` (with ``causal``): an int8 ``[Sq, Sk]``
+    mask shared by batch and heads, a query seeing only the keys it
+    selected (a DSA prefill; forward only). ``blocks``: (block_q,
+    block_k) in place of the shapes' own (``tools/flash_kernel_probe.py``
+    sweeps them)."""
     key_bias, seed = _split_extras(extras, has_bias, rate, shard_axes)
     if window is not None and (not causal or has_bias or rate > 0.0):
         raise ValueError("flash forward: a window needs causal=True "
                          "and takes neither key bias nor dropout")
+    if select is not None and (not causal or has_bias or rate > 0.0
+                               or window is not None):
+        raise ValueError("flash forward: a selection needs causal=True "
+                         "and takes no window, key bias or dropout")
     B, H, Hkv, Sq, Sk, D, Dv = _dims(q, k, v, layout)
     block_q, block_k = blocks or _blocks(Sq, Sk)
     offset = Sk - Sq
@@ -425,13 +441,20 @@ def _fwd_local(q, k, v, *extras, causal, scale, rate, has_bias, interpret,
         _fwd_kernel, scale=scale, causal=causal, block_q=block_q,
         block_k=block_k, offset=offset, rate=rate, n_heads=H,
         has_bias=has_bias, window=None if window is None else int(window),
-        guard=has_bias or window is not None or (causal and offset < 0))
+        guard=has_bias or window is not None or (causal and offset < 0)
+        or select is not None, **({} if select is None
+                                  else {"has_select": True}))
     in_specs = [
         _head_spec(layout, block_q, D, _q_of),
         _head_spec(layout, block_k, D, _k_of, kv_head),
         _head_spec(layout, block_k, Dv, _k_of, kv_head),
     ]
     inputs = [_flat(x, layout) for x in (q, k, v)]
+    if select is not None:
+        in_specs.append(pl.BlockSpec(
+            (block_q, block_k),
+            lambda b, h, t, qi, kj, fl: (qi[t], kj[t])))
+        inputs.append(select.astype(jnp.int8))
     if key_bias is not None:
         spec, bias = _bias_operand(key_bias, block_k, column=False)
         in_specs.append(spec)
@@ -477,9 +500,15 @@ _JIT_STATICS = ("causal", "scale", "dropout_rate", "partition", "interpret",
 
 @functools.partial(jax.jit, static_argnames=_JIT_STATICS + ("window",))
 def _flash_fwd_jit(q, k, v, seed, key_bias, *, causal, scale, dropout_rate,
-                   partition, interpret, layout="bhsd", window=None):
+                   partition, interpret, layout="bhsd", window=None,
+                   select=None):
     extras = [x for x in (key_bias, seed) if x is not None]
     band = {} if window is None else {"window": window}
+    if select is not None:
+        if partition is not None:
+            raise ValueError("flash forward: a selection is unsharded; "
+                             "no sharded program passes one")
+        band["select"] = select
     return _run_flash(
         _fwd_local, "xkk", "xl", (q, k, v, *extras), partition=partition,
         layout=layout, causal=causal, scale=scale, rate=dropout_rate,
@@ -488,7 +517,7 @@ def _flash_fwd_jit(q, k, v, seed, key_bias, *, causal, scale, dropout_rate,
 
 def _flash_fwd_bhsd(q, k, v, seed=None, key_bias=None, *, causal, scale,
                     dropout_rate=0.0, partition=None, window=None,
-                    interpret=None):
+                    interpret=None, select=None):
     """q: [B,H,Sq,D]; k: [B,Hkv,Sk,D]; v: [B,Hkv,Sk,Dv] -> (out
     [B,H,Sq,Dv], lse [B,H,Sq]); Dv may differ from D (forward only).
     seed: int32 [1] dropout seed, required when dropout_rate > 0.
@@ -497,12 +526,16 @@ def _flash_fwd_bhsd(q, k, v, seed=None, key_bias=None, *, causal, scale,
     partition: the :class:`KernelPartition` of a sharded program.
     window: with ``causal``, a query sees its last ``window`` keys (a
     sliding-window layer's band; forward only, as serving needs it).
+    select: with ``causal``, an int8 [Sq, Sk] mask shared by batch and
+    heads: a query sees the keys it selected only (a DSA prefill; forward
+    only, unsharded).
     interpret: None follows ``pallas_mode()``."""
+    more = {} if select is None else {"select": select}
     return _flash_fwd_jit(q, k, v, seed, key_bias, causal=causal,
                           scale=scale, dropout_rate=dropout_rate,
                           partition=partition, window=window,
                           interpret=(_interpret() if interpret is None
-                                     else interpret))
+                                     else interpret), **more)
 
 
 # ---------------------------------------------------------------------------

@@ -82,6 +82,11 @@ _i32 = np.int32
 #: longer turn spreads wider and a longer last turn's code pays for.
 TURN = 8
 
+#: what ``keep`` adds to a score the softmax leaves out: finite, so that a
+#: block of none but left-out positions gives no NaN (its weights are
+#: taken back to 0 by the first kept score, ``DROP`` below it)
+DROP = -1e30
+
 #: pages of a block, the unit of the softmax within a turn: two blocks a
 #: turn, the second's score product under way while the vector unit is on
 #: the first. Blocks of two pages schedule 9% worse (the accumulator's
@@ -153,13 +158,16 @@ def pages_computed(lengths, page_size, pages_per_seq):
     return np.minimum(held, pages_per_seq)
 
 
-def _kernel_body(len_ref, tbl_ref, q_ref, pool_hbm, o_ref, buf, sems, turn,
-                 m_scr, l_scr, acc_scr, *, page, pps, per_turn, dv, scale):
+def _kernel_body(len_ref, tbl_ref, q_ref, pool_hbm, *refs, page, pps,
+                 per_turn, dv, scale, keep=False):
     """One row of the batch a program. ``buf`` ``[2, per_turn * page,
     lanes]`` holds the pages being computed on and those in flight;
     ``turn`` (SMEM) carries from program to program the buffer the next
     pages land in and the row whose first pages are already on their
-    way."""
+    way. With ``keep``, ``keep_ref`` ``[1, 1, pps * page]`` is added to
+    the row's scores position by position (0 or ``DROP``)."""
+    keep_ref = refs[0] if keep else None
+    o_ref, buf, sems, turn, m_scr, l_scr, acc_scr = refs[1:] if keep else refs
     b = pl.program_id(0)
     rows = pl.num_programs(0)
     span = per_turn * page
@@ -219,6 +227,9 @@ def _kernel_body(len_ref, tbl_ref, q_ref, pool_hbm, o_ref, buf, sems, turn,
             ``s``, the first of them at position ``base``. Every block
             handed in holds a row under ``length``."""
             m_prev = m_scr[:, :1]
+            if keep_ref is not None:
+                s = s + keep_ref[0, :, pl.ds(pl.multiple_of(base, page),
+                                             s.shape[1])]
             if masked:
                 seen = base + jax.lax.broadcasted_iota(
                     jnp.int32, s.shape, 1) < length
@@ -288,20 +299,34 @@ def _kernel_body(len_ref, tbl_ref, q_ref, pool_hbm, o_ref, buf, sems, turn,
 
 
 def mla_decode_kernel(q, pool, lengths, block_tables, *, dv, sm_scale,
-                      interpret=False):
-    """The Pallas kernel proper (TPU; ``interpret=True`` on the CPU)."""
+                      interpret=False, keep=None, name="mla_decode"):
+    """The Pallas kernel proper (TPU; ``interpret=True`` on the CPU).
+    ``keep``, where given, is ``[B, 1, pages_per_seq * page_size]``
+    float32 added to each row's scores by position: 0 for a position the
+    softmax takes, ``DROP`` for one it leaves out (a stream's every other
+    position; ``ops/pallas/mla_sparse_decode``)."""
     _check_shapes(q, pool, lengths, block_tables, dv)
     b, nh, lanes = q.shape
     page = pool.shape[2]
     pps = block_tables.shape[1]
     per_turn = min(TURN, pps)
+    in_specs = [
+        pl.BlockSpec((1, nh, lanes), lambda bi, *_: (bi, Z, Z)),
+        pl.BlockSpec(memory_space=pl.ANY),
+    ]
+    args = (lengths.astype(jnp.int32), block_tables.astype(jnp.int32),
+            q.astype(pool.dtype), pool)
+    if keep is not None:
+        if keep.shape != (b, 1, pps * page):
+            raise ValueError(f"keep must be [B, 1, {pps * page}], got "
+                             f"{keep.shape}")
+        in_specs.append(pl.BlockSpec((1, 1, pps * page),
+                                     lambda bi, *_: (bi, Z, Z)))
+        args += (keep.astype(jnp.float32),)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b,),
-        in_specs=[
-            pl.BlockSpec((1, nh, lanes), lambda bi, *_: (bi, Z, Z)),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((1, nh, dv), lambda bi, *_: (bi, Z, Z)),
         scratch_shapes=[
             pltpu.VMEM((2, per_turn * page, lanes), pool.dtype),
@@ -314,7 +339,7 @@ def mla_decode_kernel(q, pool, lengths, block_tables, *, dv, sm_scale,
     )
     kernel = functools.partial(
         _kernel_body, page=page, pps=pps, per_turn=per_turn, dv=dv,
-        scale=sm_scale)
+        scale=sm_scale, keep=keep is not None)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -322,10 +347,9 @@ def mla_decode_kernel(q, pool, lengths, block_tables, *, dv, sm_scale,
         # rows in order on one core: a row starts the next row's copies
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
-        name="mla_decode",
+        name=name,
         interpret=interpret,
-    )(lengths.astype(jnp.int32), block_tables.astype(jnp.int32),
-      q.astype(pool.dtype), pool)
+    )(*args)
 
 
 def mla_decode(q, pool, lengths, block_tables, *, dv, sm_scale,

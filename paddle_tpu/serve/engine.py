@@ -172,6 +172,24 @@ _M_MLA_PAGES = obs.counter(
     "latent layer (ops/pallas/mla_decode.pages_computed of the same "
     "lengths): x block_size / serve.mla_ctx_tokens is what the kernel "
     "computes for a row it needs, 1 plus half a page a stream at best")
+_M_INDEX_BYTES = obs.gauge(
+    "serve.index_cache_bytes", "bytes the sparse-attention layers' index-key "
+    "pools hold on the device (one key a token and layer)")
+_M_DSA_INDEX_ROWS = obs.counter(
+    "serve.dsa_index_rows", "sum over decode steps of the decoding streams' "
+    "lengths, the step's own token counted: the index keys dsa_index scores "
+    "in each sparse-attention layer")
+_M_DSA_SELECTED = obs.counter(
+    "serve.dsa_selected_rows", "sum over decode steps of the decoding "
+    "streams' min(length, index_topk): the latent rows mla_sparse_decode "
+    "reads in each sparse-attention layer (over serve.dsa_index_rows: the "
+    "share of the context attention reads)")
+_M_DSA_PAIRS_SELECTED = obs.counter(
+    "serve.dsa_prefill_pairs_selected", "(query, key) pairs a prompt's "
+    "queries attend under their selection, summed over prefills")
+_M_DSA_PAIRS_CAUSAL = obs.counter(
+    "serve.dsa_prefill_pairs_causal", "causal (query, key) pairs of the same "
+    "prompts: what dense attention would attend")
 _M_BATCH_FILL = obs.gauge(
     "serve.batch_fill", "active streams / max_slots at the last step")
 _M_TOKENS_PER_SEC = obs.gauge(
@@ -377,7 +395,15 @@ class ServeEngine:
     expanded to per-head keys and values (the flash forward, a query-key
     head of ``nope + rope`` and a value head of ``v``); every row that is
     read from the pool is read as it lies, by ``ops/pallas/mla_decode``
-    with the expansion absorbed into the query.
+    with the expansion absorbed into the query. A latent layer with sparse
+    attention (the view's ``dsa`` statics) keeps, beside its rows, a pool
+    of one INDEX KEY a token, ``[1, num_blocks, block_size, dim]``, under
+    the same block table (a block holds both, so whatever the scheduler
+    does with a block it does with both); a step writes its tokens' keys
+    before the indexer scores them, a prompt attends under its selection
+    (``ops/dsa.py:prefill_select`` and the flash forward), and a decode
+    step scores every cached key (``ops/pallas/dsa_index``) and attends
+    over the selected rows only (``ops/pallas/mla_sparse_decode``).
 
     Usage::
 
@@ -438,6 +464,8 @@ class ServeEngine:
         #: the latent-attention layers (each keeps one pool of rows)
         self._mla = [i for i, s in enumerate(self._specs)
                      if s.mixer == "mla"]
+        #: the latent layers' sparse attention (``dsa`` statics) or None
+        self._dsa = p.get("dsa") if self._mla else None
         max_pos = p.get("max_positions")
         if max_pos is not None and max_seq_len > max_pos:
             raise ValueError(
@@ -503,8 +531,13 @@ class ServeEngine:
             if spec.mixer == "mla":
                 from ..ops.mla import row_lanes
 
-                return (jnp.zeros((1, self.pool.num_blocks, self.block_size,
+                rows = (jnp.zeros((1, self.pool.num_blocks, self.block_size,
                                    row_lanes(p["mla"])), self._dtype),)
+                if self._dsa is None:
+                    return rows
+                return rows + (jnp.zeros(
+                    (1, self.pool.num_blocks, self.block_size,
+                     self._dsa["dim"]), self._dtype),)
             pool = self.pool if spec.window is None else self.window_pool
             shape = (self._nkv // self._pack, pool.num_blocks,
                      self.block_size, self._dh * self._pack)
@@ -512,11 +545,15 @@ class ServeEngine:
                                                             self._dtype)
 
         #: one tuple a layer: (K, V) pools, (tail, state) by slot, or
-        #: the one pool of latent rows
+        #: the one pool of latent rows (and of index keys)
         self._caches = [cache_of(s) for s in self._specs]
         if self._mla:
             _M_LATENT_BYTES.set(
-                sum(a.nbytes for i in self._mla for a in self._caches[i]),
+                sum(self._caches[i][0].nbytes for i in self._mla),
+                engine=self.name)
+        if self._dsa is not None:
+            _M_INDEX_BYTES.set(
+                sum(self._caches[i][1].nbytes for i in self._mla),
                 engine=self.name)
         if self._mamba:
             _M_SSM_STATE_BYTES.set(
@@ -1010,6 +1047,12 @@ class ServeEngine:
                 _M_SSM_PREFILL_TOKENS.inc(n, engine=self.name)
             if self._mla:
                 _M_LATENT_ROWS.inc(n * len(self._mla), engine=self.name)
+            if self._dsa is not None:
+                from ..ops.dsa import selected_pairs
+
+                chosen, causal = selected_pairs(n, self._dsa["topk"], start)
+                _M_DSA_PAIRS_SELECTED.inc(chosen, engine=self.name)
+                _M_DSA_PAIRS_CAUSAL.inc(causal, engine=self.name)
             if start == 0:
                 self._caches, logits = self._prefill_fn(
                     self._arrays, self._caches, jnp.asarray(padded),
@@ -1384,9 +1427,14 @@ class ServeEngine:
 
                 ctx = self._lens[active] + 1
                 _M_MLA_CTX.inc(int(ctx.sum()), engine=self.name)
-                _M_MLA_PAGES.inc(int(pages_computed(
-                    ctx, self.block_size, self.max_blocks_per_seq).sum()),
-                    engine=self.name)
+                if self._dsa is None:
+                    _M_MLA_PAGES.inc(int(pages_computed(
+                        ctx, self.block_size,
+                        self.max_blocks_per_seq).sum()), engine=self.name)
+                else:
+                    _M_DSA_INDEX_ROWS.inc(int(ctx.sum()), engine=self.name)
+                    _M_DSA_SELECTED.inc(int(np.minimum(
+                        ctx, self._dsa["topk"]).sum()), engine=self.name)
         return rows
 
     def _decode_done(self, record: dict, wait, emit, n: int = 1):
@@ -1617,7 +1665,8 @@ class ServeEngine:
     def _scatter_latent(self, slots, fresh, pool, latent):
         """Write the rows ``latent`` ``[rows, rank + rope]`` into a latent
         layer's pool at ``slots["full"]``, padded with zeros to the pool
-        row's lanes: ``_scatter_kv`` for the one pool of a ``mla`` layer."""
+        row's lanes: ``_scatter_kv`` for the one pool of a ``mla`` layer
+        (and for the index keys ``[rows, dim]`` of a sparse one)."""
         import jax.numpy as jnp
 
         from ..ops.pallas.kv_write import kv_write
@@ -1627,6 +1676,40 @@ class ServeEngine:
         return kv_write(pool, rows, slots=slots["full"],
                         rows_start_blocks=fresh,
                         backend=self.attention_backend)
+
+    def _sparse_attn(self, i, lp, q_nope, q_pe, index, pool, ipool, lengths,
+                     tables):
+        """A sparse layer's attention of the rows' queries, ``[rows, nh *
+        v]``: ``dsa_index`` scores every cached index key of each row's
+        stream, the ``index_topk`` best are selected, and
+        ``mla_sparse_decode`` attends, absorbed, over those latent rows
+        only."""
+        import jax
+        import jax.numpy as jnp
+
+        from ..ops import dsa as _dsa
+        from ..ops import mla as _mla
+        from ..ops.pallas.dsa_index import dsa_index
+        from ..ops.pallas.mla_sparse_decode import mla_sparse_decode
+
+        st, ds = self._static["mla"], self._dsa
+        q_i, _, w = index
+        with jax.named_scope(f"layer{i}/mla/index"):
+            scores = dsa_index(q_i, w, ipool, lengths, tables,
+                               scale=ds["dim"] ** -0.5,
+                               backend=self.attention_backend)
+        with jax.named_scope(f"layer{i}/mla/select"):
+            pos = jnp.arange(scores.shape[-1], dtype=jnp.int32)[None, :]
+            chosen = _dsa.top_mask(scores, pos < lengths[:, None],
+                                   ds["topk"])
+        with jax.named_scope(f"layer{i}/mla/absorb_q"):
+            q = _mla.absorb_q(lp, st, q_nope, q_pe, pool.shape[-1])
+        with jax.named_scope(f"layer{i}/mla/attn"):
+            out = mla_sparse_decode(q, pool, chosen, lengths, tables,
+                                    dv=st["rank"], sm_scale=self._scale,
+                                    backend=self.attention_backend)
+        with jax.named_scope(f"layer{i}/mla/absorb_o"):
+            return _mla.absorb_o(lp, st, out)
 
     def _latent_attn(self, i, lp, q_nope, q_pe, pool, lengths, tables):
         """Attention of the rows' queries over a latent layer's pool as it
@@ -1653,11 +1736,17 @@ class ServeEngine:
         block table (a decode step, a suffix prefill)."""
         import jax
 
-        def mla(i, _spec, lp, q_nope, q_pe, latent, cache):
+        def mla(i, _spec, lp, q_nope, q_pe, latent, cache, index=None):
             with jax.named_scope(f"layer{i}/mla/scatter_latent"):
                 pool = self._scatter_latent(slots, False, cache[0], latent)
-            return self._latent_attn(i, lp, q_nope, q_pe, pool, lengths,
-                                     tables), (pool,)
+            if index is None:
+                return self._latent_attn(i, lp, q_nope, q_pe, pool, lengths,
+                                         tables), (pool,)
+            # the new tokens' keys first: a token may select itself
+            with jax.named_scope(f"layer{i}/mla/scatter_index"):
+                ipool = self._scatter_latent(slots, False, cache[1], index[1])
+            return self._sparse_attn(i, lp, q_nope, q_pe, index, pool, ipool,
+                                     lengths, tables), (pool, ipool)
 
         return mla
 
@@ -1871,14 +1960,15 @@ class ServeEngine:
         flash = (p.get("prefill") == "flash"
                  and self.attention_backend != "reference")
 
-        def flash_fwd(q, k, v, window=None):
-            """[tp, heads, dv] through the flash forward kernel."""
+        def flash_fwd(q, k, v, window=None, **select):
+            """[tp, heads, dv] through the flash forward kernel (under a
+            sparse layer's ``select`` mask where given)."""
             from ..ops.pallas.flash_attention import _flash_fwd_bhsd
 
             out, _ = _flash_fwd_bhsd(
                 *(a.transpose(1, 0, 2)[None] for a in (q, k, v)),
                 causal=True, scale=self._scale, window=window,
-                interpret=self.attention_backend == "interpret")
+                interpret=self.attention_backend == "interpret", **select)
             return out[0].transpose(1, 0, 2)
 
         def softmax_attn(q, k, v, seen):
@@ -1905,20 +1995,37 @@ class ServeEngine:
             v_rep = jnp.repeat(v, group, axis=1) if group > 1 else v
             return softmax_attn(q, k_rep, v_rep, seen).reshape(tp, nh * dh)
 
-        def mla(i, _spec, lp, q_nope, q_pe, latent, cache):
+        def mla(i, _spec, lp, q_nope, q_pe, latent, cache, index=None):
             """The prompt's rows into the pool as they are, and causal
-            attention within the prompt with its own rows expanded."""
+            attention within the prompt with its own rows expanded; a
+            sparse layer's index keys into their pool too, and where the
+            bucket is longer than ``index_topk`` each query attends only
+            the keys it selected."""
+            from ..ops import dsa as _dsa
             from ..ops import mla as _mla
 
             with jax.named_scope(f"layer{i}/mla/scatter_latent"):
                 pool = self._scatter_latent(slots, True, cache[0], latent)
+            new, seen = (pool,), causal
+            select = {}
+            if index is not None:
+                q_i, k_i, w = index
+                with jax.named_scope(f"layer{i}/mla/scatter_index"):
+                    new += (self._scatter_latent(slots, True, cache[1], k_i),)
+                if tp > self._dsa["topk"]:
+                    with jax.named_scope(f"layer{i}/mla/select"):
+                        chosen = _dsa.prefill_select(
+                            q_i, w, k_i, self._dsa["topk"],
+                            self._dsa["dim"] ** -0.5)
+                    select = {"select": chosen}
+                    seen = causal & (chosen != 0)
             with jax.named_scope(f"layer{i}/mla/expand"):
                 k, v = _mla.expand(lp, p["mla"], latent)
                 q = jnp.concatenate([q_nope, q_pe], axis=-1)
             with jax.named_scope(f"layer{i}/mla/attn"):
-                out = (flash_fwd(q, k, v) if flash
-                       else softmax_attn(q, k, v, causal))
-            return out.reshape(tp, -1), (pool,)
+                out = (flash_fwd(q, k, v, **select) if flash
+                       else softmax_attn(q, k, v, seen))
+            return out.reshape(tp, -1), new
 
         def ssm(i, _spec, lp, xbc, dt, cache):
             """The prompt's chunked scan from a zero state; what its last

@@ -310,3 +310,60 @@ def test_other_families_programs_lower_to_the_stored_text(lowered_texts,
     assert got == LOWERED_SHA256[program], (
         f"{program} no longer lowers to the text the parent commit "
         f"lowered it to (tools/lowered_programs.py dumps both for a diff)")
+
+
+#: the same for the DeepSeek-V2 and EXAONE-MoE engines whose compiled
+#: steps run the Pallas kernels (`interpret` backend, so that the lowered
+#: text holds each kernel's body: `mla_decode`, the flash forward at 192/128
+#: and with a band, `paged_decode`, `kv_write`, `moe_experts`), as the
+#: commit before the GLM-5 family lowers them. That family gave the latent
+#: mixer an indexer and the flash forward a selection mask behind static
+#: switches that these families never set: their programs are the parent's,
+#: kernels included.
+INTERPRETED_SHA256 = {
+    "deepseek.decode": "4cf2c9e2f4c5ea53",
+    "deepseek.prefill.128": "045310d4ae278ab9",
+    "deepseek.prefill.8": "cab33fa459d090eb",
+    "exaone.decode": "0b3ab227a2619376",
+    "exaone.prefill.128": "22f1bcf78176533e",
+    "exaone.prefill.8": "63cc4cf9b718e48c",
+}
+
+
+@pytest.fixture(scope="module")
+def interpreted_texts():
+    """{"<family>.<program>": lowered text} of the tool's DeepSeek-V2 and
+    EXAONE-MoE engines with their kernels interpreted."""
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools", "lowered_programs.py")
+    spec = importlib.util.spec_from_file_location("lowered_programs", path)
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    out = {}
+    for name, model in tool.models("float32").items():
+        if name not in ("deepseek", "exaone"):
+            continue
+        eng = ServeEngine(model, max_slots=8, block_size=128, num_blocks=16,
+                          max_seq_len=512, prefix_cache=False,
+                          attention_backend="interpret",
+                          name=f"interpreted-test-{name}", trace=False,
+                          slo=False)
+        for prog, low in eng.lowered(prompt_lens=(8, 100)).items():
+            out[f"{name}.{prog}"] = tool.strip_kernel_locations(
+                low.as_text())
+    return out
+
+
+@pytest.mark.parametrize("program", sorted(INTERPRETED_SHA256))
+def test_kernel_programs_of_the_latent_and_window_families_are_the_parents(
+        interpreted_texts, program):
+    import hashlib
+
+    got = hashlib.sha256(
+        interpreted_texts[program].encode()).hexdigest()[:16]
+    assert got == INTERPRETED_SHA256[program], (
+        f"{program} no longer lowers to the text the parent commit "
+        f"lowered it to, kernels included")

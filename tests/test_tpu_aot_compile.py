@@ -598,6 +598,102 @@ def test_deepseek_engine_programs_keep_the_latent_pools_in_place(topo):
                              hlo.replace("[1,6656,128,640]", "")), name
 
 
+def test_dsa_index_compiles(topo):
+    """The lightning indexer's decode kernel at the GLM-5 cell's shapes: 32
+    rows of 32 index queries of 128 over a pool of 4,096 pages of 128
+    index keys, tables of 160 pages, sixteen pages a turn."""
+    from paddle_tpu.ops.pallas.dsa_index import dsa_index_kernel
+
+    text, compiled = _compile(
+        lambda q, w, pool, lengths, tables: dsa_index_kernel(
+            q, w, pool, lengths, tables, scale=128 ** -0.5),
+        _on(topo, (32, 32, 128), BF16), _on(topo, (32, 32), jnp.float32),
+        _on(topo, (1, 4096, 128, 128), BF16), _on(topo, (32,), jnp.int32),
+        _on(topo, (32, 160), jnp.int32))
+    assert "tpu_custom_call" in text and "dsa_index" in text
+    assert not _pool_sized_copies(compiled.as_text(), (1, 4096, 128, 128))
+
+
+def test_mla_sparse_decode_compiles(topo):
+    """Absorbed attention over the selected rows at the cell's shapes: 32
+    rows of 64 queries, a selection mask over 160 pages a stream, a pool
+    of 4,096 pages of 640-lane rows walked in place and never copied
+    whole."""
+    from paddle_tpu.ops.pallas.mla_sparse_decode import (
+        mla_sparse_decode_kernel)
+
+    text, compiled = _compile(
+        lambda q, pool, chosen, lengths, tables: mla_sparse_decode_kernel(
+            q, pool, chosen, lengths, tables, dv=512, sm_scale=256 ** -0.5),
+        _on(topo, (32, 64, 640), BF16), _on(topo, (1, 4096, 128, 640), BF16),
+        _on(topo, (32, 20480), jnp.bool_), _on(topo, (32,), jnp.int32),
+        _on(topo, (32, 160), jnp.int32))
+    assert "tpu_custom_call" in text and "mla_sparse_decode" in text
+    assert not _pool_sized_copies(compiled.as_text(), (1, 4096, 128, 640))
+
+
+@pytest.mark.parametrize("seq", [4096, 20480])
+def test_flash_forward_under_a_selection_compiles(topo, seq):
+    """The causal forward of a DSA prefill: 64 heads of 256 and 256 under
+    an int8 ``[S, S]`` selection mask, at a bucket past the 2,048 selected
+    and at the cell's largest."""
+    from paddle_tpu.ops.pallas.flash_attention import _flash_fwd_bhsd
+
+    def fn(q, k, v, sel):
+        return _flash_fwd_bhsd(q, k, v, causal=True, scale=0.0625,
+                               select=sel)[0]
+
+    text, _ = _compile(fn, *(_on(topo, (1, 64, seq, 256), BF16)
+                             for _ in range(3)),
+                       _on(topo, (seq, seq), jnp.int8))
+    assert "tpu_custom_call" in text and "flash_fwd" in text
+
+
+def test_glm_engine_programs_keep_both_pools_in_place(topo):
+    """Two layers of GLM-5 at the published widths and the cell's engine
+    geometry (the dense layer and a sparse one holding experts [0, 16)):
+    two pools a layer (640-lane latent rows and 128-wide index keys), the
+    decode step and the 4,096 and 20,480 prefill buckets (past the 2,048
+    selected, so their selection runs) hold no ``copy`` shaped like a pool
+    and alias every donated pool, the largest in under 4.5 GB of
+    temporaries; the decode step runs ``dsa_index`` and
+    ``mla_sparse_decode`` and no ``mla_decode``, the prefill the flash
+    forward."""
+    from paddle_tpu.models.glm_moe_dsa import (GlmMoeDsaConfig,
+                                               GlmMoeDsaForCausalLM)
+    from paddle_tpu.serve import ServeEngine
+
+    model = GlmMoeDsaForCausalLM(GlmMoeDsaConfig(
+        vocab_size=19360, num_hidden_layers=2, first_k_dense_replace=1,
+        experts_held=(0, 16), dtype="bfloat16", deferred_init=True))
+    model.eval()
+    eng = ServeEngine(model, max_slots=32, block_size=128, num_blocks=1024,
+                      max_seq_len=20480, name="aot-glm", trace=False,
+                      slo=False)
+    assert eng.attention_backend == "kernel"
+    pools = ((1, 1024, 128, 640), (1, 1024, 128, 128))
+    assert [tuple(a.shape for a in c) for c in eng._caches] == [pools] * 2
+    lowered = eng.lowered(prompt_lens=(4096, 20479), device=topo.devices[0])
+    assert set(lowered) == {"decode", "prefill.4096", "prefill.20480"}
+    n_arrays = len(jax.tree.leaves(eng._arrays))
+    caches = set(range(n_arrays, n_arrays + 4))
+    for name, low in lowered.items():
+        compiled = low.compile()
+        hlo = compiled.as_text()
+        if name == "prefill.20480":
+            # what the cell's largest bucket holds beside the weights and
+            # the pool (4.24 GB at five layers): it has to fit in 16
+            assert compiled.memory_analysis().temp_size_in_bytes < 4.5e9
+        for pool in pools:
+            assert not _copies_shaped_like(hlo, pool), (name, pool)
+        assert caches <= _aliased_params(hlo), name
+        for kernel in ("moe_experts", "kv_write") + (
+                ("dsa_index", "mla_sparse_decode") if name == "decode"
+                else ("flash_fwd",)):
+            assert kernel in hlo, (name, kernel)
+        assert "mla_decode\"" not in hlo, name
+
+
 class _TopoMesh:
     """The slice of ``ProcessMesh`` a KernelPartition needs, over
     compile-only devices (a ProcessMesh indexes ``jax.devices()``)."""

@@ -2,13 +2,14 @@
 """Write the lowered text of ``ServeEngine``'s programs to a directory, to
 hold a refactoring to "the programs are the parent's".
 
-Small GPT, Llama, EXAONE-MoE and DeepSeek-V2 engines; the decode step, three cold
-prefill buckets, two bursts and (where the prefix cache applies) two
-suffix-prefill buckets and the copy-on-write: once for this host's CPU
-(``reference`` backend, float32) and once for a described compile-only
-``TPU v5 lite`` (``kernel`` backend, bfloat16, as
-``tests/test_tpu_aot_compile.py`` builds one) — 66 programs (48 where
-the checkout has no ``models/deepseek_v2.py``). Needs no
+Small GPT, Llama, EXAONE-MoE, DeepSeek-V2 and GLM-5 engines; the decode
+step, three cold prefill buckets, two bursts and (where the prefix cache
+applies) two suffix-prefill buckets and the copy-on-write: once for this
+host's CPU (``reference`` backend, float32) and once for a described
+compile-only ``TPU v5 lite`` (``kernel`` backend, bfloat16, as
+``tests/test_tpu_aot_compile.py`` builds one) — 84 programs (66 where
+the checkout has no ``models/glm_moe_dsa.py``, 48 where it has no
+``models/deepseek_v2.py``). Needs no
 chip and runs nothing. To compare two checkouts, one after the other (two
 at once fight over libtpu's lock file)::
 
@@ -88,6 +89,20 @@ def models(dtype):
         kv_lora_rank=128, qk_nope_head_dim=128, qk_rope_head_dim=64,
         v_head_dim=128, moe_intermediate_size=128, dtype=dtype))
     out["deepseek"].eval()
+    try:      # a tree from before the GLM-5 family has no such family
+        from paddle_tpu.models.glm_moe_dsa import (GlmMoeDsaConfig,
+                                                   GlmMoeDsaForCausalLM)
+    except ImportError:
+        return out
+    # DeepSeek-V2's tiny latent attention with an indexer of two heads of
+    # 128 that keeps 64 keys: the 100 and 512 buckets select
+    out["glm"] = GlmMoeDsaForCausalLM(GlmMoeDsaConfig.tiny(
+        vocab_size=512, hidden_size=256, intermediate_size=512,
+        num_hidden_layers=2, num_attention_heads=2, q_lora_rank=128,
+        kv_lora_rank=128, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128, moe_intermediate_size=128, index_n_heads=2,
+        index_head_dim=128, index_topk=64, dtype=dtype))
+    out["glm"].eval()
     return out
 
 

@@ -11,7 +11,8 @@ for the compiled texts (`ServeEngine.lowered()`, `StaticFunction.lowered()`).
 and for its `copy` and `fusion` instructions alone, layer indices folded
 (`layer*/scatter_kv`; a latent-attention layer's are `layer*/mla/q`, `/kv`,
 `/scatter_latent`, `/attn`, `/out`, with `/absorb_q` and `/absorb_o` in a
-decode step and `/expand` in a prefill); for a serving cell the engine's
+decode step and `/expand` in a prefill; with sparse attention also
+`/index`, `/scatter_index` and `/select`); for a serving cell the engine's
 own counters (`tools/serve_counters.py`'s) go into the file too; the table
 goes to
 `chiprun_out/scope_breakdown.<cell>.json` beside the traced run's own result

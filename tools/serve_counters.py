@@ -20,6 +20,10 @@ decode step: the context the window really held) beside the pages its
 products ran over (`serve.mla_pages_computed`; `computed_over_read` is
 those pages' rows over the rows read: 1 plus half a page a stream where
 the kernel computes what a stream holds); for a model with sparse
+attention (`dsa`) the index keys' bytes, the keys `dsa_index` scored and
+the rows `mla_sparse_decode` read (`selected_over_index_rows`: the share of
+the context attention reads) and a prompt's attended pairs against its
+causal ones; for a model with sparse
 layers the tokens routed, the assignments that fell on held experts and,
 under a group-limited router, the tokens whose kept groups include the
 held one (3/8 in expectation for one group of eight, three kept). A
@@ -67,6 +71,7 @@ def counters(engine: str, block_size=None) -> dict:
         "requests_finished": value("serve.requests_finished",
                                    reason="max_new_tokens"),
         "latent": latent(value, steps, block_size),
+        "dsa": sparse_attention(value),
         "moe": moe(value),
     }
 
@@ -90,6 +95,22 @@ def latent(value, steps, block_size=None) -> dict:
             "computed_over_read": (_share(pages * block_size, ctx)
                                    if pages is not None and block_size
                                    else None)}
+
+
+def sparse_attention(value) -> dict:
+    """The sparse-attention layers: the index keys' bytes, the keys
+    `dsa_index` scored and the rows `mla_sparse_decode` read over the
+    decode steps (their ratio: the share of the context attention reads),
+    and the (query, key) pairs the prompts attended against their causal
+    pairs."""
+    rows = value("serve.dsa_index_rows")
+    chosen = value("serve.dsa_selected_rows")
+    return {"index_cache_bytes": value("serve.index_cache_bytes"),
+            "index_rows": rows, "selected_rows": chosen,
+            "selected_over_index_rows": _share(chosen, rows),
+            "prefill_pairs_selected": value(
+                "serve.dsa_prefill_pairs_selected"),
+            "prefill_pairs_causal": value("serve.dsa_prefill_pairs_causal")}
 
 
 def moe(value) -> dict:
