@@ -15,8 +15,7 @@ earlier position (``min(topk, t + 1)`` of them: a query with no more than
 
 - :func:`index_scores` is the score itself (the whole-sequence forward's,
   and the oracle of the decode kernel ``ops/pallas/dsa_index``);
-- :func:`top_mask` the selection as a mask over scored rows (a decode
-  step's, for ``ops/pallas/mla_sparse_decode``);
+- :func:`top_mask` the selection as a mask over scored rows;
 - :func:`prefill_select` a prompt's selection as an int8 ``[T, T]`` mask
   for the flash forward, computed a block of queries at a time (never a
   ``[T, T]`` float32 array whole).
@@ -26,6 +25,13 @@ a sort) and takes the ties at it from the earliest position on, so it does
 not depend on the order in which a backend's ``top_k`` returns equal
 values (the CPU's does not keep the earlier of equal values first at a k
 of 2,048).
+
+On the chip the serving engine selects in VMEM instead
+(``ops/pallas/dsa_select.py``: ``dsa_select`` over a decode step's scores,
+``dsa_prefill_select`` scoring and selecting a prompt's query blocks),
+with the same halvings and the same ties; the functions here are the CPU
+engine's path, ``models/glm_moe_dsa.py``'s whole-sequence forward and
+those kernels' oracles.
 """
 from __future__ import annotations
 

@@ -6,7 +6,7 @@ into the query): ``NH`` absorbed queries against ONE key-value head whose
 key is a cached row ``[c | k_pe]`` and whose value is its first ``dv``
 lanes, bfloat16 operands on the MXU, the softmax state in float32. What
 differs is which rows: a stream's ``min(length, topk)`` selected positions
-(``ops/dsa.py:top_mask``), spread over its whole context.
+(``ops/pallas/dsa_select``'s ``keep`` rows), spread over its whole context.
 
 So is the walk: ``mla_decode``'s over every page the stream holds, each
 selected position's score taken as it is and every other one's pushed
@@ -44,36 +44,37 @@ def keep_of(chosen):
     return jnp.where(chosen, 0.0, DROP).astype(jnp.float32)[:, None, :]
 
 
-def mla_sparse_decode_reference(q, pool, chosen, lengths, block_tables, *,
+def mla_sparse_decode_reference(q, pool, keep, lengths, block_tables, *,
                                 dv, sm_scale):
-    """jnp reference: ``mla_decode_reference`` over each stream's
-    ``chosen`` positions alone. ``[B, NH, dv]`` in q.dtype; a stream of
-    length 0 gives zeros."""
+    """jnp reference: ``mla_decode_reference`` over the positions each
+    stream's ``keep`` row selects (0) alone. ``[B, NH, dv]`` in q.dtype; a
+    stream of length 0 gives zeros."""
     _check_shapes(q, pool, lengths, block_tables, dv)
     b, _, lanes = q.shape
     s_pad = block_tables.shape[1] * pool.shape[2]
-    if chosen.shape != (b, s_pad):
-        raise ValueError(f"chosen must be [B, {s_pad}], got {chosen.shape}")
+    if keep.shape != (b, 1, s_pad):
+        raise ValueError(f"keep must be [B, 1, {s_pad}], got {keep.shape}")
     rows = pool[0][block_tables].reshape(b, s_pad, lanes)
     scores = jnp.einsum("bhl,bsl->bhs", q.astype(jnp.float32),
                         rows.astype(jnp.float32)) * sm_scale
-    valid = (chosen & (jnp.arange(s_pad)[None, :] < lengths[:, None]))
+    valid = ((keep[:, 0, :] == 0.0)
+             & (jnp.arange(s_pad)[None, :] < lengths[:, None]))
     probs = jax.nn.softmax(jnp.where(valid[:, None], scores, -jnp.inf), -1)
     probs = jnp.where(valid[:, None], probs, 0.0)
     return jnp.einsum("bhs,bsv->bhv", probs,
                       rows[..., :dv].astype(jnp.float32)).astype(q.dtype)
 
 
-def mla_sparse_decode_kernel(q, pool, chosen, lengths, block_tables, *, dv,
+def mla_sparse_decode_kernel(q, pool, keep, lengths, block_tables, *, dv,
                              sm_scale, interpret=False):
     """``mla_decode``'s page walk under the selection, as its own Pallas
     call (TPU; ``interpret=True`` on the CPU)."""
     return mla_decode_kernel(q, pool, lengths, block_tables, dv=dv,
                              sm_scale=sm_scale, interpret=interpret,
-                             keep=keep_of(chosen), name="mla_sparse_decode")
+                             keep=keep, name="mla_sparse_decode")
 
 
-def mla_sparse_decode(q, pool, chosen, lengths, block_tables, *, dv,
+def mla_sparse_decode(q, pool, keep, lengths, block_tables, *, dv,
                       sm_scale, backend="auto"):
     """Latent attention of ONE decode step over the selected rows.
 
@@ -81,8 +82,9 @@ def mla_sparse_decode(q, pool, chosen, lengths, block_tables, *, dv,
       q: ``[B, NH, lanes]``: the absorbed query of each head, a row's
         layout (``ops/mla.py:absorb_q``).
       pool: ``[1, total_pages, page_size, lanes]``, the layer's rows.
-      chosen: ``[B, pages_per_seq * page_size]`` bool, a stream's selected
-        positions (none at or past its length).
+      keep: ``[B, 1, pages_per_seq * page_size]`` float32, 0 at a
+        stream's selected positions (none at or past its length) and
+        ``DROP`` at every other (``ops/pallas/dsa_select``'s rows).
       lengths: ``[B]`` int32; a stream of length 0 returns zeros.
       block_tables: ``[B, pages_per_seq]`` int32 physical page ids.
       dv: the leading lanes of a row that are its value.
@@ -94,9 +96,9 @@ def mla_sparse_decode(q, pool, chosen, lengths, block_tables, *, dv,
     """
     backend = resolve_backend(backend)
     if backend == "reference":
-        return mla_sparse_decode_reference(q, pool, chosen, lengths,
+        return mla_sparse_decode_reference(q, pool, keep, lengths,
                                            block_tables, dv=dv,
                                            sm_scale=sm_scale)
-    return mla_sparse_decode_kernel(q, pool, chosen, lengths, block_tables,
+    return mla_sparse_decode_kernel(q, pool, keep, lengths, block_tables,
                                     dv=dv, sm_scale=sm_scale,
                                     interpret=(backend == "interpret"))

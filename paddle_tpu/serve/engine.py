@@ -401,9 +401,10 @@ class ServeEngine:
     the same block table (a block holds both, so whatever the scheduler
     does with a block it does with both); a step writes its tokens' keys
     before the indexer scores them, a prompt attends under its selection
-    (``ops/dsa.py:prefill_select`` and the flash forward), and a decode
-    step scores every cached key (``ops/pallas/dsa_index``) and attends
-    over the selected rows only (``ops/pallas/mla_sparse_decode``).
+    (``ops/pallas/dsa_select.dsa_prefill_select`` and the flash forward),
+    and a decode step scores every cached key (``ops/pallas/dsa_index``),
+    selects in VMEM (``dsa_select``) and attends over the selected rows
+    only (``ops/pallas/mla_sparse_decode``).
 
     Usage::
 
@@ -1681,15 +1682,14 @@ class ServeEngine:
                      tables):
         """A sparse layer's attention of the rows' queries, ``[rows, nh *
         v]``: ``dsa_index`` scores every cached index key of each row's
-        stream, the ``index_topk`` best are selected, and
+        stream, ``dsa_select`` keeps the ``index_topk`` best, and
         ``mla_sparse_decode`` attends, absorbed, over those latent rows
         only."""
         import jax
-        import jax.numpy as jnp
 
-        from ..ops import dsa as _dsa
         from ..ops import mla as _mla
         from ..ops.pallas.dsa_index import dsa_index
+        from ..ops.pallas.dsa_select import dsa_select
         from ..ops.pallas.mla_sparse_decode import mla_sparse_decode
 
         st, ds = self._static["mla"], self._dsa
@@ -1699,13 +1699,12 @@ class ServeEngine:
                                scale=ds["dim"] ** -0.5,
                                backend=self.attention_backend)
         with jax.named_scope(f"layer{i}/mla/select"):
-            pos = jnp.arange(scores.shape[-1], dtype=jnp.int32)[None, :]
-            chosen = _dsa.top_mask(scores, pos < lengths[:, None],
-                                   ds["topk"])
+            keep = dsa_select(scores, lengths, topk=ds["topk"],
+                              backend=self.attention_backend)
         with jax.named_scope(f"layer{i}/mla/absorb_q"):
             q = _mla.absorb_q(lp, st, q_nope, q_pe, pool.shape[-1])
         with jax.named_scope(f"layer{i}/mla/attn"):
-            out = mla_sparse_decode(q, pool, chosen, lengths, tables,
+            out = mla_sparse_decode(q, pool, keep, lengths, tables,
                                     dv=st["rank"], sm_scale=self._scale,
                                     backend=self.attention_backend)
         with jax.named_scope(f"layer{i}/mla/absorb_o"):
@@ -2001,8 +2000,8 @@ class ServeEngine:
             sparse layer's index keys into their pool too, and where the
             bucket is longer than ``index_topk`` each query attends only
             the keys it selected."""
-            from ..ops import dsa as _dsa
             from ..ops import mla as _mla
+            from ..ops.pallas.dsa_select import dsa_prefill_select
 
             with jax.named_scope(f"layer{i}/mla/scatter_latent"):
                 pool = self._scatter_latent(slots, True, cache[0], latent)
@@ -2014,9 +2013,10 @@ class ServeEngine:
                     new += (self._scatter_latent(slots, True, cache[1], k_i),)
                 if tp > self._dsa["topk"]:
                     with jax.named_scope(f"layer{i}/mla/select"):
-                        chosen = _dsa.prefill_select(
+                        chosen = dsa_prefill_select(
                             q_i, w, k_i, self._dsa["topk"],
-                            self._dsa["dim"] ** -0.5)
+                            self._dsa["dim"] ** -0.5,
+                            backend=self.attention_backend)
                     select = {"select": chosen}
                     seen = causal & (chosen != 0)
             with jax.named_scope(f"layer{i}/mla/expand"):

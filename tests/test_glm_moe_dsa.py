@@ -7,8 +7,9 @@ selection engages: the model's forward, `ServeEngine` prefill then decode
 through the latent and index-key pools (slots churn, a stream is
 preempted, a burst, the prefix cache), the selection against a plain numpy
 one, `dsa_index` and `mla_sparse_decode` under the Pallas interpreter
-against their references, the counters, and the dense control that does
-not pass."""
+against their references, the selections in VMEM (`dsa_select`,
+`dsa_prefill_select`) interpreted against `top_mask` and `prefill_select`,
+the counters, and the dense control that does not pass."""
 import os
 import sys
 
@@ -30,8 +31,10 @@ from paddle_tpu.models import glm_moe_dsa as prog  # noqa: E402
 from paddle_tpu.ops import dsa  # noqa: E402
 from paddle_tpu.ops.pallas.dsa_index import (dsa_index_kernel,  # noqa: E402
                                              dsa_index_reference)
+from paddle_tpu.ops.pallas.dsa_select import (  # noqa: E402
+    dsa_prefill_select_kernel, dsa_select_kernel)
 from paddle_tpu.ops.pallas.mla_sparse_decode import (  # noqa: E402
-    mla_sparse_decode_kernel, mla_sparse_decode_reference)
+    keep_of, mla_sparse_decode_kernel, mla_sparse_decode_reference)
 from paddle_tpu.serve import ServeEngine  # noqa: E402
 
 #: float32 program against float32 reference
@@ -402,9 +405,10 @@ def test_mla_sparse_decode_kernel_equals_its_reference(case, k):
     pos = jnp.arange(pps * page)[None, :]
     chosen = dsa.top_mask(scores, pos < lengths[:, None], k)
     assert np.asarray(chosen.sum(-1)).tolist() == [min(n, k) for n in lens]
-    got = mla_sparse_decode_kernel(q, pool, chosen, lengths, tables, dv=dv,
+    keep = keep_of(chosen)
+    got = mla_sparse_decode_kernel(q, pool, keep, lengths, tables, dv=dv,
                                    sm_scale=0.07, interpret=True)
-    want = mla_sparse_decode_reference(q, pool, chosen, lengths, tables,
+    want = mla_sparse_decode_reference(q, pool, keep, lengths, tables,
                                        dv=dv, sm_scale=0.07)
     assert got.shape == (b, nh, dv) and got.dtype == jnp.bfloat16
     np.testing.assert_allclose(np.asarray(got, np.float32),
@@ -421,3 +425,75 @@ def test_mla_sparse_decode_kernel_equals_its_reference(case, k):
         plain = (p / p.sum(-1, keepdims=True)) @ pick[:, :dv]
         np.testing.assert_allclose(np.asarray(want[r], np.float32), plain,
                                    atol=3e-2)
+
+
+# --- the selection in VMEM under the interpreter ----------------------------------
+def _decode_scores(case, rng):
+    """(scores [B, S], lengths, topk) of a named case of the decode
+    selection."""
+    s = rng.normal(size=(6, 640)).astype(np.float32)
+    lens = [0, 5, 200, 333, 640, 129]
+    k = 64
+    if case == "ties_at_the_kth":            # a few values, many equal
+        s = np.round(s * 2) / 2
+    elif case == "infs_and_signed_zeros":
+        s[:, ::3] = 0.0
+        s[:, 1::5] = -0.0
+        s[2, 10:190] = -np.inf
+        s[3, :] = -np.inf
+        s[4, 100:] = np.inf
+    elif case == "lengths_at_most_topk":
+        lens = [64, 63, 1, 0, 17, 64]
+    elif case == "lengths_off_the_lane_tiles":
+        s = rng.normal(size=(11, 2600)).astype(np.float32)
+        lens = [1500, 1025, 1023, 2600, 2599, 130, 0, 7, 1100, 2048, 64]
+        k = 1000
+    elif case == "a_tiny_model":             # the engine's tiny scale
+        s = np.round(rng.normal(size=(3, 64)) * 4).astype(np.float32)
+        lens = [20, 64, 8]
+        k = TOPK
+    return s, lens, k
+
+
+@pytest.mark.parametrize("case", ["random", "ties_at_the_kth",
+                                  "infs_and_signed_zeros",
+                                  "lengths_at_most_topk",
+                                  "lengths_off_the_lane_tiles",
+                                  "a_tiny_model"])
+def test_dsa_select_kernel_is_top_mask_bit_for_bit(case):
+    """The decode selection in VMEM is `top_mask` then `keep_of`, bit for
+    bit: many scores equal at the k-th, -inf, +inf and -0.0 beside +0.0,
+    a row of length 0, rows of no more than `topk`, lengths off the
+    128-lane tiles and rows past a multiple of eight, a `topk` of 64 of
+    640 and the tiny model's 8 of 64."""
+    s, lens, k = _decode_scores(case, np.random.default_rng(14))
+    scores, lengths = jnp.asarray(s), jnp.asarray(lens, jnp.int32)
+    pos = jnp.arange(s.shape[1])[None, :]
+    want = np.asarray(keep_of(dsa.top_mask(scores, pos < lengths[:, None],
+                                           k)))
+    got = np.asarray(dsa_select_kernel(scores, lengths, topk=k,
+                                       interpret=True))
+    assert got.shape == (len(lens), 1, s.shape[1]) and got.dtype == np.float32
+    np.testing.assert_array_equal(got, want)
+    assert ((got == 0).sum(-1)[:, 0] == np.minimum(lens, k)).all()
+
+
+@pytest.mark.parametrize("t,block", [(256, 128), (256, 64), (384, 128),
+                                     (48, None)])
+def test_dsa_prefill_select_kernel_equals_prefill_select(t, block):
+    """A prompt's selection scored and selected in VMEM is
+    `prefill_select`'s on scores exact in float32 (small integers for
+    queries, keys and weights, so no order of the sums moves one): two to
+    four blocks of queries, and the rows before the `topk`-th that take
+    every causal key."""
+    rng = np.random.default_rng(15)
+    heads, dim, k = 4, 16, 40
+    q = jnp.asarray(rng.integers(-2, 3, (t, heads, dim)), jnp.float32)
+    key = jnp.asarray(rng.integers(-2, 3, (t, dim)), jnp.float32)
+    w = jnp.asarray(rng.integers(-2, 3, (t, heads)), jnp.float32)
+    want = np.asarray(dsa.prefill_select(q, w, key, k, 0.25, block=16))
+    got = np.asarray(dsa_prefill_select_kernel(q, w, key, k, 0.25,
+                                               block=block, interpret=True))
+    assert got.dtype == np.int8 and got.shape == (t, t)
+    np.testing.assert_array_equal(got, want)
+    assert (got.sum(1) == np.minimum(np.arange(1, t + 1), k)).all()

@@ -616,20 +616,64 @@ def test_dsa_index_compiles(topo):
 
 def test_mla_sparse_decode_compiles(topo):
     """Absorbed attention over the selected rows at the cell's shapes: 32
-    rows of 64 queries, a selection mask over 160 pages a stream, a pool
+    rows of 64 queries, ``keep`` rows over 160 pages a stream, a pool
     of 4,096 pages of 640-lane rows walked in place and never copied
     whole."""
     from paddle_tpu.ops.pallas.mla_sparse_decode import (
         mla_sparse_decode_kernel)
 
     text, compiled = _compile(
-        lambda q, pool, chosen, lengths, tables: mla_sparse_decode_kernel(
-            q, pool, chosen, lengths, tables, dv=512, sm_scale=256 ** -0.5),
+        lambda q, pool, keep, lengths, tables: mla_sparse_decode_kernel(
+            q, pool, keep, lengths, tables, dv=512, sm_scale=256 ** -0.5),
         _on(topo, (32, 64, 640), BF16), _on(topo, (1, 4096, 128, 640), BF16),
-        _on(topo, (32, 20480), jnp.bool_), _on(topo, (32,), jnp.int32),
+        _on(topo, (32, 1, 20480), jnp.float32), _on(topo, (32,), jnp.int32),
         _on(topo, (32, 160), jnp.int32))
     assert "tpu_custom_call" in text and "mla_sparse_decode" in text
     assert not _pool_sized_copies(compiled.as_text(), (1, 4096, 128, 640))
+
+
+def test_dsa_select_compiles(topo):
+    """A decode step's selection in VMEM at the GLM-5 cell's shape: 32
+    streams' scores over tables of 160 pages of 128, written as the
+    ``keep`` rows ``mla_sparse_decode`` reads, with no copy between."""
+    from paddle_tpu.ops.pallas.dsa_select import dsa_select_kernel
+    from paddle_tpu.ops.pallas.mla_sparse_decode import (
+        mla_sparse_decode_kernel)
+
+    def fn(scores, lengths, q, pool, tables):
+        keep = dsa_select_kernel(scores, lengths, topk=2048)
+        return mla_sparse_decode_kernel(q, pool, keep, lengths, tables,
+                                        dv=512, sm_scale=256 ** -0.5)
+
+    text, compiled = _compile(
+        fn, _on(topo, (32, 20480), jnp.float32), _on(topo, (32,), jnp.int32),
+        _on(topo, (32, 64, 640), BF16), _on(topo, (1, 4096, 128, 640), BF16),
+        _on(topo, (32, 160), jnp.int32))
+    assert "dsa_select" in text and "mla_sparse_decode" in text
+    hlo = compiled.as_text()
+    assert not re.search(r"= f32\[32,1,20480\]\S* (copy|fusion)\(", hlo)
+
+
+@pytest.mark.parametrize("seq", [8192, 16384, 20480])
+def test_dsa_prefill_select_compiles(topo, seq):
+    """A prompt's selection scored and selected in VMEM at the GLM-5
+    cell's buckets past the 2,048 selected (32 index heads of 128,
+    bfloat16): blocks of 256 queries while a block's float32 keys fit 16
+    MiB, of 128 at 20,480, each under the VMEM limit it asks for."""
+    from paddle_tpu.ops.pallas.dsa_select import (dsa_prefill_select_kernel,
+                                                  prefill_block,
+                                                  prefill_vmem_bytes)
+
+    block = prefill_block(seq)
+    assert block == (128 if seq > 16384 else 256)
+    # Mosaic refuses a kernel whose buffers pass the limit it is given
+    assert prefill_vmem_bytes(seq, 32, 128, BF16, block) < 64 << 20
+    text, compiled = _compile(
+        lambda q, w, k: dsa_prefill_select_kernel(q, w, k, 2048,
+                                                  128 ** -0.5),
+        _on(topo, (seq, 32, 128), BF16), _on(topo, (seq, 32), jnp.float32),
+        _on(topo, (seq, 128), BF16))
+    assert "tpu_custom_call" in text and "dsa_prefill_select" in text
 
 
 @pytest.mark.parametrize("seq", [4096, 20480])
@@ -656,9 +700,9 @@ def test_glm_engine_programs_keep_both_pools_in_place(topo):
     decode step and the 4,096 and 20,480 prefill buckets (past the 2,048
     selected, so their selection runs) hold no ``copy`` shaped like a pool
     and alias every donated pool, the largest in under 4.5 GB of
-    temporaries; the decode step runs ``dsa_index`` and
-    ``mla_sparse_decode`` and no ``mla_decode``, the prefill the flash
-    forward."""
+    temporaries; the decode step runs ``dsa_index``, ``dsa_select`` and
+    ``mla_sparse_decode`` and no ``mla_decode``, the prefill
+    ``dsa_prefill_select`` and the flash forward."""
     from paddle_tpu.models.glm_moe_dsa import (GlmMoeDsaConfig,
                                                GlmMoeDsaForCausalLM)
     from paddle_tpu.serve import ServeEngine
@@ -688,8 +732,8 @@ def test_glm_engine_programs_keep_both_pools_in_place(topo):
             assert not _copies_shaped_like(hlo, pool), (name, pool)
         assert caches <= _aliased_params(hlo), name
         for kernel in ("moe_experts", "kv_write") + (
-                ("dsa_index", "mla_sparse_decode") if name == "decode"
-                else ("flash_fwd",)):
+                ("dsa_index", "dsa_select", "mla_sparse_decode")
+                if name == "decode" else ("dsa_prefill_select", "flash_fwd")):
             assert kernel in hlo, (name, kernel)
         assert "mla_decode\"" not in hlo, name
 

@@ -1,6 +1,5 @@
-"""Probe: the two kernels of a DeepSeek-sparse-attention decode step alone
-at the GLM-5 cell's shape, as ``tools/mla_kernel_probe.py`` is for
-``mla_decode``:
+"""Probe: the kernels of DeepSeek sparse attention alone at the GLM-5
+cell's shape, as ``tools/mla_kernel_probe.py`` is for ``mla_decode``:
 
     python3 tools/dsa_kernel_probe.py        # on one TPU chip
 
@@ -12,21 +11,34 @@ a uniform share of an output of lognormal(4,096, 0.4) in 1,536-8,192),
 
 1. each kernel against its jnp reference on the cell's lengths (every
    backend; the CPU runs the kernels interpreted and stops there: a time
-   comes from the chip only);
+   comes from the chip only): ``dsa_select`` against ``top_mask`` (then
+   ``keep_of``) bit for bit, on the scores and on them rounded (ties at
+   the k-th), and ``dsa_prefill_select`` against ``prefill_select`` at
+   the 8,192 bucket, on random operands (the pairs on which they differ:
+   a different order of a score's sums may move it across the k-th) and
+   on small integers (exact scores, many ties);
 2. microseconds a call (one layer) and a row: ``dsa_index`` beside its
-   XLA form (``dsa_index_reference``), the decode step's selection
-   (``ops/dsa.top_mask``: the k-th score by 32 halvings of its bits and
-   the tie count) beside one ``lax.top_k`` of the scores (a sort), a
-   prompt's block of 256 queries' mask by halvings and by a top-k,
-   ``mla_sparse_decode`` (the page walk under the selection) and, for the
-   same lengths, the dense page walk (``mla_decode`` over every row), each
-   beside the least the HBM allows for the bytes it needs
-   (``benchmark/work/``).
+   XLA form (``dsa_index_reference``), the decode step's selection in VMEM
+   (``dsa_select``, with a sweep of its streams a program and chunk of
+   lanes) beside the
+   XLA form (``ops/dsa.top_mask``: the k-th score by 32 passes over the
+   scores in the HBM, the tie count, ``keep_of``) and one ``lax.top_k``
+   of the scores (a sort), a prompt's block of 256 queries' mask by
+   halvings and by a top-k, a prompt's whole selection (one layer) at the
+   8,192 and 16,384 buckets scored and selected in VMEM
+   (``dsa_prefill_select``, with a sweep of its block, key tile, summed
+   rows and selected rows at 16,384) beside ``ops/dsa.prefill_select``
+   (XLA: a head
+   loop and the halvings over ``[256, T]`` in the HBM, a block at a
+   time), ``mla_sparse_decode`` (the page walk under the selection) and,
+   for the same lengths, the dense page walk (``mla_decode`` over every
+   row), each beside the least the HBM allows for the bytes it needs
+   (``benchmark/work/``; the prompt's selection beside nothing).
 
 ``--tiny`` runs the same control flow at toy sizes (here, on the CPU,
-the kernels interpreted): it prints no result. Copied into another
-checkout's ``tools/`` it measures that tree.
+the kernels interpreted): it prints no result.
 """
+import functools
 import os
 import sys
 import time
@@ -42,6 +54,7 @@ if _REPO_ROOT not in sys.path:
 from paddle_tpu.core.flags import pallas_mode  # noqa: E402
 from paddle_tpu.device import chip  # noqa: E402
 from paddle_tpu.ops import dsa  # noqa: E402
+from paddle_tpu.ops.pallas import dsa_select as vmem  # noqa: E402
 from paddle_tpu.ops.pallas import mla_sparse_decode as sparse  # noqa: E402
 from paddle_tpu.ops.pallas.dsa_index import (dsa_index_kernel,  # noqa: E402
                                              dsa_index_reference)
@@ -60,8 +73,11 @@ IH, IDIM, TOPK = 32, 128, 2048
 PAGE, PAGES, PPS = 128, 4096, 160
 SCALE, ISCALE = 256 ** -0.5, 128 ** -0.5
 STEPS = 50
+#: a prompt's buckets, and the calls chained for a prompt's selection
+PROMPTS, PROMPT_STEPS = (8192, 16384), 2
 if TINY:
     B, NH, TOPK, PAGES, PPS, STEPS = 4, 4, 512, 64, 16, 2
+    IH, PROMPTS = 4, (1024,)
 
 
 def cell_lengths(seed=0):
@@ -112,11 +128,54 @@ def time_call(fn, first, *rest, steps=STEPS):
     return best
 
 
-# --- selection forms the probe compares (the program's is dsa.top_mask)
+# --- selection forms the probe compares (the program's is dsa_select)
 def select_mask(scores, lengths, k):
-    """The decode step's selection as the program makes it."""
+    """The decode step's selection in XLA (``top_mask``)."""
     pos = jnp.arange(scores.shape[-1], dtype=jnp.int32)[None, :]
     return dsa.top_mask(scores, pos < lengths[:, None], k)
+
+
+def _with(names, values, fn):
+    """``fn()`` with the ``dsa_select`` module's constants ``names`` set
+    to ``values`` where not None (read as a kernel is traced)."""
+    raw = [getattr(vmem, n) for n in names]
+    for n, v in zip(names, values):
+        if v is not None:
+            setattr(vmem, n, v)
+    try:
+        return fn()
+    finally:
+        for n, v in zip(names, raw):
+            setattr(vmem, n, v)
+
+
+def select_in_vmem(scores, lengths, k, rows=None, chunk=None):
+    """The program's decode selection (``dsa_select``), its streams a
+    program and chunk of lanes set where given."""
+    return _with(("DECODE_ROWS", "DECODE_CHUNK"), (rows, chunk),
+                 lambda: vmem.dsa_select_kernel(scores, lengths, topk=k,
+                                                interpret=not ON_TPU))
+
+
+def prompt_in_vmem(q, w, k, block=None, tile=None, sums=None, rows=None,
+                   chunk=None):
+    """A prompt's selection as the program makes it
+    (``dsa_prefill_select``), its block, key tile, rows summed at once,
+    rows selected at once and chunk of lanes set where given."""
+    return _with(("KEY_TILE", "SUM_ROWS", "PREFILL_ROWS", "PREFILL_CHUNK"),
+                 (tile, sums, rows, chunk),
+                 lambda: vmem.dsa_prefill_select_kernel(
+                     q, w, k, TOPK, ISCALE, block=block,
+                     interpret=not ON_TPU))
+
+
+def prompt_arrays(t, seed=5):
+    """A prompt's index queries, weights and keys (bfloat16 and float32,
+    the cell's widths)."""
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return (jax.random.normal(k1, (t, IH, IDIM), jnp.bfloat16),
+            jax.random.normal(k2, (t, IH), jnp.float32),
+            jax.random.normal(k3, (t, IDIM), jnp.bfloat16))
 
 
 def select_by_sort(scores, lengths, k):
@@ -166,19 +225,40 @@ def main():
     print(f"dsa_index against its reference: largest gap {gap:.3e} "
           f"(scores of spread {float(np.std(np.asarray(want)[seen])):.3f})",
           flush=True)
-    chosen = jax.jit(lambda s, l: select_mask(s, l, TOPK))(want, lengths)
+    for what, scores in ((" on scores rounded (ties at the k-th)",
+                          jnp.round(want / 2)), ("", want)):
+        keep = jax.jit(lambda s, l: vmem.dsa_select_reference(
+            s, l, topk=TOPK))(scores, lengths)
+        got = jax.jit(lambda s, l: select_in_vmem(s, l, TOPK))(scores,
+                                                              lengths)
+        print(f"dsa_select against top_mask{what}: "
+              f"{int(jnp.sum(got != keep))} positions differ of "
+              f"{keep.size}", flush=True)
 
-    def sparse_call(q, pool, chosen, lengths, tables):
+    def sparse_call(q, pool, keep, lengths, tables):
         return sparse.mla_sparse_decode_kernel(
-            q, pool, chosen, lengths, tables, dv=DV, sm_scale=SCALE,
+            q, pool, keep, lengths, tables, dv=DV, sm_scale=SCALE,
             interpret=not ON_TPU)
 
-    got = jax.jit(sparse_call)(q, pool, chosen, lengths, tables)
+    got = jax.jit(sparse_call)(q, pool, keep, lengths, tables)
     want = jax.jit(lambda *a: sparse.mla_sparse_decode_reference(
-        *a, dv=DV, sm_scale=SCALE))(q, pool, chosen, lengths, tables)
+        *a, dv=DV, sm_scale=SCALE))(q, pool, keep, lengths, tables)
     print(f"mla_sparse_decode against its reference: largest gap "
           f"{float(jnp.max(jnp.abs(got.astype(jnp.float32) - want.astype(jnp.float32)))):.3e}",
           flush=True)
+    parts = prompt_arrays(PROMPTS[0])
+    for what, (pq, pw, pk) in (
+            ("", parts),
+            (" on small integers (exact scores, ties at the k-th)",
+             [jnp.round(a * 1.5).astype(a.dtype) for a in parts])):
+        got = jax.jit(prompt_in_vmem)(pq, pw, pk)
+        want = jax.jit(lambda *a: dsa.prefill_select(*a, TOPK, ISCALE))(
+            pq, pw, pk)
+        differ = int(jnp.sum(jnp.any(got != want, axis=1)))
+        print(f"dsa_prefill_select against prefill_select at {PROMPTS[0]}"
+              f"{what}: {int(jnp.sum(got != want))} pairs differ of "
+              f"{int(jnp.sum(want))} selected, in {differ} of {PROMPTS[0]} "
+              f"rows", flush=True)
     if not ON_TPU and not TINY:
         print("# not a TPU: no times", flush=True)
         return 0
@@ -207,9 +287,18 @@ def main():
         ("dsa_index (XLA gather form)",
          lambda qi, *a: dsa_index_reference(qi, *a, scale=ISCALE),
          (qi, w, ipool, lengths, tables), least(f_i, b_i), sum_ctx),
-        ("selection, the program's (halvings, tie count)",
-         lambda s, l: select_mask(s, l, TOPK), (scores, lengths),
+        ("selection in VMEM (dsa_select, the program's)",
+         lambda s, l: select_in_vmem(s, l, TOPK), (scores, lengths),
          least(0, sum_ctx * 4), sum_ctx),
+    ] + [
+        (f"selection in VMEM, {r} streams a program, chunks of {c} lanes",
+         lambda s, l, r=r, c=c: select_in_vmem(s, l, TOPK, r, c),
+         (scores, lengths), least(0, sum_ctx * 4), sum_ctx)
+        for r, c in ((8, 2048), (16, 512), (32, 256), (32, 512))
+    ] + [
+        ("selection in XLA (halvings, tie count, keep_of)",
+         lambda s, l: vmem.dsa_select_reference(s, l, topk=TOPK),
+         (scores, lengths), least(0, sum_ctx * 4), sum_ctx),
         ("selection by one top-k (a sort)",
          lambda s, l: select_by_sort(s, l, TOPK), (scores, lengths),
          least(0, sum_ctx * 4), sum_ctx),
@@ -220,7 +309,8 @@ def main():
          lambda s, m: _mask_by_sort(s, m, TOPK), (block_s, block_m),
          least(0, block_s.size * 4), block_s.size),
         ("mla_sparse_decode (the page walk under the selection)",
-         sparse_call, (q, pool, jnp.asarray(mask), lengths, tables),
+         sparse_call, (q, pool, vmem.dsa_select_reference(
+             scores, lengths, topk=TOPK), lengths, tables),
          least(f_s, b_s), rows),
         ("dense page walk (mla_decode, every row)",
          lambda q, p, l, t: mla_decode_kernel(q, p, l, t, dv=DV,
@@ -228,9 +318,32 @@ def main():
                                               interpret=not ON_TPU),
          (q, pool, lengths, tables), least(f_d, b_d), sum_ctx),
     ]
+    for t in PROMPTS:
+        parts = prompt_arrays(t)
+        pairs = t * (t + 1) // 2
+        cases += [
+            (f"a prompt's selection at {t} in VMEM (dsa_prefill_select)",
+             prompt_in_vmem, parts, 0.0, pairs),
+            (f"a prompt's selection at {t} in XLA (prefill_select)",
+             lambda *a: dsa.prefill_select(*a, TOPK, ISCALE), parts, 0.0,
+             pairs),
+        ]
+        if t == PROMPTS[-1] and not TINY:
+            cases += [
+                (f"a prompt's selection at {t} in VMEM, block {b}, key "
+                 f"tile {kt}, {sr} rows summed, {r} rows x {c} lanes "
+                 f"selected at once",
+                 functools.partial(prompt_in_vmem, block=b, tile=kt,
+                                   sums=sr, rows=r, chunk=c), parts, 0.0,
+                 pairs)
+                for b, kt, sr, r, c in (
+                    (128, 128, 64, 32, 128), (256, 256, 32, 32, 256),
+                    (256, 128, 32, 32, 256), (256, 128, 64, 64, 256),
+                    (256, 128, 64, 128, 128), (256, 128, 64, 256, 128))]
     for name, fn, args, lo, n in cases:
         try:
-            s = time_call(fn, *args)
+            s = time_call(fn, *args, steps=PROMPT_STEPS
+                          if name.startswith("a prompt") else STEPS)
         except Exception as e:  # noqa: BLE001 — report and go on
             print(f"{name}: failed: {type(e).__name__}: {str(e)[:300]}",
                   flush=True)
@@ -239,8 +352,9 @@ def main():
             print(f"{name}: ran (no time off the chip)", flush=True)
             continue
         print(f"{name}: {s * 1e6:.1f} us a call, {s * 1e9 / n:.2f} ns a "
-              f"row over {n} rows; least {lo * 1e6:.1f} us "
-              f"({100 * lo / s:.1f}%)", flush=True)
+              f"row over {n} rows" + (f"; least {lo * 1e6:.1f} us "
+                                      f"({100 * lo / s:.1f}%)" if lo else ""),
+              flush=True)
     return 0
 
 
