@@ -183,7 +183,8 @@ def moe_ffn(h, lp, st, dtype, *, valid=None, backend="auto", scope=None):
     """The sparse FFN on ``h`` ``[T, H]`` for one chip's share: the held
     experts' part of the routed sum and the shared expert. ``lp`` holds
     ``router`` [H, E], ``router_bias`` [E], ``gate_up`` [count, H, 2 I],
-    ``down`` [count, I, H] and the shared expert's ``wg``/``wu``/``wd``;
+    ``down`` [count, I, H] and the shared expert's ``wg``/``wu``/``wd``
+    (or, fused, ``w_in`` [H, 2 I] and ``wd``: ``models/granite_hybrid``);
     ``st`` the statics (``top_k``, ``scale``, ``norm_topk``, ``first``
     and, where they are not this family's, ``scoring``, ``n_group``,
     ``topk_group``); a router without ``router_bias`` has none.
@@ -195,7 +196,7 @@ def moe_ffn(h, lp, st, dtype, *, valid=None, backend="auto", scope=None):
     import jax.numpy as jnp
 
     from ..ops.pallas.moe_experts import experts_ffn, scoped
-    from .decoder_stack import swiglu_ffn
+    from .decoder_stack import swiglu_ffn, swiglu_fused_ffn
 
     t = h.shape[0]
     if t > MOE_CHUNK and t % MOE_CHUNK == 0:
@@ -230,7 +231,8 @@ def moe_ffn(h, lp, st, dtype, *, valid=None, backend="auto", scope=None):
             sizes = jnp.concatenate(
                 [sizes, jnp.sum(here, dtype=sizes.dtype)[None]])
     with named("shared"):
-        shared = swiglu_ffn(h, lp, dtype)
+        shared = (swiglu_fused_ffn if "w_in" in lp else swiglu_ffn)(
+            h, lp, dtype)
     return (routed + shared.astype(jnp.float32)).astype(dtype), sizes
 
 

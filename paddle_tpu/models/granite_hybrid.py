@@ -3,7 +3,8 @@ state-space layers and a few attention layers in one stack, a shared
 SwiGLU MLP in every layer, four scalar multipliers.
 
 Source of the shapes: the published ``config.json`` of
-ibm-granite/granite-4.0-h-micro. What that file does not carry follows
+ibm-granite/granite-4.0-h-micro (no experts) and of granite-4.0-h-small
+(72 routed experts, top 10). What those files do not carry follows
 ``transformers``' ``modeling_granitemoehybrid.py`` and the
 ``modeling_bamba.py`` it takes its mixer from: the gate is applied BEFORE
 the mixer's norm (``MambaRMSNormGated``: ``RMSNorm(y * silu(z)) * w``), the
@@ -12,9 +13,14 @@ norm runs over all the inner channels as one group (``mamba_n_groups``
 
 The layer, for input ``x`` (``m`` = ``residual_multiplier``):
 
-- ``x = x + m * mixer(RMSNorm(x))``; ``x = x + m * MLP(RMSNorm(x))``; the
-  MLP is ``output_linear(silu(gate) * up)`` with ``[gate | up] =
-  input_linear(h)``. The model has no experts (``num_local_experts`` 0).
+- ``x = x + m * mixer(RMSNorm(x))``; ``x = x + m * F(RMSNorm(x))``; the
+  shared MLP is ``output_linear(silu(gate) * up)`` with ``[gate | up] =
+  input_linear(h)``. Without experts (``num_local_experts`` 0) ``F`` is
+  that MLP; with them ``F(u) = sum_{e in S} g_e E_e(u) + MLP(u)``: ``S``
+  the ``num_experts_per_tok`` largest router logits ``u W_router`` (no
+  bias), ``g = softmax(logits[S])`` over the chosen ones alone, each
+  expert ``E_e`` a SwiGLU whose ``input_linear`` is ``[gate | up]`` too
+  (``GraniteMoeHybridMoE`` / ``GraniteMoeHybridTopKGating``).
 - ``attention`` layers: GQA without bias and WITHOUT any position
   encoding (``position_embedding_type: nope``), scores multiplied by
   ``attention_multiplier`` (1/64 at head size 64: not ``dh ** -0.5``),
@@ -28,6 +34,13 @@ The layer, for input ``x`` (``m`` = ``residual_multiplier``):
   (RMSNorm(y * silu(z)) * w) W_out``. ``ops/ssm.py`` computes it.
 - embedding rows times ``embedding_multiplier``; a final RMSNorm; the
   tied head's logits divided by ``logits_scaling``.
+
+**One chip's share** (``experts_held = (first, count)``): the expert
+layer stores only the held experts' matrices, routes over all
+``num_local_experts``, keeps the gates normalised over all the chosen
+experts, and adds only its own experts' terms and the shared MLP's
+(``models/exaone_moe.moe_ffn``); what the absent experts would add is left
+out. Nothing here stands in for the other chips.
 
 Serving goes through ``serve.ServeEngine``, which runs
 ``models/decoder_stack.py`` over :meth:`GraniteHybridForCausalLM.
@@ -53,7 +66,7 @@ from .decoder_stack import LayerSpec
 from .exaone_moe import _init, _Linear, _Norm, attention_mask
 
 __all__ = ["GraniteHybridConfig", "GraniteHybridForCausalLM",
-           "GraniteHybridModel", "GraniteMamba2Mixer"]
+           "GraniteHybridModel", "GraniteMamba2Mixer", "GraniteMoE"]
 
 
 @dataclass
@@ -83,7 +96,12 @@ class GraniteHybridConfig:
     residual_multiplier: float = 0.22
     logits_scaling: float = 8.0
     position_embedding_type: str = "nope"
+    #: routed experts of a layer (0: the shared MLP alone); each is
+    #: ``intermediate_size`` wide
     num_local_experts: int = 0
+    num_experts_per_tok: int = 0
+    #: (first, count) of the routed experts this chip holds; None: all
+    experts_held: Optional[Tuple[int, int]] = None
     rms_norm_eps: float = 1e-5
     max_position_embeddings: int = 131072
     tie_word_embeddings: bool = True
@@ -104,7 +122,7 @@ class GraniteHybridConfig:
                 != self.mamba_expand * self.hidden_size:
             raise ValueError("mamba_n_heads * mamba_d_head must be "
                              "mamba_expand * hidden_size")
-        for key, want in (("mamba_n_groups", 1), ("num_local_experts", 0),
+        for key, want in (("mamba_n_groups", 1),
                           ("position_embedding_type", "nope"),
                           ("mamba_conv_bias", True),
                           ("mamba_proj_bias", False),
@@ -114,6 +132,20 @@ class GraniteHybridConfig:
                 raise ValueError(
                     f"granite_hybrid runs {key}={want!r}, got "
                     f"{getattr(self, key)!r}")
+        if self.num_local_experts:
+            if not 1 <= self.num_experts_per_tok <= self.num_local_experts:
+                raise ValueError(
+                    f"num_experts_per_tok {self.num_experts_per_tok} of "
+                    f"{self.num_local_experts} experts")
+            first, count = self.experts_held or (0, self.num_local_experts)
+            if not (0 <= first and count >= 1
+                    and first + count <= self.num_local_experts):
+                raise ValueError(f"experts_held {self.experts_held} of "
+                                 f"{self.num_local_experts}")
+            self.experts_held = (int(first), int(count))
+        elif self.experts_held is not None:
+            raise ValueError("experts_held names experts of a model that "
+                             "has none (num_local_experts 0)")
 
     @property
     def head_dim(self) -> int:
@@ -129,7 +161,8 @@ class GraniteHybridConfig:
 
     def layer_spec(self, layer: int) -> LayerSpec:
         return LayerSpec(
-            rope=False, ffn="swiglu_fused",
+            rope=False, ffn="moe" if self.num_local_experts
+            else "swiglu_fused",
             mixer="mamba2" if self.layer_types[layer] == "mamba"
             else "attention")
 
@@ -240,6 +273,38 @@ class GraniteSharedMLP(nn.Layer):
         self.output_linear = _Linear(config, i, h)
 
 
+class _Experts(nn.Layer):
+    """The held experts' matrices of one projection, ``[count, in,
+    out]``."""
+
+    def __init__(self, config, n_in, n_out):
+        super().__init__()
+        self.weight = self.create_parameter(
+            [config.experts_held[1], n_in, n_out], dtype=config.dtype,
+            default_initializer=_init(config))
+
+
+class _Router(nn.Layer):
+    def __init__(self, config: GraniteHybridConfig):
+        super().__init__()
+        self.layer = _Linear(config, config.hidden_size,
+                             config.num_local_experts)
+
+
+class GraniteMoE(nn.Layer):
+    """The whole router (``router.layer`` ``[H, experts]``: every chip
+    routes over all of them) and the held experts: ``input_linear``
+    ``[count, H, 2 I]`` (gate and up side by side), ``output_linear``
+    ``[count, I, H]``."""
+
+    def __init__(self, config: GraniteHybridConfig):
+        super().__init__()
+        h, i = config.hidden_size, config.intermediate_size
+        self.router = _Router(config)
+        self.input_linear = _Experts(config, h, 2 * i)
+        self.output_linear = _Experts(config, i, h)
+
+
 class GraniteHybridDecoderLayer(nn.Layer):
     def __init__(self, config: GraniteHybridConfig, layer: int):
         super().__init__()
@@ -247,6 +312,8 @@ class GraniteHybridDecoderLayer(nn.Layer):
             self.mamba = GraniteMamba2Mixer(config)
         else:
             self.self_attn = GraniteAttention(config)
+        if config.num_local_experts:
+            self.block_sparse_moe = GraniteMoE(config)
         self.shared_mlp = GraniteSharedMLP(config)
         self.input_layernorm = _Norm(config, config.hidden_size)
         self.post_attention_layernorm = _Norm(config, config.hidden_size)
@@ -275,9 +342,12 @@ class GraniteHybridForCausalLM(nn.Layer):
     def decode_view(self):
         """The parameter view ``models/decoder_stack.py`` runs over a
         cache: one ``LayerSpec`` a layer (the mixer's kind, no RoPE, the
-        fused SwiGLU), the attention layers' leaves under the Llama
-        view's names, a Mamba layer's under ``ops/ssm.py``'s, ``ssm``
-        (the mixer's sizes) and the four scalings."""
+        fused SwiGLU or the expert layer), the attention layers' leaves
+        under the Llama view's names, a Mamba layer's under
+        ``ops/ssm.py``'s, ``ssm`` (the mixer's sizes), the four scalings
+        and, with experts, the expert layer's leaves under ``moe_ffn``'s
+        names (the shared MLP as the fused ``w_in``) and ``moe``, its
+        router's statics."""
         cfg = self.config
         layers = []
         for l, layer in enumerate(self.model.layers):
@@ -300,7 +370,16 @@ class GraniteHybridForCausalLM(nn.Layer):
                           wk=a.k_proj.weight._value,
                           wv=a.v_proj.weight._value,
                           wo=a.o_proj.weight._value)
+            if cfg.num_local_experts:
+                e = layer.block_sparse_moe
+                lp.update(router=e.router.layer.weight._value,
+                          gate_up=e.input_linear.weight._value,
+                          down=e.output_linear.weight._value)
             layers.append(lp)
+        moe = {} if not cfg.num_local_experts else dict(moe=dict(
+            top_k=cfg.num_experts_per_tok, scale=1.0, norm_topk=True,
+            scoring="softmax", first=cfg.experts_held[0],
+            count=cfg.experts_held[1], num_experts=cfg.num_local_experts))
         return dict(
             embed=self.model.embed_tokens.weight._value,
             norm=self.model.norm.weight._value, tied_head=True,
@@ -316,7 +395,7 @@ class GraniteHybridForCausalLM(nn.Layer):
             embedding_multiplier=cfg.embedding_multiplier,
             residual_multiplier=cfg.residual_multiplier,
             logits_scaling=cfg.logits_scaling,
-            attn_scale=cfg.attention_multiplier,
+            attn_scale=cfg.attention_multiplier, **moe,
         )
 
     def forward(self, input_ids):

@@ -467,6 +467,91 @@ def test_granite_engine_programs_keep_state_and_pools_in_place(topo):
             assert kernel in hlo, (name, kernel)
 
 
+def test_ssm_decode_compiles_at_128_heads(topo):
+    """The recurrent step at Granite 4.0-H Small's state, 128 slots of 128
+    heads of [64, 128] in bfloat16 (``[128, 64, 128, 128]``: a row's block
+    is 2 MiB, in and out double-buffered about 8 MiB of VMEM, its body 64
+    groups unrolled): Mosaic takes it with the one row a grid step, in
+    place, with no copy of the state."""
+    from paddle_tpu.ops.pallas.ssm_decode import (ssm_decode_kernel,
+                                                  state_shape)
+
+    assert state_shape(128, 128, 64, 128) == (128, 64, 128, 128)
+    state = _on(topo, (128, 64, 128, 128), BF16)
+    lowered = jax.jit(ssm_decode_kernel, donate_argnums=(0,)).lower(
+        state, _on(topo, (128, 128, 64), BF16),
+        _on(topo, (128, 128), jnp.float32), _on(topo, (128,), jnp.float32),
+        _on(topo, (128, 128), BF16), _on(topo, (128, 128), BF16),
+        _on(topo, (128,), jnp.bool_))
+    assert "ssm_decode" in lowered.as_text()
+    hlo = lowered.compile().as_text()
+    assert not _pool_sized_copies(hlo, state.shape)
+    assert 0 in _aliased_params(hlo)
+
+
+def test_moe_experts_compiles_at_expert_width_768(topo):
+    """Granite 4.0-H Small's held experts: 36 of [4096, 1536] gate-up and
+    [768, 4096] down at a decode step's 128 x 10 rows (the down product's
+    contraction, 768, one k tile)."""
+    from paddle_tpu.ops.pallas.moe_experts import experts_ffn
+
+    tokens = 128
+
+    def fn(x, w, experts, gate_up, down):
+        return experts_ffn(x, w, experts, gate_up, down, first=0)
+
+    text, compiled = _compile(
+        fn, _on(topo, (tokens, 4096), BF16),
+        _on(topo, (tokens, 10), jnp.float32),
+        _on(topo, (tokens, 10), jnp.int32),
+        _on(topo, (36, 4096, 1536), BF16), _on(topo, (36, 768, 4096), BF16))
+    assert text.count("tpu_custom_call") == 2 and "moe_experts" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e9
+
+
+def test_granite_moe_engine_programs_keep_state_and_pools_in_place(topo):
+    """Two layers of Granite 4.0-H Small at the published widths (a Mamba-2
+    layer of 128 heads, the attention layer with heads of 128, each with
+    its expert layer: 36 held of 72, top 10, and the shared expert) at the
+    cell's slots: heads of 128 take the unpacked pool (``[8, blocks, 128,
+    128]``), the decode step runs ``ssm_decode``, ``paged_decode`` and
+    ``moe_experts`` and the 64 bucket ``flash_fwd`` and ``moe_experts``,
+    no program copies a state or a pool, and every donated cache is
+    aliased to its result."""
+    from paddle_tpu.models.granite_hybrid import (GraniteHybridConfig,
+                                                  GraniteHybridForCausalLM)
+    from paddle_tpu.serve import ServeEngine
+
+    model = GraniteHybridForCausalLM(GraniteHybridConfig(
+        vocab_size=50176, hidden_size=4096, intermediate_size=768,
+        shared_intermediate_size=1536, num_hidden_layers=2,
+        layer_types=("mamba", "attention"), mamba_n_heads=128,
+        attention_multiplier=1 / 128, logits_scaling=16.0,
+        num_local_experts=72, num_experts_per_tok=10, experts_held=(0, 36),
+        dtype="bfloat16", deferred_init=True))
+    model.eval()
+    eng = ServeEngine(model, max_slots=128, block_size=128, num_blocks=256,
+                      max_seq_len=4096, name="aot-granite-moe", trace=False,
+                      slo=False)
+    assert eng.attention_backend == "kernel" and eng._pack == 1
+    state, tail, pool = (128, 64, 128, 128), (3, 128, 8448), (8, 256, 128,
+                                                                128)
+    assert [tuple(a.shape for a in c) for c in eng._caches] == [
+        (tail, state), (pool, pool)]
+    lowered = eng.lowered(prompt_lens=(64,), device=topo.devices[0])
+    n_arrays = len(jax.tree.leaves(eng._arrays))
+    caches = set(range(n_arrays, n_arrays + 4))
+    for name, low in lowered.items():
+        hlo = low.compile().as_text()
+        assert not _pool_sized_copies(hlo, state), name
+        assert not _copies_shaped_like(hlo, pool), name
+        assert caches <= _aliased_params(hlo), name
+        for kernel in ("kv_write", "moe_experts") + (
+                ("ssm_decode", "paged_decode") if name == "decode"
+                else ("flash_fwd",)):
+            assert kernel in hlo, (name, kernel)
+
+
 def test_mla_decode_compiles(topo):
     """The latent decode kernel at the DeepSeek-V2 cell's shapes: 192
     rows of 128 absorbed queries over a pool of 6,656 pages of 128 rows

@@ -2,13 +2,16 @@
 """Write the lowered text of ``ServeEngine``'s programs to a directory, to
 hold a refactoring to "the programs are the parent's".
 
-Small GPT, Llama, EXAONE-MoE, DeepSeek-V2 and GLM-5 engines; the decode
+Small GPT, Llama, EXAONE-MoE, DeepSeek-V2, GLM-5 and Granite 4.0-H
+engines (the last without experts and, where the checkout's Granite family
+takes them, with them); the decode
 step, three cold prefill buckets, two bursts and (where the prefix cache
 applies) two suffix-prefill buckets and the copy-on-write: once for this
 host's CPU (``reference`` backend, float32) and once for a described
 compile-only ``TPU v5 lite`` (``kernel`` backend, bfloat16, as
-``tests/test_tpu_aot_compile.py`` builds one) — 84 programs (66 where
-the checkout has no ``models/glm_moe_dsa.py``, 48 where it has no
+``tests/test_tpu_aot_compile.py`` builds one) — 108 programs (96 where
+the checkout's Granite family has no experts, 78 where the checkout has
+no ``models/glm_moe_dsa.py``, 60 where it has no
 ``models/deepseek_v2.py``). Needs no
 chip and runs nothing. To compare two checkouts, one after the other (two
 at once fight over libtpu's lock file)::
@@ -103,18 +106,39 @@ def models(dtype):
         v_head_dim=128, moe_intermediate_size=128, index_n_heads=2,
         index_head_dim=128, index_topk=64, dtype=dtype))
     out["glm"].eval()
+    from paddle_tpu.models.granite_hybrid import (GraniteHybridConfig,
+                                                  GraniteHybridForCausalLM)
+
+    # heads of 64 (two a pool row, two a state row), both kinds of layer
+    granite = dict(vocab_size=512, hidden_size=256, intermediate_size=128,
+                   shared_intermediate_size=512, num_attention_heads=4,
+                   num_key_value_heads=2, attention_multiplier=1 / 64,
+                   mamba_n_heads=8, mamba_d_head=64, mamba_d_state=128,
+                   mamba_chunk_size=64, num_hidden_layers=2,
+                   layer_types=("mamba", "attention"), dtype=dtype)
+    out["granite"] = GraniteHybridForCausalLM(GraniteHybridConfig(**granite))
+    try:      # a tree from before Granite's experts takes none
+        out["granite_moe"] = GraniteHybridForCausalLM(GraniteHybridConfig(
+            num_local_experts=8, num_experts_per_tok=2, experts_held=(0, 4),
+            **granite))
+    except TypeError:
+        pass
+    for name in ("granite", "granite_moe"):
+        if name in out:
+            out[name].eval()
     return out
 
 
 def dump(out_dir, tag, dtype, device):
     n = 0
     for name, model in models(dtype).items():
-        ring = name == "exaone"     # a ring is not shared: no prefix cache
+        # a ring or a recurrent state is not shared: no prefix cache
+        private = name in ("exaone", "granite", "granite_moe")
         eng = ServeEngine(model, max_slots=8, block_size=128, num_blocks=16,
-                          max_seq_len=512, prefix_cache=not ring,
+                          max_seq_len=512, prefix_cache=not private,
                           name=f"lowered-{tag}-{name}", trace=False,
                           slo=False)
-        more = {} if ring else dict(suffix_lens=(8, 200), cow=True)
+        more = {} if private else dict(suffix_lens=(8, 200), cow=True)
         programs = eng.lowered(prompt_lens=(8, 100, 512), bursts=(2, 4),
                                device=device, **more)
         for prog, low in programs.items():
