@@ -378,8 +378,9 @@ def _kv_write_case(c, *, kvh, pages, page, dh, slots, prompt):
 
 
 def _moe_case(c, *, tokens, width, expert, held, experts, top_k, tol=2e-2):
-    """The held experts' grouped products against `lax.ragged_dot` on
-    the same layout, and no assignment lost."""
+    """The held experts' products (the direct path up to 128 tokens, the
+    grouped buffer above) against `lax.ragged_dot` on the grouped
+    layout, and no assignment lost."""
     import jax
     import jax.numpy as jnp
     import numpy as np

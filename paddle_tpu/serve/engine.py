@@ -274,6 +274,10 @@ _M_MOE_MAX = obs.counter(
 _M_MOE_SUM = obs.counter(
     "serve.moe_expert_tokens_sum", "a decode step's tokens on all held "
     "experts, summed over steps, by sparse layer")
+_M_MOE_LAYERS = obs.counter(
+    "serve.moe_decode_layers", "sparse layers of the decode programs "
+    "read, by the path their held experts took: `direct` (the step's own "
+    "rows, every group within one tile) or `grouped` (the grouped buffer)")
 _M_HOST_RT = obs.counter(
     "serve.host_roundtrips", "host->device decode dispatches — one "
     "per burst, so decode_burst=N cuts this ~N x per token")
@@ -490,11 +494,15 @@ class ServeEngine:
                             else -(-self.window // self.block_size) + 1)
         self.window_pool = (None if self.window is None else BlockPool(
             self.max_slots * self.ring_blocks, block_size))
+        from ..ops.pallas.moe_experts import takes_direct
         from ..ops.pallas.paged_attention import resolve_backend
 
         #: the attention path every compiled step of this engine takes:
         #: "kernel" (Mosaic-compiled), "interpret" or "reference"
         self.attention_backend = resolve_backend(attention_backend)
+        #: the held experts' path in a decode program (``moe_experts``)
+        self._moe_path = ("direct" if takes_direct(
+            self.max_slots, self.attention_backend) else "grouped")
 
         self._static = {k: v for k, v in p.items()
                         if not hasattr(v, "dtype")
@@ -1396,6 +1404,8 @@ class ServeEngine:
                                   engine=self.name)
             sizes = sizes[:, :count]
         _M_MOE_ROUTED.inc(n_tokens * len(self._sparse), engine=self.name)
+        _M_MOE_LAYERS.inc(len(self._sparse), engine=self.name,
+                          path=self._moe_path)
         _M_MOE_HELD.inc(int(sizes.sum()), engine=self.name)
         for layer, row in zip(self._sparse, sizes):
             _M_MOE_MAX.inc(int(row.max()), engine=self.name, layer=layer)

@@ -21,6 +21,7 @@ import paddle_tpu  # noqa: E402,F401
 from benchmark.harness import weights as _weights  # noqa: E402
 from benchmark.reference import exaone_moe as ref  # noqa: E402
 from benchmark.stacks import exaone_moe as stack  # noqa: E402
+from paddle_tpu import observability as obs  # noqa: E402
 from paddle_tpu.models import exaone_moe as prog  # noqa: E402
 from paddle_tpu.serve import ServeEngine  # noqa: E402
 
@@ -95,6 +96,12 @@ def test_engine_streams_match_the_reference_through_both_caches(backend):
             for n, m in [(5, 30), (17, 25), (9, 40), (26, 12), (3, 33)]]
     eng.run()
     assert eng.decode_traces == 1
+    # three slots fit a tile: the kernels take the direct path
+    layers = obs.registry.get("serve.moe_decode_layers")
+    path = "grouped" if backend == "reference" else "direct"
+    other = {"grouped": "direct", "direct": "grouped"}[path]
+    assert layers.value(engine=f"ex-{backend}", path=path) > 0
+    assert layers.value(engine=f"ex-{backend}", path=other) == 0
     for r in reqs:
         gaps = ref.served_gaps(params, cfg, np.asarray(r.prompt),
                                np.asarray(r.output_ids), 64)
@@ -229,8 +236,9 @@ def test_the_shares_add_up_to_the_uncut_layer():
 @pytest.mark.parametrize("backend", ["reference", "interpret"])
 def test_a_router_skewed_onto_one_held_expert_drops_no_token(backend):
     """The selection bias puts expert 5 first for every token: its group
-    is all 24 tokens (three tiles of 16 rows' worth of buffer), none is
-    dropped, and the result is the reference's."""
+    is all 24 tokens (within one tile of 32 rows: the kernels take the
+    direct path, the reference the grouped buffer), none is dropped, and
+    the result is the reference's."""
     cfg = tiny_cfg(experts_held=[4, 3])
     x, lp = _layer_inputs(cfg)
     lp = dict(lp)

@@ -278,22 +278,38 @@ def test_engine_programs_copy_no_pool(topo):
         assert "kv_write" in hlo, name
 
 
-@pytest.mark.parametrize("tokens", [128, 2048])
+def _dispatch_ops(hlo, kind):
+    """The instructions of ``kind`` (``sort``, ``gather``, ``scatter``)
+    that the compiled text puts down to a ``moe/dispatch`` scope."""
+    return [line.strip()[:160] for line in hlo.splitlines()
+            if "moe/dispatch" in line and re.search(rf" {kind}\(", line)]
+
+
+@pytest.mark.parametrize("tokens", [32, 128, 2048])
 def test_moe_experts_compiles(topo, tokens):
-    """The held experts' grouped products at the published widths (16
-    experts of [6144, 4096] gate-up and [2048, 6144] down), a decode
-    step's 128 x 8 rows and a prefill piece's 2,048 x 8: Mosaic takes the
-    kernel, the group sizes being data."""
-    from paddle_tpu.ops.pallas.moe_experts import buffer_rows, experts_ffn
+    """The held experts' products at the published widths (16 experts of
+    [6144, 4096] gate-up and [2048, 6144] down): a decode step's 32 x 8
+    (GLM-5) and 128 x 8 (K-EXAONE) rows on the direct path, whose
+    dispatch sorts and gathers nothing, and a prefill piece's 2,048 x 8
+    through the grouped buffer: Mosaic takes both kernels, the group
+    sizes being data, two calls a layer either way."""
+    from paddle_tpu.ops.pallas.moe_experts import (buffer_rows, experts_ffn,
+                                                   takes_direct)
 
     def fn(x, w, experts, gate_up, down):
-        return experts_ffn(x, w, experts, gate_up, down, first=0)
+        return experts_ffn(x, w, experts, gate_up, down, first=0,
+                           scope="moe")
 
     text, compiled = _compile(
         fn, _on(topo, (tokens, 6144), BF16),
         _on(topo, (tokens, 8), jnp.float32), _on(topo, (tokens, 8), jnp.int32),
         _on(topo, (16, 6144, 4096), BF16), _on(topo, (16, 2048, 6144), BF16))
     assert text.count("tpu_custom_call") == 2 and "moe_experts" in text
+    hlo = compiled.as_text()
+    direct = takes_direct(tokens, "kernel")
+    assert direct == (tokens <= 128)
+    for kind in ("sort", "gather"):
+        assert bool(_dispatch_ops(hlo, kind)) != direct, kind
     assert buffer_rows(tokens, 8, 16, 128) == tokens * 8 + 16 * 128
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
 
@@ -350,7 +366,9 @@ def test_exaone_engine_programs_copy_no_pool(topo):
     layer over the block table): the decode step and the 64 and 2,048
     prefill buckets hold no ``copy`` shaped like either pool, alias all
     four donated pools, and run ``kv_write``, ``paged_decode``,
-    ``moe_experts`` and (prefill) ``flash_fwd``."""
+    ``moe_experts`` and (prefill) ``flash_fwd``; the experts of the decode
+    step and the 64 bucket take the direct path, the 2,048 bucket's the
+    grouped buffer."""
     from paddle_tpu.models.exaone_moe import (ExaoneMoeConfig,
                                               ExaoneMoeForCausalLM)
     from paddle_tpu.serve import ServeEngine
@@ -378,6 +396,11 @@ def test_exaone_engine_programs_copy_no_pool(topo):
         for kernel in ("kv_write", "moe_experts") + (
                 ("paged_decode",) if name == "decode" else ("flash_fwd",)):
             assert kernel in hlo, (name, kernel)
+        # 128 decode rows and a 64 bucket fit one tile: the direct path,
+        # whose dispatch neither sorts nor gathers; 2,048 rows do not
+        grouped = name == "prefill.2048"
+        for kind in ("sort", "gather"):
+            assert bool(_dispatch_ops(hlo, kind)) == grouped, (name, kind)
 
 
 def test_ssm_decode_compiles_in_place(topo):
@@ -492,13 +515,15 @@ def test_ssm_decode_compiles_at_128_heads(topo):
 def test_moe_experts_compiles_at_expert_width_768(topo):
     """Granite 4.0-H Small's held experts: 36 of [4096, 1536] gate-up and
     [768, 4096] down at a decode step's 128 x 10 rows (the down product's
-    contraction, 768, one k tile)."""
+    contraction, 768, one k tile), on the direct path: no sort and no
+    gather in the dispatch."""
     from paddle_tpu.ops.pallas.moe_experts import experts_ffn
 
     tokens = 128
 
     def fn(x, w, experts, gate_up, down):
-        return experts_ffn(x, w, experts, gate_up, down, first=0)
+        return experts_ffn(x, w, experts, gate_up, down, first=0,
+                           scope="moe")
 
     text, compiled = _compile(
         fn, _on(topo, (tokens, 4096), BF16),
@@ -506,6 +531,8 @@ def test_moe_experts_compiles_at_expert_width_768(topo):
         _on(topo, (tokens, 10), jnp.int32),
         _on(topo, (36, 4096, 1536), BF16), _on(topo, (36, 768, 4096), BF16))
     assert text.count("tpu_custom_call") == 2 and "moe_experts" in text
+    hlo = compiled.as_text()
+    assert not _dispatch_ops(hlo, "sort") and not _dispatch_ops(hlo, "gather")
     assert compiled.memory_analysis().temp_size_in_bytes < 1e9
 
 
@@ -515,9 +542,10 @@ def test_granite_moe_engine_programs_keep_state_and_pools_in_place(topo):
     its expert layer: 36 held of 72, top 10, and the shared expert) at the
     cell's slots: heads of 128 take the unpacked pool (``[8, blocks, 128,
     128]``), the decode step runs ``ssm_decode``, ``paged_decode`` and
-    ``moe_experts`` and the 64 bucket ``flash_fwd`` and ``moe_experts``,
-    no program copies a state or a pool, and every donated cache is
-    aliased to its result."""
+    ``moe_experts`` and the 64 bucket ``flash_fwd`` and ``moe_experts``
+    (both on the direct path: no sort and no gather in the dispatch), no
+    program copies a state or a pool, and every donated cache is aliased
+    to its result."""
     from paddle_tpu.models.granite_hybrid import (GraniteHybridConfig,
                                                   GraniteHybridForCausalLM)
     from paddle_tpu.serve import ServeEngine
@@ -550,6 +578,8 @@ def test_granite_moe_engine_programs_keep_state_and_pools_in_place(topo):
                 ("ssm_decode", "paged_decode") if name == "decode"
                 else ("flash_fwd",)):
             assert kernel in hlo, (name, kernel)
+        assert not _dispatch_ops(hlo, "sort"), name        # the direct path
+        assert not _dispatch_ops(hlo, "gather"), name
 
 
 def test_mla_decode_compiles(topo):

@@ -26,9 +26,11 @@ the context attention reads) and a prompt's attended pairs against its
 causal ones; for a model with sparse
 layers the tokens routed, the assignments that fell on held experts and,
 under a group-limited router, the tokens whose kept groups include the
-held one (3/8 in expectation for one group of eight, three kept). A
-checkout from before a counter has it, and a model without such layers,
-prints null there.
+held one (3/8 in expectation for one group of eight, three kept), and
+the share of the decode programs' sparse layers whose held experts took
+the direct path (`direct_share`: 1.0 where a step's slots fit one tile of
+`moe_experts`, 128). A checkout from before a counter has it, and a
+model without such layers, prints null there.
 Then, from the engine's step ring (its last 16,384 steps), the five longest
 steps with their seconds by phase and the five longest gaps between two
 steps (the caller's time), and from its ring of programs the percentiles of
@@ -114,14 +116,20 @@ def sparse_attention(value) -> dict:
 
 
 def moe(value) -> dict:
-    """Where the sparse layers' tokens went."""
+    """Where the sparse layers' tokens went, and the share of the decode
+    programs' sparse layers whose held experts took the direct path."""
     routed = value("serve.moe_tokens_routed")
     held = value("serve.moe_assignments_held")
     group = value("serve.moe_tokens_to_held_group")
+    direct = value("serve.moe_decode_layers", path="direct")
+    grouped = value("serve.moe_decode_layers", path="grouped")
     return {"tokens_routed": routed, "assignments_held": held,
             "assignments_held_a_token": _share(held, routed),
             "tokens_to_held_group": group,
-            "held_group_share": _share(group, routed)}
+            "held_group_share": _share(group, routed),
+            "decode_layers": {"direct": direct, "grouped": grouped},
+            "direct_share": (_share(direct, direct + grouped)
+                             if direct is not None else None)}
 
 
 def _block_size(cell: str):
